@@ -1,7 +1,8 @@
-"""Public op: flash attention with GQA, padding, and platform dispatch.
+"""Public op: flash attention with GQA and padding.
 
-On TPU the Pallas kernel runs natively; on CPU it runs in interpret mode
-(tests) or falls back to the jnp oracle (large shapes).
+The Pallas kernel is compiled for the device unless the caller passes
+``interpret=True`` (how the CPU tests run it); ``use_kernel=False``
+computes the jnp oracle instead.  Nothing here looks at the platform.
 
 The Q-block visit order is a UDS scheduling decision: under causal masking
 Q block i attends to O(i) KV blocks, so a decreasing-cost schedule

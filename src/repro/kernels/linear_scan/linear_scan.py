@@ -45,18 +45,25 @@ def _scalar_kernel(q_ref, k_ref, v_ref, lw_ref, y_ref, s_out_ref, s_ref,
     q = q_ref[0].astype(jnp.float32)          # (C, dk)
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)          # (C, dv)
-    lw = lw_ref[0].astype(jnp.float32)        # (C,)
+    # this chunk's log-decay: row ci of the (n_chunks, C) block
+    lw_row = lw_ref[0, pl.ds(ci, 1), :].astype(jnp.float32)   # (1, C)
 
-    ai = jnp.cumsum(lw)                       # inclusive log-decay
+    # prefix sums of the log-decay as masked reductions (the TPU lowering
+    # has no cumsum and no small transposes): the same sums, laid out as a
+    # column (C, 1) and as a row (1, C)
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    lw = jnp.sum(jnp.where(row == col, lw_row, 0.0), axis=1, keepdims=True)
+    ai = jnp.sum(jnp.where(col <= row, lw_row, 0.0), axis=1,
+                 keepdims=True)               # (C, 1) inclusive log-decay
+    ai_row = jnp.sum(jnp.where(row <= col, lw, 0.0), axis=0, keepdims=True)
     q_dec = ai if inclusive else ai - lw
     # inter-chunk: (q ⊙ exp(dec)) @ S
-    y = jax.lax.dot_general(q * jnp.exp(q_dec)[:, None], s_ref[...],
+    y = jax.lax.dot_general(q * jnp.exp(q_dec), s_ref[...],
                             (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     # intra-chunk
-    gap = q_dec[:, None] - ai[None, :]        # (C, C), masked entries <= 0
-    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    gap = q_dec - ai_row                      # (C, C), masked entries <= 0
     mask = (col <= row) if inclusive else (col < row)
     m = jnp.where(mask, jnp.exp(jnp.where(mask, gap, 0.0)), 0.0)
     scores = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -65,8 +72,8 @@ def _scalar_kernel(q_ref, k_ref, v_ref, lw_ref, y_ref, s_out_ref, s_ref,
                                 preferred_element_type=jnp.float32)
     y_ref[0] = y.astype(y_ref.dtype)
     # state update
-    alast = ai[-1]
-    kdec = k * jnp.exp(alast - ai)[:, None]
+    alast = jnp.sum(lw_row, axis=1, keepdims=True)             # (1, 1)
+    kdec = k * jnp.exp(alast - ai)
     s_ref[...] = s_ref[...] * jnp.exp(alast) + jax.lax.dot_general(
         kdec, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -90,7 +97,8 @@ def linear_scan_scalar(q: jax.Array, k: jax.Array, v: jax.Array,
     qr = q.reshape(bh, T, dk)
     kr = k.reshape(bh, T, dk)
     vr = v.reshape(bh, T, dv)
-    lwr = log_w.reshape(bh, T)
+    # (n_chunks, C) per head: the block's last two dims are the array's
+    lwr = log_w.reshape(bh, nc, chunk)
 
     y, s = pl.pallas_call(
         functools.partial(_scalar_kernel, chunk=chunk, n_chunks=nc,
@@ -100,7 +108,7 @@ def linear_scan_scalar(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, chunk, dk), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, dk), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, dv), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk), lambda b, c: (b, c)),
+            pl.BlockSpec((1, nc, chunk), lambda b, c: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, dv), lambda b, c: (b, c, 0)),
@@ -118,7 +126,7 @@ def linear_scan_scalar(q: jax.Array, k: jax.Array, v: jax.Array,
 
 # ----------------------------------------------------------- vector decay
 def _vector_kernel(q_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, s_out_ref,
-                   s_ref, y_acc_ref,
+                   s_ref, q_s, k_s, v_s, w_s, y_acc_ref,
                    *, chunk: int, n_chunks: int):
     ci = pl.program_id(1)
 
@@ -126,27 +134,34 @@ def _vector_kernel(q_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, s_out_ref,
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    q = q_ref[0].astype(jnp.float32)          # (C, dk)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)          # (C, dv)
-    w = jnp.exp(lw_ref[0].astype(jnp.float32))  # (C, dk)
-    u = u_ref[0].astype(jnp.float32)          # (dk,)
+    # stage the chunk in f32 scratch: the token loop reads one row of each
+    q_s[...] = q_ref[0].astype(jnp.float32)   # (C, dk)
+    k_s[...] = k_ref[0].astype(jnp.float32)
+    v_s[...] = v_ref[0].astype(jnp.float32)   # (C, dv)
+    w_s[...] = jnp.exp(lw_ref[0].astype(jnp.float32))
+    u = u_ref[0].astype(jnp.float32)          # (1, dk)
+    n = s_ref.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+
+    def column(x):
+        """(1, n) -> (n, 1) as a masked reduction (no small transposes
+        in the TPU lowering)."""
+        return jnp.sum(jnp.where(eye, x, 0.0), axis=1, keepdims=True)
 
     def step(t, _):
-        qt = jax.lax.dynamic_slice_in_dim(q, t, 1, 0)      # (1, dk)
-        kt = jax.lax.dynamic_slice_in_dim(k, t, 1, 0)
-        vt = jax.lax.dynamic_slice_in_dim(v, t, 1, 0)      # (1, dv)
-        wt = jax.lax.dynamic_slice_in_dim(w, t, 1, 0)      # (1, dk)
+        qt = q_s[pl.ds(t, 1), :]                            # (1, dk)
+        kt = k_s[pl.ds(t, 1), :]
+        vt = v_s[pl.ds(t, 1), :]                            # (1, dv)
+        wt = w_s[pl.ds(t, 1), :]                            # (1, dk)
         # exclusive + bonus-u (RWKV6): y = q·S_prev + (q·(u⊙k)) v
         y_hist = jax.lax.dot_general(qt, s_ref[...],
                                      (((1,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
-        bonus = jnp.sum(qt * u[None, :] * kt, axis=-1, keepdims=True)
-        yt = y_hist + bonus * vt                           # (1, dv)
-        y_acc_ref[...] = jax.lax.dynamic_update_slice_in_dim(
-            y_acc_ref[...], yt, t, 0)
+        bonus = jnp.sum(qt * u * kt, axis=-1, keepdims=True)
+        y_acc_ref[pl.ds(t, 1), :] = y_hist + bonus * vt
         # S = diag(w)·S + kᵀ v
-        s_ref[...] = s_ref[...] * wt.T + kt.T * vt         # (dk, dv)
+        s_ref[...] = s_ref[...] * column(wt) + column(kt) * vt
         return ()
 
     jax.lax.fori_loop(0, chunk, step, ())
@@ -172,7 +187,7 @@ def linear_scan_vector(q: jax.Array, k: jax.Array, v: jax.Array,
     kr = k.reshape(bh, T, n)
     vr = v.reshape(bh, T, n)
     lwr = log_w.reshape(bh, T, n)
-    ur = jnp.broadcast_to(u[None], (B, H, n)).reshape(bh, n)
+    ur = jnp.broadcast_to(u[None], (B, H, n)).reshape(bh, 1, n)
 
     y, s = pl.pallas_call(
         functools.partial(_vector_kernel, chunk=chunk, n_chunks=nc),
@@ -182,7 +197,7 @@ def linear_scan_vector(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, n), lambda b, c: (b, 0)),
+            pl.BlockSpec((1, 1, n), lambda b, c: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
@@ -192,10 +207,8 @@ def linear_scan_vector(q: jax.Array, k: jax.Array, v: jax.Array,
             jax.ShapeDtypeStruct((bh, T, n), v.dtype),
             jax.ShapeDtypeStruct((bh, n, n), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((n, n), jnp.float32),
-            pltpu.VMEM((chunk, n), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)]
+        + [pltpu.VMEM((chunk, n), jnp.float32) for _ in range(5)],
         interpret=interpret,
     )(qr, kr, vr, lwr, ur)
     return y.reshape(B, H, T, n), s.reshape(B, H, n, n)

@@ -37,16 +37,10 @@ __all__ = ["analyze_hlo", "normalize_cost_analysis", "HloStats"]
 
 
 def normalize_cost_analysis(ca) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions.
-
-    Some versions (e.g. 0.4.3x) return a one-entry *list* of per-program
-    dicts, others a plain dict, and it may be None for empty programs —
-    always return a dict so callers can index ``["flops"]`` safely.
-    """
+    """``Compiled.cost_analysis()`` as a dict: it is None for empty
+    programs, so callers can always index ``["flops"]``."""
     if ca is None:
         return {}
-    if isinstance(ca, (list, tuple)):
-        return dict(ca[0]) if ca else {}
     return dict(ca)
 
 _DTYPE_BYTES = {

@@ -16,7 +16,7 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 
 from repro.models.config import ModelConfig
 
@@ -61,15 +61,9 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
     if len(devs) < n:
         raise ValueError(f"mesh {tuple(shape)} needs {n} devices, "
                          f"only {len(devs)} available")
-    try:                       # jax >= 0.5: explicit-sharding axis types
-        from jax.sharding import AxisType
-    except ImportError:        # jax 0.4.x: meshes are implicitly Auto
-        AxisType = None
-    if AxisType is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(AxisType.Auto,) * len(shape),
-                             devices=devs[:n])
-    return jax.make_mesh(tuple(shape), tuple(axes), devices=devs[:n])
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devs[:n])
 
 
 # A rule maps a logical axis name to a mesh axis (or tuple of axes, or None).

@@ -85,6 +85,7 @@ from repro.core import (LoopHistory, LoopSpec, LoopTelemetry,
                         MembershipEvent, SchedulerContext, ServeMeter,
                         get_engine)
 from repro.core.spec import SpecLike, describe, resolve
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import (make_fused_serve_step, make_paged_prefill_step,
                                 make_paged_serve_step, make_prefill_step,
                                 make_serve_step)
@@ -143,6 +144,8 @@ class ServeLoop:
     serving steady state's feedback channel.  After each run,
     ``last_stats`` holds the telemetry summary (per-slot busy time,
     tokens, tok/s, decode dispatch counts, truncations, measured epoch).
+    Parameters and KV cache are bfloat16, as in training, so a published
+    config fits one accelerator.
     """
 
     def __init__(self, cfg, *, slots: int = 4, max_len: int = 256,
@@ -157,7 +160,7 @@ class ServeLoop:
         if decode_steps < 1:
             raise ValueError(f"decode_steps must be >= 1, got {decode_steps}")
         key = jax.random.PRNGKey(seed)
-        self.params, _ = self.model.init(key, jnp.float32)
+        self.params, _ = self.model.init(key, jnp.bfloat16)
         # any schedule-clause form: spec, "guided,4", "uds:name", "runtime",
         # or a scheduler instance
         self.scheduler = scheduler
@@ -184,22 +187,28 @@ class ServeLoop:
             # one stacked [slots, max_len] cache, per-slot lengths; ONE
             # jitted dispatch per decode_steps tokens across all active
             # slots (an on-device scan with per-slot stop handling)
+            # the cache argument is donated: each dispatch updates it in
+            # place instead of holding an input and an output copy
             self._decode_fused = jax.jit(
-                make_fused_serve_step(self.model, self.decode_steps))
-            self._insert = jax.jit(self.model.insert_prefill)
-            self.cache = self.model.init_batched_decode(
-                slots, max_len, dtype=jnp.float32)[0]
+                make_fused_serve_step(self.model, self.decode_steps),
+                donate_argnums=(2,))
+            self._insert = jax.jit(self.model.insert_prefill,
+                                   donate_argnums=(0,))
+            self.cache = self.model.init_batched_decode(slots, max_len)[0]
             self.caches = None
         else:
             # per-slot state: one cache per slot (batch=1), one jit call
             # per active slot per token — the escape hatch / SSM path
-            self._decode = jax.jit(make_serve_step(self.model))
-            self.caches = [self.model.init_decode(1, max_len,
-                                                  dtype=jnp.float32)[0]
+            self._decode = jax.jit(make_serve_step(self.model),
+                                   donate_argnums=(2,))
+            self.caches = [self.model.init_decode(1, max_len)[0]
                            for _ in range(slots)]
         self.active: Dict[int, Request] = {}
         self._dispatches = 0
         self._decoded = 0
+        # last-position logits (V,) of the most recent prefill, kept for
+        # comparison against a reference forward pass
+        self.last_prefill_logits: Optional[jax.Array] = None
 
     @property
     def mode(self) -> str:
@@ -245,6 +254,7 @@ class ServeLoop:
             self.cache = self._insert(self.cache, cache, slot)
         else:
             self.caches[slot] = cache
+        self.last_prefill_logits = logits[0]
         tok = int(jnp.argmax(logits, -1)[0])
         req.generated = [tok]
         return tok
@@ -497,6 +507,7 @@ class PagedServeLoop:
     ``max_len``); budgets clamp/truncate against it exactly as in
     :class:`ServeLoop`.  ``concurrency`` is only the fused dispatch's
     batch width (compiled once) — memory admission happens first.
+    Parameters and the KV pool are bfloat16.
     """
 
     def __init__(self, cfg, *, num_blocks: int = 64, block_size: int = 8,
@@ -532,7 +543,7 @@ class PagedServeLoop:
             raise ValueError(
                 "kill_rows and kill_at_dispatch must be given together")
         self.params, _ = self.model.init(jax.random.PRNGKey(seed),
-                                         jnp.float32)
+                                         jnp.bfloat16)
         self.scheduler = scheduler
         self.sched_name = describe(scheduler)
         self.loop_id = "serve_paged"
@@ -548,15 +559,19 @@ class PagedServeLoop:
         self.pool = BlockPool(num_blocks, block_size)
         self.tables = BlockTables(self.pool,
                                   max_blocks=self.max_blocks_per_seq)
-        self.cache = self.model.init_paged_decode(num_blocks, block_size,
-                                                  dtype=jnp.float32)[0]
+        self.cache = self.model.init_paged_decode(num_blocks,
+                                                  block_size)[0]
         # one compile per prefill BUCKET (chunks are bucket-padded) and
-        # ONE decode program (fixed (concurrency, W) dispatch shape)
-        self._prefill_step = jax.jit(make_paged_prefill_step(self.model))
+        # ONE decode program (fixed (concurrency, W) dispatch shape); both
+        # donate the pool, so it is updated in place
+        self._prefill_step = jax.jit(make_paged_prefill_step(self.model),
+                                     donate_argnums=(2,))
         self._decode = jax.jit(make_paged_serve_step(self.model,
-                                                     decode_steps))
+                                                     decode_steps),
+                               donate_argnums=(2,))
         self.active: Dict[int, Request] = {}        # dispatch row -> req
         self.last_stats: Dict[str, Any] = {}
+        self.last_prefill_logits: Optional[np.ndarray] = None
         self._dispatches = 0
         self._decoded = 0
         self._pf_dispatches = 0
@@ -755,6 +770,7 @@ class PagedServeLoop:
                 if pf.idx == len(pf.sizes):     # prompt fully cached
                     req = pf.req
                     pf = None
+                    self.last_prefill_logits = logits[0]
                     tok = int(np.argmax(logits[0]))
                     if req.generated is None:
                         req.generated = []
@@ -933,6 +949,7 @@ def main() -> None:
                          "injected kill fires")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     rng = np.random.default_rng(0)
     reqs = [Request(rid=i,

@@ -39,6 +39,7 @@ from repro.core import (Chunk, LoopHistory, LoopTelemetry, MembershipEvent,
                         get_engine)
 from repro.core.spec import SpecLike, resolve
 from repro.data import SyntheticCorpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import (batch_shardings, make_host_mesh, make_mesh,
                                rules_for, shardings_for)
 from repro.launch.steps import (apply_microbatch_plan, make_fused_train_step,
@@ -209,14 +210,16 @@ class TrainLoop:
 
         key = jax.random.PRNGKey(seed)
         with self.mesh, axis_rules(self.mesh, self.rules):
-            params, specs = self.model.init(key, jnp.bfloat16)
-            pshard = shardings_for(specs, self.rules, self.mesh, tree=params)
-            params = jax.device_put(params, pshard)
-            opt_state = opt_init(params)
+            # parameters and optimizer state are built directly in their
+            # shardings: no device ever holds the whole unsharded state
+            shapes, specs = self.model.init(key, jnp.bfloat16, abstract=True)
+            pshard = shardings_for(specs, self.rules, self.mesh, tree=shapes)
+            params = jax.jit(lambda k: self.model.init(k, jnp.bfloat16)[0],
+                             out_shardings=pshard)(key)
             oshard = shardings_for(
-                opt_state_specs(cfg.optimizer, params, specs),
-                self.rules, self.mesh, tree=opt_state)
-            opt_state = jax.device_put(opt_state, oshard)
+                opt_state_specs(cfg.optimizer, shapes, specs),
+                self.rules, self.mesh, tree=jax.eval_shape(opt_init, shapes))
+            opt_state = jax.jit(opt_init, out_shardings=oshard)(params)
         self.params, self.opt_state = params, opt_state
         self.pshard, self.oshard = pshard, oshard
         self.specs = specs
@@ -613,6 +616,7 @@ def main() -> None:
                          "(between batch planning and execution)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     kill_hosts = ([int(h) for h in args.kill_hosts.split(",")]
                   if args.kill_hosts else None)
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)

@@ -17,6 +17,7 @@ Conventions
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -34,6 +35,15 @@ __all__ = [
 ]
 
 Tree = Dict[str, Any]
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "dtype"))
+def _scaled_normal(key: jax.Array, scale: float, *, shape: Tuple[int, ...],
+                   dtype: jnp.dtype) -> jax.Array:
+    """normal(0, scale) drawn in float32 and cast, as one compiled program:
+    the float32 draw is fused away, so building a tensor needs no more
+    memory than the tensor itself."""
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
 
 class ParamBuilder:
@@ -69,8 +79,8 @@ class ParamBuilder:
             if scale is None:
                 fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
                 scale = 1.0 / math.sqrt(max(fan_in, 1))
-            arr = (jax.random.normal(self._next(), shape, jnp.float32)
-                   * scale).astype(self.dtype)
+            arr = _scaled_normal(self._next(), scale, shape=tuple(shape),
+                                 dtype=self.dtype)
         self._set(path, arr, tuple(axes))
 
     def ones(self, path: str, shape: Tuple[int, ...],

@@ -1,0 +1,138 @@
+"""Compiles for a described TPU v5e: the serving programs of a published
+config and the Pallas kernels, at real widths, with no chip attached.
+
+The TPU compiler refuses what interpret mode accepts: a block shape not
+aligned to the (8, 128) tiling, a primitive the kernel lowering lacks, a
+program larger than the chip's memory.  Each compile here takes seconds
+and needs no chip.  The topology is described inside a fixture, never at
+import: only the worker that runs this file loads the TPU library.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.launch.steps import make_paged_prefill_step, make_paged_serve_step
+from repro.models import get_model
+
+# one v5e chip: 16 GiB of HBM, of which the compiler lets a program use
+# 15.75 GiB (the limit it names when it refuses one)
+V5E_HBM_BYTES = 15.75 * 2 ** 30
+
+# the chip_smoke.py serving shapes (qwen2.5-3b at published widths)
+BLOCK_SIZE = 16
+MAX_CONTEXT = 2048
+NUM_BLOCKS = 640
+CONCURRENCY = 8
+PREFILL_CHUNK = 512
+DECODE_STEPS = 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - any failure means no TPU compiler
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip can be written to the persistent
+        # cache but never read back without one: keep it out of the cache
+        prev = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", prev)
+            compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+
+
+@pytest.fixture(scope="module")
+def qwen(one_chip):
+    """qwen2.5-3b at published widths in bf16, as shapes on one chip."""
+    cfg = get_config("qwen2.5-3b")
+    model = get_model(cfg)
+    params, _ = model.init(jax.random.PRNGKey(0), jnp.bfloat16,
+                           abstract=True)
+    pool, _ = model.init_paged_decode(NUM_BLOCKS, BLOCK_SIZE, abstract=True)
+    return cfg, model, _on(one_chip, params), _on(one_chip, pool)
+
+
+def test_paged_decode_fits_one_v5e(one_chip, qwen):
+    cfg, model, params, pool = qwen
+    C, W = CONCURRENCY, MAX_CONTEXT // BLOCK_SIZE
+    i32 = jnp.int32
+    step = jax.jit(make_paged_serve_step(model, DECODE_STEPS),
+                   donate_argnums=(2,))
+    compiled = step.lower(
+        params, {"tokens": _sds(one_chip, (C, 1), i32)}, pool,
+        _sds(one_chip, (C, W), i32), _sds(one_chip, (C,), i32),
+        _sds(one_chip, (C,), i32), _sds(one_chip, (C,), jnp.bool_),
+        _sds(one_chip, (C,), i32), _sds(one_chip, (), i32)).compile()
+    assert cfg.num_layers == 36 and cfg.vocab_size == 151936
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_paged_prefill_chunk_fits_one_v5e(one_chip, qwen):
+    _, model, params, pool = qwen
+    W = MAX_CONTEXT // BLOCK_SIZE
+    i32 = jnp.int32
+    step = jax.jit(make_paged_prefill_step(model), donate_argnums=(2,))
+    compiled = step.lower(
+        params, {"tokens": _sds(one_chip, (1, PREFILL_CHUNK), i32)}, pool,
+        _sds(one_chip, (W,), i32), _sds(one_chip, (), i32),
+        _sds(one_chip, (), i32)).compile()
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def _kernel_cases():
+    from repro.kernels.flash_attention.ops import mha
+    from repro.kernels.linear_scan.ops import ssd, wkv
+    from repro.kernels.sched_matmul.ops import scheduled_matmul
+    bf, f32 = jnp.bfloat16, jnp.float32
+    attn = (1, 4096, 16, 128)          # qwen2.5-3b heads at 4k tokens
+    rwkv = (1, 40, 4096, 64)           # rwkv6-3b: 40 wkv heads of 64
+    mamba = (1, 80, 4096, 64)          # zamba2-2.7b: 80 heads, state 64
+    return {
+        "sched_matmul": (lambda a, b: scheduled_matmul(a, b),
+                         [((2048, 2048), bf), ((2048, 11008), bf)]),
+        "flash_attention": (lambda q, k, v: mha(q, k, v, causal=True),
+                            [(attn, bf)] * 3),
+        "linear_scan_wkv": (lambda r, k, v, lw, u: wkv(r, k, v, lw, u,
+                                                       chunk=32),
+                            [(rwkv, bf)] * 3 + [(rwkv, f32),
+                                                ((40, 64), f32)]),
+        "linear_scan_ssd": (lambda c, b, x, la: ssd(c, b, x, la, chunk=32),
+                            [(mamba, bf)] * 3 + [(mamba[:3], f32)]),
+    }
+
+
+@pytest.mark.parametrize("name", ["sched_matmul", "flash_attention",
+                                  "linear_scan_wkv", "linear_scan_ssd"])
+def test_pallas_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = _kernel_cases()[name]
+    args = [_sds(one_chip, s, d) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
