@@ -42,7 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from repro.core.history import ChunkRecord, LoopHistory
 from repro.core.interface import Chunk
@@ -99,7 +99,7 @@ def _percentile(xs: List[float], q: float) -> Optional[float]:
 
 
 class ServeMeter:
-    """Per-request serving observability: latency stamps + KV residency.
+    """Per-request serving observability: latency percentiles + KV residency.
 
     The continuous-batching engine makes admission a *scheduling* decision
     (blocks free? chunk budget?), so the interesting latencies live
@@ -110,20 +110,17 @@ class ServeMeter:
       prefill time as the request experiences it),
     * ``e2e``        — arrival → finish.
 
-    The loop calls :meth:`arrive` / :meth:`admit` / :meth:`first_token` /
-    :meth:`finish` / :meth:`preempt` with its own clock value (pass
-    ``time.perf_counter()``), and :meth:`blocks` whenever pool occupancy
-    changes; :meth:`summary` reduces to the p50/p99 dictionary that
-    ``last_stats`` and BENCH_serve.json carry.  A preempted request keeps
-    its original arrival/admission stamps — preemption inflates its e2e
-    latency, which is exactly what the percentiles should see.
+    The stamps are the requests' own (``t_arrive`` / ``t_admit`` /
+    ``t_first`` / ``t_finish``, set by the serve loops);
+    :meth:`summary` reduces them to the p50/p99 dictionary that
+    ``last_stats`` and BENCH_serve.json carry.  The loop calls
+    :meth:`preempt` on every eviction and :meth:`blocks` whenever pool
+    occupancy changes (pass ``time.perf_counter()``).  A preempted request
+    keeps its original arrival/admission stamps — preemption inflates its
+    e2e latency, which is exactly what the percentiles should see.
     """
 
     def __init__(self) -> None:
-        self._arrive: Dict[int, float] = {}
-        self._admit: Dict[int, float] = {}
-        self._first: Dict[int, float] = {}
-        self._finish: Dict[int, float] = {}
         self.preemptions = 0
         self.preempted_rids: List[int] = []
         # time-weighted pool utilization: integral of used/total dt
@@ -132,21 +129,6 @@ class ServeMeter:
         self._blk_total = 0
         self._blk_area = 0.0
         self._blk_span = 0.0
-
-    # ---------------------------------------------------------- lifecycle
-    def arrive(self, rid: int, t: float) -> None:
-        self._arrive.setdefault(rid, t)
-
-    def admit(self, rid: int, t: float) -> None:
-        """First admission only: readmission after preemption does not
-        reset the stamp (the wait is part of the request's latency)."""
-        self._admit.setdefault(rid, t)
-
-    def first_token(self, rid: int, t: float) -> None:
-        self._first.setdefault(rid, t)
-
-    def finish(self, rid: int, t: float) -> None:
-        self._finish.setdefault(rid, t)
 
     def preempt(self, rid: int) -> None:
         self.preemptions += 1
@@ -165,18 +147,23 @@ class ServeMeter:
         self._blk_total = int(total)
 
     # ------------------------------------------------------------ summary
-    def _lat(self, a: Dict[int, float], b: Dict[int, float]) -> List[float]:
-        return [b[r] - a[r] for r in b if r in a]
+    @staticmethod
+    def _lat(requests: Sequence[Any], a: str, b: str) -> List[float]:
+        pairs = ((getattr(r, a), getattr(r, b)) for r in requests)
+        return [tb - ta for ta, tb in pairs if ta is not None and tb is not None]
 
-    def summary(self) -> Dict[str, Any]:
-        queue = self._lat(self._arrive, self._admit)
-        admission = self._lat(self._admit, self._first)
-        e2e = self._lat(self._arrive, self._finish)
+    def summary(self, requests: Sequence[Any]) -> Dict[str, Any]:
+        """Percentiles over ``requests``' lifecycle stamps, and the pool
+        gauge and preemption count this meter recorded."""
+        queue = self._lat(requests, "t_arrive", "t_admit")
+        admission = self._lat(requests, "t_admit", "t_first")
+        e2e = self._lat(requests, "t_arrive", "t_finish")
         util = (self._blk_area / self._blk_span
                 if self._blk_span > 0 else None)
         return {
-            "requests_seen": len(self._arrive),
-            "requests_finished": len(self._finish),
+            "requests_seen": sum(r.t_arrive is not None for r in requests),
+            "requests_finished": sum(r.t_finish is not None
+                                     for r in requests),
             "queue_p50_s": _percentile(queue, 50),
             "queue_p99_s": _percentile(queue, 99),
             "admission_p50_s": _percentile(admission, 50),

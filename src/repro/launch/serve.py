@@ -79,6 +79,7 @@ from typing import Any, Deque, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import get_config, get_smoke_config
 from repro.core import (LoopHistory, LoopSpec, LoopTelemetry,
@@ -280,7 +281,6 @@ class ServeLoop:
         for req in requests:
             if req.t_arrive is None:
                 req.t_arrive = now
-            meter.arrive(req.rid, req.t_arrive)
         queue: Deque[Request] = deque(requests)
         pending: Dict[int, Deque[Request]] = {s: deque()
                                               for s in range(self.slots)}
@@ -301,7 +301,6 @@ class ServeLoop:
         def finish(s: int, req: Request) -> None:
             results[req.rid] = req.generated
             req.t_finish = time.perf_counter()
-            meter.finish(req.rid, req.t_finish)
             if req.truncated:
                 truncated.append(req.rid)
 
@@ -328,12 +327,10 @@ class ServeLoop:
                     t0 = time.perf_counter()
                     if req.t_admit is None:
                         req.t_admit = t0
-                    meter.admit(req.rid, t0)
                     tok = self._prefill_into(s, req)
                     t1 = time.perf_counter()
                     if req.t_first is None:
                         req.t_first = t1
-                    meter.first_token(req.rid, t1)
                     telemetry.add_time(s, t1 - t0, tokens=1)
                     progressed = True
                     if self._finished_at_admission(req, tok):
@@ -418,7 +415,7 @@ class ServeLoop:
             else None)
         self.last_stats["truncated"] = sorted(truncated)
         self.last_stats["prefill_compiles"] = self.prefill_compiles
-        self.last_stats["serve_meter"] = meter.summary()
+        self.last_stats["serve_meter"] = meter.summary(requests)
         return results
 
     def measured_epoch(self) -> int:
@@ -585,8 +582,12 @@ class PagedServeLoop:
         self._dead_rows: set = set()
         self.membership_events: List[MembershipEvent] = []
         # per-dispatch measurement log (elastic_recovery bench splits it
-        # at the kill dispatch): wall time, produced tokens, live rows
+        # at the kill dispatch): wall time, produced tokens, live rows,
+        # each row's cached fill and tokens made, and the context
+        # positions the program's attention read
         self.dispatch_log: List[Dict[str, Any]] = []
+        # its prefill twin: one {rid, start, length, bucket} per chunk
+        self.prefill_log: List[Dict[str, int]] = []
 
     @property
     def mode(self) -> str:
@@ -609,7 +610,16 @@ class PagedServeLoop:
         return int(req.prompt.size) + len(req.generated) - 1
 
     def run(self, requests: List[Request]) -> Dict[int, List[int]]:
-        """Admit, prefill, decode, preempt as needed — to completion."""
+        """Admit, prefill, decode, preempt as needed — to completion.
+
+        Each phase runs inside a ``serve.*`` profiler span (see
+        docs/SCHEDULING.md, "Tracing the paged loop"); every prefill chunk
+        appends to ``prefill_log`` and every decode dispatch to
+        ``dispatch_log``."""
+        with TraceAnnotation("serve.run", requests=len(requests)):
+            return self._run(requests)
+
+    def _run(self, requests: List[Request]) -> Dict[int, List[int]]:
         meter = ServeMeter()
         telemetry = LoopTelemetry(self.history, loop_id=self.loop_id,
                                   num_workers=1)
@@ -619,7 +629,6 @@ class PagedServeLoop:
         for req in requests:
             if req.t_arrive is None:
                 req.t_arrive = now
-            meter.arrive(req.rid, req.t_arrive)
         meter.blocks(self.pool.used, self.pool.num_blocks, now)
         queue: Deque[Request] = deque(requests)
         requeue: Deque[Request] = deque()     # preempted; front of the line
@@ -628,18 +637,22 @@ class PagedServeLoop:
         pf: Optional[_Prefill] = None
         admit_seq = 0
         peak_conc = 0
+        turn = 0
         self._dispatches = 0
         self._decoded = 0
         self._pf_dispatches = 0
         self.dispatch_log = []
+        self.prefill_log = []
         C, W = self.concurrency, self.max_blocks_per_seq
+        # context positions one dispatch's attention reads: every row's
+        # whole gathered view, each step (see gather_kv_paged)
+        read_positions = C * W * self.block_size * self.decode_steps
         eos_arr = jnp.asarray(-1 if self.eos_id is None else self.eos_id,
                               jnp.int32)
 
         def finish(req: Request) -> None:
             results[req.rid] = req.generated
             req.t_finish = time.perf_counter()
-            meter.finish(req.rid, req.t_finish)
             if req.truncated:
                 truncated.append(req.rid)
             self.tables.release(req.rid)
@@ -655,225 +668,251 @@ class PagedServeLoop:
                 return False
             victim = max(rows, key=lambda r: self.active[r].admit_seq)
             rq = self.active.pop(victim)
-            self.tables.release(rq.rid)
-            rq.preemptions += 1
-            meter.preempt(rq.rid)
-            meter.blocks(self.pool.used, self.pool.num_blocks,
-                         time.perf_counter())
-            requeue.appendleft(rq)
+            with TraceAnnotation("serve.preempt", rid=rq.rid):
+                self.tables.release(rq.rid)
+                rq.preemptions += 1
+                meter.preempt(rq.rid)
+                meter.blocks(self.pool.used, self.pool.num_blocks,
+                             time.perf_counter())
+                requeue.appendleft(rq)
             return True
 
         while len(results) < len(requests):
-            progressed = False
-            ran_prefill = False
+            with TraceAnnotation("serve.turn", turn=turn):
+                turn += 1
+                progressed = False
+                ran_prefill = False
 
-            # ---- injected worker kill: a slot-set shrink is a membership
-            # event.  The doomed rows' in-flight requests drain through
-            # the evict-requeue machinery (blocks freed, front of the
-            # line) and readmit on surviving rows; greedy decode makes
-            # every resumed request token-for-token identical to an
-            # unkilled run.  The fused dispatch keeps its compiled
-            # (C, W) shape — dead rows just stay mask-gated off.
-            if (self._kill_at is not None and not self._kill_fired
-                    and self._dispatches >= self._kill_at):
-                self._kill_fired = True
-                doomed = set(range(C - self._kill_rows, C))
-                self._dead_rows |= doomed
-                # evict newest-first so appendleft leaves the requeue in
-                # admit order (oldest victim readmits first)
-                for r in sorted((r for r in doomed if r in self.active),
-                                key=lambda r: self.active[r].admit_seq,
-                                reverse=True):
-                    rq = self.active.pop(r)
-                    self.tables.release(rq.rid)
-                    rq.preemptions += 1
-                    meter.preempt(rq.rid)
-                    requeue.appendleft(rq)
-                meter.blocks(self.pool.used, self.pool.num_blocks,
-                             time.perf_counter())
-                event = MembershipEvent(
-                    kind="loss", old_size=C,
-                    new_size=C - len(self._dead_rows),
-                    lost=tuple(sorted(doomed)), step=self._dispatches)
-                telemetry.record_membership(event)
-                # the serve loop's telemetry worker is the fused
-                # dispatcher, not a row — keep the summary single-worker
-                telemetry.num_workers = 1
-                self.membership_events.append(event)
+                # ---- injected worker kill: a slot-set shrink is a membership
+                # event.  The doomed rows' in-flight requests drain through
+                # the evict-requeue machinery (blocks freed, front of the
+                # line) and readmit on surviving rows; greedy decode makes
+                # every resumed request token-for-token identical to an
+                # unkilled run.  The fused dispatch keeps its compiled
+                # (C, W) shape — dead rows just stay mask-gated off.
+                if (self._kill_at is not None and not self._kill_fired
+                        and self._dispatches >= self._kill_at):
+                    self._kill_fired = True
+                    doomed = set(range(C - self._kill_rows, C))
+                    self._dead_rows |= doomed
+                    # evict newest-first so appendleft leaves the requeue in
+                    # admit order (oldest victim readmits first)
+                    for r in sorted((r for r in doomed if r in self.active),
+                                    key=lambda r: self.active[r].admit_seq,
+                                    reverse=True):
+                        rq = self.active.pop(r)
+                        self.tables.release(rq.rid)
+                        rq.preemptions += 1
+                        meter.preempt(rq.rid)
+                        requeue.appendleft(rq)
+                    meter.blocks(self.pool.used, self.pool.num_blocks,
+                                 time.perf_counter())
+                    event = MembershipEvent(
+                        kind="loss", old_size=C,
+                        new_size=C - len(self._dead_rows),
+                        lost=tuple(sorted(doomed)), step=self._dispatches)
+                    telemetry.record_membership(event)
+                    # the serve loop's telemetry worker is the fused
+                    # dispatcher, not a row — keep the summary single-worker
+                    telemetry.num_workers = 1
+                    self.membership_events.append(event)
 
-            # ---- admission: memory first (blocks for the prompt), then a
-            # dispatch row; preempted requests readmit ahead of the queue
-            if (pf is None and (requeue or queue)
-                    and len(self.active) < C - len(self._dead_rows)):
-                src = requeue if requeue else queue
-                req = src[0]
-                if req.budget == 0:    # first admission: fix the budget
-                    P = int(req.prompt.size)
-                    capacity = self.max_context - P + 1
-                    if capacity < 1:
-                        raise ValueError(
-                            f"request {req.rid}: prompt ({P} tokens) "
-                            f"exceeds max_context={self.max_context}; "
-                            f"raise PagedServeLoop max_context or shorten "
-                            f"the request")
-                    req.budget = min(req.max_new, capacity)
-                    req.truncated = req.budget < req.max_new
-                tokens = req.prompt
-                if req.generated:      # readmission: replay the prefix
-                    tokens = np.concatenate(
-                        [tokens, np.asarray(req.generated, np.int32)])
-                n_all = int(tokens.size)
-                if self.tables.ensure(req.rid, n_all):
-                    src.popleft()
-                    req.admit_seq = admit_seq
-                    admit_seq += 1
-                    t = time.perf_counter()
-                    if req.t_admit is None:
-                        req.t_admit = t
-                    meter.admit(req.rid, t)
-                    meter.blocks(self.pool.used, self.pool.num_blocks, t)
-                    pf = _Prefill(req=req, tokens=tokens,
-                                  sizes=plan_prefill_chunks(
-                                      self.scheduler, n_all,
-                                      max_chunk=self.prefill_chunk,
-                                      history=self.history))
-                    progressed = True
-                elif not self.active:
-                    # every block is free and the prompt still doesn't
-                    # fit: the pool itself is too small for this request
-                    raise ValueError(
-                        f"request {req.rid}: {n_all} tokens need "
-                        f"{blocks_for_tokens(n_all, self.block_size)} "
-                        f"blocks but the pool has {self.pool.num_blocks}; "
-                        f"raise num_blocks")
-
-            # ---- one prefill chunk per turn while admission can progress
-            if pf is not None:
-                ran_prefill = True
-                n = pf.sizes[pf.idx]
-                pb = bucket_length(n, self.prefill_chunk)
-                buf = np.zeros((1, pb), np.int32)
-                buf[0, :n] = pf.tokens[pf.start:pf.start + n]
-                t0 = time.perf_counter()
-                logits, self.cache = self._prefill_step(
-                    self.params, {"tokens": jnp.asarray(buf)}, self.cache,
-                    jnp.asarray(self.tables.row(pf.req.rid)),
-                    jnp.asarray(pf.start, jnp.int32),
-                    jnp.asarray(n, jnp.int32))
-                logits = np.asarray(logits)     # sync: true chunk time
-                dt = time.perf_counter() - t0
-                pf_tel.record_chunk(0, pf.start, pf.start + n, dt, tokens=n)
-                self._pf_dispatches += 1
-                pf.start += n
-                pf.idx += 1
-                progressed = True
-                if pf.idx == len(pf.sizes):     # prompt fully cached
-                    req = pf.req
-                    pf = None
-                    self.last_prefill_logits = logits[0]
-                    tok = int(np.argmax(logits[0]))
-                    if req.generated is None:
-                        req.generated = []
-                    req.generated.append(tok)
-                    t1 = time.perf_counter()
-                    if req.t_first is None:
-                        req.t_first = t1
-                    meter.first_token(req.rid, t1)
-                    done = len(req.generated) >= req.budget
-                    if self.eos_id is not None and tok == self.eos_id:
-                        done = True
-                    if done:
-                        finish(req)
-                    else:
-                        row = min(r for r in range(C)
-                                  if r not in self.active
-                                  and r not in self._dead_rows)
-                        self.active[row] = req
-                        peak_conc = max(peak_conc, len(self.active))
-
-            # ---- one fused decode dispatch across every active row.
-            # Admission has priority: decode runs when prefill could NOT
-            # progress this turn (queue empty, pool full, or concurrency
-            # cap) — occupancy builds while blocks are free, and under
-            # memory pressure the loop alternates admission attempts with
-            # decode dispatches at chunk granularity, which is exactly the
-            # prefill/decode interleave the scheduler clause governs.
-            if self.active and not ran_prefill:
-                # grow tables oldest-first so the head of the line wins
-                # under pressure; LIFO victims free blocks as needed
-                for r in sorted(self.active,
-                                key=lambda r: self.active[r].admit_seq):
-                    if r not in self.active:    # preempted this turn
-                        continue
-                    rq = self.active[r]
-                    total_need = int(rq.prompt.size) + rq.budget - 1
-                    need = min(self._fill_of(rq) + self.decode_steps,
-                               total_need)
-                    while not self.tables.ensure(rq.rid, need):
-                        if not preempt_one(exclude_rid=rq.rid):
+                # ---- admission: memory first (blocks for the prompt), then a
+                # dispatch row; preempted requests readmit ahead of the queue
+                if (pf is None and (requeue or queue)
+                        and len(self.active) < C - len(self._dead_rows)):
+                    src = requeue if requeue else queue
+                    req = src[0]
+                    # readmission replays the generated prefix
+                    n_all = int(req.prompt.size) + len(req.generated or ())
+                    with TraceAnnotation("serve.admit", rid=req.rid,
+                                         tokens=n_all):
+                        if req.budget == 0:    # first admission: fix the budget
+                            P = int(req.prompt.size)
+                            capacity = self.max_context - P + 1
+                            if capacity < 1:
+                                raise ValueError(
+                                    f"request {req.rid}: prompt ({P} tokens) "
+                                    f"exceeds max_context={self.max_context}; "
+                                    f"raise PagedServeLoop max_context or "
+                                    f"shorten the request")
+                            req.budget = min(req.max_new, capacity)
+                            req.truncated = req.budget < req.max_new
+                        if self.tables.ensure(req.rid, n_all):
+                            src.popleft()
+                            req.admit_seq = admit_seq
+                            admit_seq += 1
+                            t = time.perf_counter()
+                            if req.t_admit is None:
+                                req.t_admit = t
+                            meter.blocks(self.pool.used, self.pool.num_blocks, t)
+                            tokens = req.prompt
+                            if req.generated:
+                                tokens = np.concatenate(
+                                    [tokens, np.asarray(req.generated, np.int32)])
+                            with TraceAnnotation("serve.plan",
+                                                 rid=req.rid) as span:
+                                sizes = plan_prefill_chunks(
+                                    self.scheduler, n_all,
+                                    max_chunk=self.prefill_chunk,
+                                    history=self.history)
+                                span.set_metadata(chunks=len(sizes))
+                            pf = _Prefill(req=req, tokens=tokens, sizes=sizes)
+                            progressed = True
+                        elif not self.active:
+                            # every block is free and the prompt still doesn't
+                            # fit: the pool itself is too small for this request
                             raise ValueError(
-                                f"request {rq.rid}: cannot grow to {need} "
-                                f"tokens with every other request evicted "
-                                f"— the pool ({self.num_blocks} blocks) "
-                                f"is smaller than one request's context; "
-                                f"raise num_blocks")
-                meter.blocks(self.pool.used, self.pool.num_blocks,
-                             time.perf_counter())
-                rows = sorted(self.active)
-                last = np.zeros((C, 1), np.int32)
-                mask = np.zeros((C,), bool)
-                rem = np.zeros((C,), np.int32)
-                lens = np.zeros((C,), np.int32)
-                lims = np.zeros((C,), np.int32)
-                tab = np.full((C, W), -1, np.int32)
-                for r in rows:
-                    rq = self.active[r]
-                    last[r, 0] = rq.generated[-1]
-                    mask[r] = True
-                    rem[r] = rq.budget - len(rq.generated)
-                    lens[r] = self._fill_of(rq)
-                    lims[r] = self.tables.capacity(rq.rid)
-                    tab[r] = self.tables.row(rq.rid)
-                t0 = time.perf_counter()
-                toks, self.cache, _, act_out, rem_out = self._decode(
-                    self.params, {"tokens": jnp.asarray(last)}, self.cache,
-                    jnp.asarray(tab), jnp.asarray(lens), jnp.asarray(lims),
-                    jnp.asarray(mask), jnp.asarray(rem), eos_arr)
-                toks = np.asarray(toks)         # sync: true dispatch time
-                rem_out = np.asarray(rem_out)
-                dt = time.perf_counter() - t0
-                produced_total = int(rem[mask].sum() - rem_out[mask].sum())
-                telemetry.record_chunk(0, self._dispatches,
-                                       self._dispatches + 1, dt,
-                                       tokens=produced_total)
-                self.dispatch_log.append(
-                    {"dispatch": self._dispatches, "dt_s": dt,
-                     "tokens": produced_total, "rows": len(rows),
-                     "live_rows": C - len(self._dead_rows)})
-                self._dispatches += 1
-                progressed = True
-                for r in rows:
-                    rq = self.active[r]
-                    produced = int(rem[r] - rem_out[r])
-                    rq.generated.extend(int(t) for t in toks[r, :produced])
-                    self._decoded += produced
-                    done = len(rq.generated) >= rq.budget
-                    if (self.eos_id is not None
-                            and rq.generated[-1] == self.eos_id):
-                        done = True
-                    if done:
-                        del self.active[r]
-                        finish(rq)
-                    # a capacity-frozen row just stays active: the next
-                    # turn's growth phase gets it more blocks (or preempts
-                    # someone to)
+                                f"request {req.rid}: {n_all} tokens need "
+                                f"{blocks_for_tokens(n_all, self.block_size)} "
+                                f"blocks but the pool has "
+                                f"{self.pool.num_blocks}; raise num_blocks")
 
-            if not progressed:
-                break
+                # ---- one prefill chunk per turn while admission can progress
+                if pf is not None:
+                    ran_prefill = True
+                    n = pf.sizes[pf.idx]
+                    pb = bucket_length(n, self.prefill_chunk)
+                    entry = {"rid": pf.req.rid, "start": pf.start, "length": n,
+                             "bucket": pb}
+                    self.prefill_log.append(entry)
+                    with TraceAnnotation("serve.prefill", **entry):
+                        buf = np.zeros((1, pb), np.int32)
+                        buf[0, :n] = pf.tokens[pf.start:pf.start + n]
+                        t0 = time.perf_counter()
+                        args = (self.params, {"tokens": jnp.asarray(buf)},
+                                self.cache,
+                                jnp.asarray(self.tables.row(pf.req.rid)),
+                                jnp.asarray(pf.start, jnp.int32),
+                                jnp.asarray(n, jnp.int32))
+                        # the call and the read-back: the host holds no
+                        # result until the program has run
+                        with TraceAnnotation("serve.wait",
+                                             program="prefill_chunk"):
+                            logits, self.cache = self._prefill_step(*args)
+                            logits = np.asarray(logits)   # sync: true chunk time
+                        dt = time.perf_counter() - t0
+                        pf_tel.record_chunk(0, pf.start, pf.start + n, dt,
+                                            tokens=n)
+                        self._pf_dispatches += 1
+                        pf.start += n
+                        pf.idx += 1
+                        progressed = True
+                        if pf.idx == len(pf.sizes):     # prompt fully cached
+                            req = pf.req
+                            pf = None
+                            self.last_prefill_logits = logits[0]
+                            tok = int(np.argmax(logits[0]))
+                            if req.generated is None:
+                                req.generated = []
+                            req.generated.append(tok)
+                            if req.t_first is None:
+                                req.t_first = time.perf_counter()
+                            done = len(req.generated) >= req.budget
+                            if self.eos_id is not None and tok == self.eos_id:
+                                done = True
+                            if done:
+                                finish(req)
+                            else:
+                                row = min(r for r in range(C)
+                                          if r not in self.active
+                                          and r not in self._dead_rows)
+                                self.active[row] = req
+                                peak_conc = max(peak_conc, len(self.active))
+
+                # ---- one fused decode dispatch across every active row.
+                # Admission has priority: decode runs when prefill could NOT
+                # progress this turn (queue empty, pool full, or concurrency
+                # cap) — occupancy builds while blocks are free, and under
+                # memory pressure the loop alternates admission attempts with
+                # decode dispatches at chunk granularity, which is exactly the
+                # prefill/decode interleave the scheduler clause governs.
+                if self.active and not ran_prefill:
+                    # grow tables oldest-first so the head of the line wins
+                    # under pressure; LIFO victims free blocks as needed
+                    with TraceAnnotation("serve.grow", rows=len(self.active)):
+                        for r in sorted(self.active,
+                                        key=lambda r: self.active[r].admit_seq):
+                            if r not in self.active:    # preempted this turn
+                                continue
+                            rq = self.active[r]
+                            total_need = int(rq.prompt.size) + rq.budget - 1
+                            need = min(self._fill_of(rq) + self.decode_steps,
+                                       total_need)
+                            while not self.tables.ensure(rq.rid, need):
+                                if not preempt_one(exclude_rid=rq.rid):
+                                    raise ValueError(
+                                        f"request {rq.rid}: cannot grow to "
+                                        f"{need} tokens with every other "
+                                        f"request evicted — the pool "
+                                        f"({self.num_blocks} blocks) is "
+                                        f"smaller than one request's context; "
+                                        f"raise num_blocks")
+                        meter.blocks(self.pool.used, self.pool.num_blocks,
+                                     time.perf_counter())
+                    with TraceAnnotation("serve.decode",
+                                         dispatch=self._dispatches,
+                                         rows=len(self.active)):
+                        rows = sorted(self.active)
+                        last = np.zeros((C, 1), np.int32)
+                        mask = np.zeros((C,), bool)
+                        rem = np.zeros((C,), np.int32)
+                        lens = np.zeros((C,), np.int32)
+                        lims = np.zeros((C,), np.int32)
+                        tab = np.full((C, W), -1, np.int32)
+                        for r in rows:
+                            rq = self.active[r]
+                            last[r, 0] = rq.generated[-1]
+                            mask[r] = True
+                            rem[r] = rq.budget - len(rq.generated)
+                            lens[r] = self._fill_of(rq)
+                            lims[r] = self.tables.capacity(rq.rid)
+                            tab[r] = self.tables.row(rq.rid)
+                        t0 = time.perf_counter()
+                        args = (self.params, {"tokens": jnp.asarray(last)},
+                                self.cache, jnp.asarray(tab), jnp.asarray(lens),
+                                jnp.asarray(lims), jnp.asarray(mask),
+                                jnp.asarray(rem), eos_arr)
+                        with TraceAnnotation("serve.wait", program="serve_step"):
+                            toks, self.cache, _, act_out, rem_out = self._decode(*args)
+                            toks = np.asarray(toks)   # sync: true dispatch time
+                            rem_out = np.asarray(rem_out)
+                        dt = time.perf_counter() - t0
+                        made = rem[rows] - rem_out[rows]
+                        produced_total = int(made.sum())
+                        telemetry.record_chunk(0, self._dispatches,
+                                               self._dispatches + 1, dt,
+                                               tokens=produced_total)
+                        self.dispatch_log.append(
+                            {"dispatch": self._dispatches, "dt_s": dt,
+                             "tokens": produced_total, "rows": len(rows),
+                             "live_rows": C - len(self._dead_rows),
+                             "fills": lens[rows].tolist(),
+                             "made": made.tolist(),
+                             "read_positions": read_positions})
+                        self._dispatches += 1
+                        progressed = True
+                        for r, produced in zip(rows, made.tolist()):
+                            rq = self.active[r]
+                            rq.generated.extend(int(t) for t in toks[r, :produced])
+                            self._decoded += produced
+                            done = len(rq.generated) >= rq.budget
+                            if (self.eos_id is not None
+                                    and rq.generated[-1] == self.eos_id):
+                                done = True
+                            if done:
+                                del self.active[r]
+                                finish(rq)
+                            # a capacity-frozen row just stays active: the next
+                            # turn's growth phase gets it more blocks (or
+                            # preempts someone to)
+
+                if not progressed:
+                    break
         telemetry.flush()
         pf_tel.flush()
         self.last_stats = telemetry.summary()
-        self.last_stats.update(meter.summary())
+        self.last_stats.update(meter.summary(requests))
         self.last_stats["mode"] = self.mode
         self.last_stats["decode_steps"] = self.decode_steps
         self.last_stats["decode_dispatches"] = self._dispatches

@@ -355,6 +355,9 @@ def gather_kv_paged(pool: jax.Array, tables: jax.Array) -> jax.Array:
     """
     B, W = tables.shape
     _, BS, C = pool.shape
+    # every row's whole W*BS view is read, whatever its fill: the paged
+    # serve loop's dispatch_log counts "read_positions" as rows * W * BS
+    # * decode steps, and that count must follow any change in what is read
     got = jnp.take(pool, jnp.clip(tables, 0), axis=0)    # (B, W, BS, C)
     return got.reshape(B, W * BS, C)
 
@@ -398,13 +401,15 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     """
     B = q.shape[0]
     hd = q.shape[-1]
-    k = gather_kv_paged(k_pool, tables)          # (B, W*BS, C)
-    v = gather_kv_paged(v_pool, tables)
+    with jax.named_scope("kv_gather"):
+        k = gather_kv_paged(k_pool, tables)          # (B, W*BS, C)
+        v = gather_kv_paged(v_pool, tables)
     S = k.shape[1]
     kv_heads = k.shape[-1] // hd
-    return decode_attention(
-        q, k.reshape(B, S, kv_heads, hd).astype(q.dtype),
-        v.reshape(B, S, kv_heads, hd).astype(q.dtype), cur_len)
+    with jax.named_scope("attention"):
+        return decode_attention(
+            q, k.reshape(B, S, kv_heads, hd).astype(q.dtype),
+            v.reshape(B, S, kv_heads, hd).astype(q.dtype), cur_len)
 
 
 # ----------------------------------------------------------------- MLPs

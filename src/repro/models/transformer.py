@@ -330,54 +330,68 @@ def _decode_forward(params: Tree, cfg: ModelConfig,
     else (qkv, attention, residual, MLP/MoE, final norm, head) is this one
     function, so the engines cannot drift apart.
 
+    Each stage runs in a named scope (``embed``, ``qkv``, ``kv_append``,
+    ``kv_gather``, ``attention``, ``attn_out``, ``mlp``, ``head``), so a
+    device trace can tell the program's operations apart.
+
     Returns (logits (B, V), new_k, new_v).
     """
-    if cfg.frontend != "none":
-        x = inputs["embeds"].astype(params["embed"]["tok"].dtype)
-    else:
-        x = params["embed"]["tok"][inputs["tokens"]]
+    with jax.named_scope("embed"):
+        if cfg.frontend != "none":
+            x = inputs["embeds"].astype(params["embed"]["tok"].dtype)
+        else:
+            x = params["embed"]["tok"][inputs["tokens"]]
+        if cfg.positional == "sinusoidal":
+            x = x + sinusoidal_positions(positions, cfg.d_model).astype(x.dtype)
     B = x.shape[0]
-    if cfg.positional == "sinusoidal":
-        x = x + sinusoidal_positions(positions, cfg.d_model).astype(x.dtype)
     pos3d = inputs.get("positions_3d")  # (3,B,1) for qwen2-vl
 
     def body(x, layer):
         lp, kc, vc = layer                      # kc/vc: (B, S, KV*hd) flat
-        h = rms_norm(x, lp["ln1"])
-        q, k, v = _attn_qkv(lp, cfg, h)
-        q, k = _position_rotate(cfg, q, k, positions, pos3d)
-        kc = kv_append(kc, k.reshape(B, 1, cfg.kv_dim))
-        vc = kv_append(vc, v.reshape(B, 1, cfg.kv_dim))
-        kcv = kv_view(kc) if kv_view is not None else kc
-        vcv = kv_view(vc) if kv_view is not None else vc
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, lp["ln1"])
+            q, k, v = _attn_qkv(lp, cfg, h)
+            q, k = _position_rotate(cfg, q, k, positions, pos3d)
+        with jax.named_scope("kv_append"):
+            kc = kv_append(kc, k.reshape(B, 1, cfg.kv_dim))
+            vc = kv_append(vc, v.reshape(B, 1, cfg.kv_dim))
+        with jax.named_scope("kv_gather"):
+            kcv = kv_view(kc) if kv_view is not None else kc
+            vcv = kv_view(vc) if kv_view is not None else vc
         S_max = kcv.shape[1]
-        a = decode_attention(
-            q,
-            kcv.reshape(B, S_max, cfg.num_kv_heads, cfg.head_dim
-                        ).astype(q.dtype),
-            vcv.reshape(B, S_max, cfg.num_kv_heads, cfg.head_dim
-                        ).astype(q.dtype),
-            attend_len)
-        a = a.reshape(B, 1, cfg.q_dim)
-        x = x + jnp.einsum("bsq,qd->bsd", a, lp["attn"]["wo"])
-        h = rms_norm(x, lp["ln2"])
-        if cfg.is_moe:
-            out, _ = moe_ffn(h, lp["moe"]["router"], lp["moe"]["w_gate"],
-                             lp["moe"]["w_up"], lp["moe"]["w_down"], cfg, cap_e)
-        elif cfg.mlp == "swiglu":
-            out = mlp_swiglu(h, lp["mlp"]["wi_gate"], lp["mlp"]["wi_up"],
-                             lp["mlp"]["wo"])
-        else:
-            out = mlp_gelu(h, lp["mlp"]["wi"], lp["mlp"]["bi"],
-                           lp["mlp"]["wo"], lp["mlp"]["bo"])
-        return x + out, (kc, vc)
+        with jax.named_scope("attention"):
+            a = decode_attention(
+                q,
+                kcv.reshape(B, S_max, cfg.num_kv_heads, cfg.head_dim
+                            ).astype(q.dtype),
+                vcv.reshape(B, S_max, cfg.num_kv_heads, cfg.head_dim
+                            ).astype(q.dtype),
+                attend_len)
+        with jax.named_scope("attn_out"):
+            a = a.reshape(B, 1, cfg.q_dim)
+            x = x + jnp.einsum("bsq,qd->bsd", a, lp["attn"]["wo"])
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, lp["ln2"])
+            if cfg.is_moe:
+                out, _ = moe_ffn(h, lp["moe"]["router"], lp["moe"]["w_gate"],
+                                 lp["moe"]["w_up"], lp["moe"]["w_down"], cfg,
+                                 cap_e)
+            elif cfg.mlp == "swiglu":
+                out = mlp_swiglu(h, lp["mlp"]["wi_gate"], lp["mlp"]["wi_up"],
+                                 lp["mlp"]["wo"])
+            else:
+                out = mlp_gelu(h, lp["mlp"]["wi"], lp["mlp"]["bi"],
+                               lp["mlp"]["wo"], lp["mlp"]["bo"])
+            x = x + out
+        return x, (kc, vc)
 
     x, (new_k, new_v) = jax.lax.scan(
         body, x, (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"])
-    head = (params["embed"]["tok"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    logits = jnp.einsum("bsd,dv->bsv", x, head)[:, 0, :cfg.vocab_size]
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"])
+        head = (params["embed"]["tok"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = jnp.einsum("bsd,dv->bsv", x, head)[:, 0, :cfg.vocab_size]
     return logits, new_k, new_v
 
 
@@ -573,11 +587,12 @@ def fused_paged_decode_steps(params: Tree, cfg: ModelConfig,
         logits, new_cache, new_ln = paged_decode_step(
             params, cfg, {"tokens": tok}, {"k": k, "v": v},
             tables=tables, lengths=ln, active=act, cap_e=cap_e)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)     # (B,)
-        emit = jnp.where(act, nxt, -1)
-        rem = rem - act.astype(jnp.int32)
-        act = act & (rem > 0) & (nxt != eos) & (new_ln < limits)
-        tok = jnp.where(act, nxt, tok[:, 0])[:, None]
+        with jax.named_scope("sample"):
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)     # (B,)
+            emit = jnp.where(act, nxt, -1)
+            rem = rem - act.astype(jnp.int32)
+            act = act & (rem > 0) & (nxt != eos) & (new_ln < limits)
+            tok = jnp.where(act, nxt, tok[:, 0])[:, None]
         return (tok, new_cache["k"], new_cache["v"], new_ln, act, rem), emit
 
     (tok, k, v, ln, act, rem), toks = jax.lax.scan(
@@ -607,6 +622,10 @@ def prefill_paged_chunk(params: Tree, cfg: ModelConfig,
     serves every chunk of a given padded width ``Cb`` — the
     one-compile-per-bucket guarantee carries over from dense prefill.
 
+    Each stage runs in a named scope (``embed``, ``qkv``, ``kv_gather``,
+    ``attention``, ``attn_out``, ``mlp``, ``kv_append``, ``head``), as in
+    the decode body.
+
     Returns (logits (1, V) at the chunk's last real position, updated
     cache) — the logits only matter for the final chunk of a prompt,
     where they produce the request's first generated token.
@@ -615,8 +634,9 @@ def prefill_paged_chunk(params: Tree, cfg: ModelConfig,
     length = jnp.asarray(length, jnp.int32)
     Cb = inputs["tokens"].shape[1]
     positions = start + jnp.arange(Cb, dtype=jnp.int32)[None, :]  # (1, Cb)
-    x, positions, pos3d = _embed_inputs(
-        cfg, params, dict(inputs, positions=positions))
+    with jax.named_scope("embed"):
+        x, positions, pos3d = _embed_inputs(
+            cfg, params, dict(inputs, positions=positions))
     B = x.shape[0]
     W = tables.shape[-1]
     BS = cache["k"].shape[2]
@@ -635,58 +655,66 @@ def prefill_paged_chunk(params: Tree, cfg: ModelConfig,
 
     def body(x, layer):
         lp, kc, vc = layer                      # kc/vc: (NB, BS, C)
-        h = rms_norm(x, lp["ln1"])
-        q, k, v = _attn_qkv(lp, cfg, h)
-        q, k = _position_rotate(cfg, q, k, positions, pos3d)
-        past_k = gather_kv_paged(kc, tab_b)     # (1, S_past, C)
-        past_v = gather_kv_paged(vc, tab_b)
-        keys = jnp.concatenate(
-            [past_k.reshape(B, S_past, cfg.num_kv_heads, cfg.head_dim
-                            ).astype(q.dtype), k], axis=1)
-        vals = jnp.concatenate(
-            [past_v.reshape(B, S_past, cfg.num_kv_heads, cfg.head_dim
-                            ).astype(q.dtype), v], axis=1)
-        groups = q.shape[2] // keys.shape[2]
-        kk = _repeat_kv(keys, groups)
-        vv = _repeat_kv(vals, groups)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(mask, s, -1e30)
-        probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-        a = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
-        a = a.reshape(B, Cb, cfg.q_dim)
-        x = x + jnp.einsum("bsq,qd->bsd", a, lp["attn"]["wo"])
-        h = rms_norm(x, lp["ln2"])
-        if cfg.is_moe:
-            out, _ = moe_ffn(h, lp["moe"]["router"], lp["moe"]["w_gate"],
-                             lp["moe"]["w_up"], lp["moe"]["w_down"], cfg,
-                             cap_e)
-        elif cfg.mlp == "swiglu":
-            out = mlp_swiglu(h, lp["mlp"]["wi_gate"], lp["mlp"]["wi_up"],
-                             lp["mlp"]["wo"])
-        else:
-            out = mlp_gelu(h, lp["mlp"]["wi"], lp["mlp"]["bi"],
-                           lp["mlp"]["wo"], lp["mlp"]["bo"])
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, lp["ln1"])
+            q, k, v = _attn_qkv(lp, cfg, h)
+            q, k = _position_rotate(cfg, q, k, positions, pos3d)
+        with jax.named_scope("kv_gather"):
+            past_k = gather_kv_paged(kc, tab_b)     # (1, S_past, C)
+            past_v = gather_kv_paged(vc, tab_b)
+        with jax.named_scope("attention"):
+            keys = jnp.concatenate(
+                [past_k.reshape(B, S_past, cfg.num_kv_heads, cfg.head_dim
+                                ).astype(q.dtype), k], axis=1)
+            vals = jnp.concatenate(
+                [past_v.reshape(B, S_past, cfg.num_kv_heads, cfg.head_dim
+                                ).astype(q.dtype), v], axis=1)
+            groups = q.shape[2] // keys.shape[2]
+            kk = _repeat_kv(keys, groups)
+            vv = _repeat_kv(vals, groups)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(mask, s, -1e30)
+            probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+            a = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
+        with jax.named_scope("attn_out"):
+            a = a.reshape(B, Cb, cfg.q_dim)
+            x = x + jnp.einsum("bsq,qd->bsd", a, lp["attn"]["wo"])
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, lp["ln2"])
+            if cfg.is_moe:
+                out, _ = moe_ffn(h, lp["moe"]["router"], lp["moe"]["w_gate"],
+                                 lp["moe"]["w_up"], lp["moe"]["w_down"], cfg,
+                                 cap_e)
+            elif cfg.mlp == "swiglu":
+                out = mlp_swiglu(h, lp["mlp"]["wi_gate"], lp["mlp"]["wi_up"],
+                                 lp["mlp"]["wo"])
+            else:
+                out = mlp_gelu(h, lp["mlp"]["wi"], lp["mlp"]["bi"],
+                               lp["mlp"]["wo"], lp["mlp"]["bo"])
+            x = x + out
         # scatter the chunk's ROTATED keys (decode appends rotated keys
         # too) into the request's blocks; positions >= length are pad and
         # dropped.  Each chunk position is its own "row" of the scatter.
-        write_ok = jnp.arange(Cb) < length
-        kc = scatter_kv_paged(
-            kc, k.reshape(Cb, 1, cfg.kv_dim), chunk_pos, write_ok,
-            jnp.broadcast_to(tables, (Cb, W)))
-        vc = scatter_kv_paged(
-            vc, v.reshape(Cb, 1, cfg.kv_dim), chunk_pos, write_ok,
-            jnp.broadcast_to(tables, (Cb, W)))
-        return x + out, (kc, vc)
+        with jax.named_scope("kv_append"):
+            write_ok = jnp.arange(Cb) < length
+            kc = scatter_kv_paged(
+                kc, k.reshape(Cb, 1, cfg.kv_dim), chunk_pos, write_ok,
+                jnp.broadcast_to(tables, (Cb, W)))
+            vc = scatter_kv_paged(
+                vc, v.reshape(Cb, 1, cfg.kv_dim), chunk_pos, write_ok,
+                jnp.broadcast_to(tables, (Cb, W)))
+        return x, (kc, vc)
 
     x, (ks, vs) = jax.lax.scan(
         body, x, (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"])
-    head = (params["embed"]["tok"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    x_last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
-                                          keepdims=False)
-    logits = jnp.einsum("bd,dv->bv", x_last, head)[:, :cfg.vocab_size]
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"])
+        head = (params["embed"]["tok"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        x_last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
+                                              keepdims=False)
+        logits = jnp.einsum("bd,dv->bv", x_last, head)[:, :cfg.vocab_size]
     return logits, {"k": ks, "v": vs}
 
 

@@ -390,3 +390,48 @@ def test_mixed_trace_deterministic_and_mixed():
         t.prompt.size for t in shorts)
     c = make_mixed_trace(40, vocab_size=256, seed=10)
     assert any(not np.array_equal(x.prompt, y.prompt) for x, y in zip(a, c))
+
+
+def test_prefill_and_dispatch_logs_count_the_work(cfg):
+    """``prefill_log`` holds one {rid, start, length, bucket} per chunk,
+    tiling each admission's tokens; its padding equals an independent
+    ``bucket_length`` count over the planned chunks.  Each
+    ``dispatch_log`` entry's ``fills``/``made`` are its rows' cached
+    positions and tokens, and ``read_positions`` what the gather path
+    reads."""
+    loop = PagedServeLoop(cfg, num_blocks=64, block_size=BLOCK_SIZE,
+                          max_context=MAX_LEN, concurrency=8,
+                          decode_steps=2, prefill_chunk=16,
+                          scheduler="static")
+    reqs = make_requests(5, lo=4, hi=40, max_new=5)
+    loop.run(reqs)
+    by_rid = {}
+    for e in loop.prefill_log:
+        assert set(e) == {"rid", "start", "length", "bucket"}
+        assert e["bucket"] == bucket_length(e["length"], 16)
+        assert e["start"] == sum(c["length"] for c in by_rid.get(e["rid"], []))
+        by_rid.setdefault(e["rid"], []).append(e)
+    assert {r: sum(c["length"] for c in cs) for r, cs in by_rid.items()} == {
+        r.rid: r.prompt.size for r in reqs}
+    pad = sum(bucket_length(n, 16) - n for r in reqs
+              for n in plan_prefill_chunks("static", int(r.prompt.size), max_chunk=16))
+    assert sum(e["bucket"] - e["length"] for e in loop.prefill_log) == pad
+
+    W = MAX_LEN // BLOCK_SIZE
+    for d in loop.dispatch_log:
+        assert len(d["fills"]) == len(d["made"]) == d["rows"]
+        assert sum(d["made"]) == d["tokens"]
+        assert d["read_positions"] == 8 * W * BLOCK_SIZE * 2
+    assert sum(sum(d["made"]) for d in loop.dispatch_log) == sum(
+        len(r.generated) - 1 for r in reqs)
+    # a row's fill advances by the tokens it made, dispatch to dispatch
+    first = loop.dispatch_log[0]
+    assert sorted(first["fills"]) == sorted(int(r.prompt.size) for r in reqs)
+
+
+def test_logs_reset_per_run(paged_loop):
+    run_loop(paged_loop, "static", make_requests(21))
+    n = len(paged_loop.prefill_log)
+    run_loop(paged_loop, "static", make_requests(21))
+    assert len(paged_loop.prefill_log) == n
+    assert paged_loop.dispatch_log[0]["dispatch"] == 0

@@ -12,6 +12,26 @@ from repro.core.engine import PlanEngine
 
 
 # ----------------------------------------------------------- unit: recorder
+def test_serve_meter_reads_the_requests_stamps():
+    """ServeMeter's percentiles come from the requests' own lifecycle
+    stamps; a request without a stamp is left out of that latency."""
+    from types import SimpleNamespace
+    from repro.core import ServeMeter
+    reqs = [SimpleNamespace(t_arrive=0.0, t_admit=0.5 * i, t_first=0.5 * i + 1.0,
+                            t_finish=0.5 * i + 2.0) for i in range(4)]
+    reqs.append(SimpleNamespace(t_arrive=0.0, t_admit=None, t_first=None,
+                                t_finish=None))
+    meter = ServeMeter()
+    meter.preempt(3)
+    s = meter.summary(reqs)
+    assert s["requests_seen"] == 5 and s["requests_finished"] == 4
+    assert s["queue_p50_s"] == pytest.approx(1.0)
+    assert s["queue_p99_s"] == pytest.approx(1.5)
+    assert s["admission_p99_s"] == pytest.approx(1.0)
+    assert s["e2e_p99_s"] == pytest.approx(3.5)
+    assert s["preemptions"] == 1 and s["kv_util_mean"] is None
+
+
 def test_ledger_accumulates_interleaved_chunk_time():
     tel = LoopTelemetry(LoopHistory(), loop_id="serve", num_workers=2)
     tel.begin(0, Chunk(0, 3, 0))
