@@ -1,0 +1,71 @@
+"""Public op: single-token paged decode attention through block tables.
+
+The Pallas kernel is compiled for the device unless the caller passes
+``interpret=True`` (how the CPU tests run it).  Nothing here looks at the
+platform: the model chooses between this kernel and the block-table
+gather at lowering (``repro.models.transformer``), and :func:`supports`
+is the shape test it applies.
+
+:func:`copied_positions` counts the context positions the kernel copies
+for given row lengths, so a caller's counter can follow what is read.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from repro.kernels.paged_attention.paged_attention import \
+    paged_decode_attention
+
+__all__ = ["paged_attention", "supports", "copied_positions",
+           "paged_decode_attention"]
+
+# positions each step of the kernel's loop computes, and the most
+# consecutive blocks one copy moves: on one v5e at the benchmark's serving
+# shapes, 1024 and 8 came within 3% of the best of spans 256-2048 and
+# runs of 1-64
+SPAN = 1024
+RUN = 8
+# the second-minor tile of a pool dtype: a block must fill whole tiles
+_SUBLANES = {jnp.dtype(jnp.bfloat16): 16, jnp.dtype(jnp.float32): 8}
+
+
+def supports(head_dim: int, block_size: int, dtype) -> bool:
+    """Whether the kernel takes this shape: whole 128-lane heads, and pool
+    blocks of whole ``(sublanes, 128)`` tiles of a bf16 or f32 pool."""
+    sub = _SUBLANES.get(jnp.dtype(dtype))
+    return sub is not None and head_dim % 128 == 0 and block_size % sub == 0
+
+
+def copied_positions(lengths, block_size: int) -> int:
+    """Context positions the kernel copies for rows of these lengths:
+    each row's blocks below its length, whole."""
+    lengths = np.asarray(lengths, np.int64)
+    return int((-(-lengths // block_size)).sum() * block_size)
+
+
+def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                    tables: jax.Array, lengths: jax.Array, *,
+                    pages_per_copy: int | None = None,
+                    interpret: bool = False) -> jax.Array:
+    """q ``(B, H, hd)`` against one layer's pools ``(NB, BS, KV*hd)``
+    through ``tables (B, W)``; row ``b`` attends its first ``lengths[b]``
+    positions and a row of length 0 gets zeros.  Returns ``(B, H, hd)``
+    in q's dtype.  ``pages_per_copy`` (default: ``SPAN`` positions'
+    worth, at most ``W``) is the number of blocks each step of the
+    kernel's loop copies and computes; runs of up to ``RUN`` of them
+    (the largest divisor of ``pages_per_copy``) move in one copy where
+    they are consecutive in the pool."""
+    B, H, hd = q.shape
+    BS = k_pool.shape[1]
+    kv = k_pool.shape[2] // hd
+    if pages_per_copy is None:
+        pages_per_copy = max(1, min(SPAN // BS, tables.shape[1]))
+    run_pages = max(r for r in range(1, RUN + 1) if pages_per_copy % r == 0)
+    out = paged_decode_attention(
+        q.astype(jnp.float32).reshape(B, kv, H // kv, hd), k_pool, v_pool,
+        tables, lengths, pages_per_copy=pages_per_copy, run_pages=run_pages,
+        interpret=interpret)
+    return out.reshape(B, H, hd).astype(q.dtype)

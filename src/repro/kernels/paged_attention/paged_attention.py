@@ -1,0 +1,228 @@
+"""Single-token paged decode attention — Pallas TPU kernel.
+
+Each decode row's keys and values live in fixed-size blocks of one
+layer's pool ``(NB, BS, KV*hd)``, found through the row's block table.
+The kernel reads them straight from the pool, and only the blocks below
+the row's length:
+
+  * grid = (rows,); the block tables and lengths are scalar-prefetched
+    into SMEM and the pools stay in HBM (``memory_space=pl.ANY``);
+  * a row's live blocks are copied ``pages_per_copy`` at a time into a
+    ring of ``DEPTH`` VMEM tiles ``(pages_per_copy, BS, KV*hd)``: the
+    copies of the next ``DEPTH - 1`` groups are in flight while a group
+    is computed, and a block past the row's length is never copied;
+  * a run of ``run_pages`` pages that the table holds on consecutive
+    pool blocks (a prompt's blocks, allocated together) moves in one
+    copy; any other page moves alone;
+  * GQA without repeating K/V: the queries come grouped ``(KV, G, hd)``
+    and each KV head's ``G`` queries meet that head's lane slice of the
+    tile, one matmul pair per head;
+  * bf16 (the pool's dtype) into the MXU, f32 scores, online softmax and
+    accumulator, so no ``(rows, W*BS)`` view is ever materialized;
+  * a row of length 0 (inactive or empty) issues no copy and writes
+    zeros.
+
+Oracle: ref.py (the block-table gather and the dense decode attention).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["paged_decode_attention"]
+
+NEG_INF = -1e30
+# tiles in the copy ring: the copies of DEPTH - 1 groups are in flight
+# while one is computed (4 measured within 3% of the best of 2-8 on one
+# v5e at the benchmark's serving shapes)
+DEPTH = 4
+
+
+def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sem, whole_ref, m_ref, l_ref, acc_ref, *,
+            block_size: int, pages_per_copy: int, run_pages: int,
+            pages_per_row: int, num_blocks: int, kv_heads: int,
+            head_dim: int, scale: float):
+    b = pl.program_id(0)
+    length = lengths_ref[b]
+    n_pages = jnp.minimum((length + block_size - 1) // block_size,
+                          pages_per_row)
+    n_groups = (n_pages + pages_per_copy - 1) // pages_per_copy
+    span = pages_per_copy * block_size
+    runs = pages_per_copy // run_pages
+
+    def block(page):
+        """Pool block of the row's ``page``-th page (clamped in range)."""
+        page = jnp.minimum(page, pages_per_row - 1)
+        return tables_ref[b * pages_per_row + page]
+
+    def copies(src, dst, slot):
+        return (pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, dst],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, dst],
+                                      sem.at[1, slot]))
+
+    def page_by_page(g, slot, lo, hi, action):
+        """One copy per live page ``lo <= i < hi`` of group ``g``."""
+        def one(i, carry):
+            blk = jnp.clip(block(g * pages_per_copy + i), 0, num_blocks - 1)
+            for copy in copies(blk, i, slot):
+                action(copy)
+            return carry
+        jax.lax.fori_loop(lo, jnp.minimum(hi, n_pages - g * pages_per_copy),
+                          one, 0)
+
+    def run_is_whole(g, r):
+        """Whether the ``r``-th run of group ``g`` is ``run_pages`` live
+        pages on consecutive pool blocks: one copy then moves them all."""
+        first = g * pages_per_copy + r * run_pages
+        blk0 = block(first)
+        whole = ((first + run_pages <= n_pages) & (blk0 >= 0)
+                 & (blk0 + run_pages <= num_blocks))
+        for i in range(1, run_pages):
+            whole = whole & (block(first + i) == blk0 + i)
+        return whole
+
+    def for_live_pages(g, slot, action, plan):
+        """Apply ``action`` to each copy of group ``g``'s live pages: a
+        run on consecutive blocks in one copy, any other page alone.
+        ``plan`` decides the runs (as the copies start) and records the
+        decision for the waits, which read it back."""
+        if run_pages == 1:
+            page_by_page(g, slot, 0, pages_per_copy, action)
+            return
+
+        def run(r, carry):
+            flag = slot * runs + r
+            if plan:
+                whole_ref[flag] = run_is_whole(g, r).astype(jnp.int32)
+            whole = whole_ref[flag] == 1
+
+            @pl.when(whole)
+            def _():
+                blk0 = block(g * pages_per_copy + r * run_pages)
+                for copy in copies(pl.ds(blk0, run_pages),
+                                   pl.ds(r * run_pages, run_pages), slot):
+                    action(copy)
+
+            @pl.when(jnp.logical_not(whole))
+            def _():
+                page_by_page(g, slot, r * run_pages, (r + 1) * run_pages,
+                             action)
+            return carry
+
+        live = n_pages - g * pages_per_copy
+        jax.lax.fori_loop(0, jnp.minimum(runs, (live + run_pages - 1)
+                                         // run_pages), run, 0)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # group g lands in tile g % DEPTH of the ring
+    def prologue(ahead, carry):
+        for_live_pages(ahead, ahead, lambda c: c.start(), True)
+        return carry
+
+    jax.lax.fori_loop(0, jnp.minimum(DEPTH - 1, n_groups), prologue, 0)
+
+    def body(g, carry):
+        ahead = g + DEPTH - 1
+
+        @pl.when(ahead < n_groups)
+        def _():
+            for_live_pages(ahead, jax.lax.rem(ahead, DEPTH),
+                           lambda c: c.start(), True)
+
+        slot = jax.lax.rem(g, DEPTH)
+        for_live_pages(g, slot, lambda c: c.wait(), False)
+        g_len = length - g * span
+        groups = q_ref.shape[2]
+        live_s = jax.lax.broadcasted_iota(jnp.int32, (groups, span), 1) < g_len
+        live_v = jax.lax.broadcasted_iota(jnp.int32, (span, head_dim), 0) < g_len
+        for h in range(kv_heads):
+            cols = pl.ds(h * head_dim, head_dim)
+            k = k_buf[slot, :, :, cols].reshape(span, head_dim)
+            v = v_buf[slot, :, :, cols].reshape(span, head_dim)
+            q = q_ref[0, h].astype(k.dtype)               # (G, hd)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(live_s, s, NEG_INF)
+            m_prev = m_ref[h]                             # (G, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = alpha * l_ref[h] + p.sum(axis=1, keepdims=True)
+            # a page's tail past the length holds whatever the tile held
+            # before: zero it, so that no stale value meets a zero weight
+            v = jnp.where(live_v, v, jnp.zeros_like(v))
+            acc_ref[h] = alpha * acc_ref[h] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, body, 0)
+    for h in range(kv_heads):
+        o_ref[0, h] = (acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)
+                       ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("pages_per_copy", "run_pages",
+                                             "interpret"))
+def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
+                           v_pool: jax.Array, tables: jax.Array,
+                           lengths: jax.Array, *, pages_per_copy: int,
+                           run_pages: int, interpret: bool = False
+                           ) -> jax.Array:
+    """q ``(B, KV, G, hd)`` float32 (grouped by KV head); pools ``(NB, BS,
+    KV*hd)``; tables ``(B, W)`` int32 (``-1`` past a row's blocks);
+    lengths ``(B,)`` int32, each at most ``W*BS``.  Returns ``(B, KV, G,
+    hd)`` float32: row ``b`` attends its first ``lengths[b]`` positions.
+    Each step of the kernel's loop computes ``pages_per_copy`` blocks;
+    ``run_pages`` consecutive blocks (dividing ``pages_per_copy``) move
+    in one copy where the table holds them on consecutive pool blocks.
+    """
+    B, KV, G, hd = q.shape
+    NB, BS, C = k_pool.shape
+    W = tables.shape[1]
+    assert C == KV * hd and v_pool.shape == k_pool.shape, (q.shape,
+                                                          k_pool.shape)
+    assert pages_per_copy % run_pages == 0, (pages_per_copy, run_pages)
+    body = functools.partial(
+        _kernel, block_size=BS, pages_per_copy=pages_per_copy,
+        run_pages=run_pages, pages_per_row=W, num_blocks=NB, kv_heads=KV, head_dim=hd,
+        scale=1.0 / math.sqrt(hd))
+    row = pl.BlockSpec((1, KV, G, hd), lambda b, tables, lengths: (b, 0, 0, 0))
+    kernel = pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row,
+            scratch_shapes=[
+                pltpu.VMEM((DEPTH, pages_per_copy, BS, C), k_pool.dtype),
+                pltpu.VMEM((DEPTH, pages_per_copy, BS, C), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, DEPTH)),
+                pltpu.SMEM((DEPTH * (pages_per_copy // run_pages),),
+                           jnp.int32),
+                pltpu.VMEM((KV, G, 1), jnp.float32),
+                pltpu.VMEM((KV, G, 1), jnp.float32),
+                pltpu.VMEM((KV, G, hd), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )
+    return kernel(jnp.asarray(tables, jnp.int32).reshape(-1),
+                  jnp.asarray(lengths, jnp.int32), q, k_pool, v_pool)

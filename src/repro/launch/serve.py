@@ -90,7 +90,9 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import (make_fused_serve_step, make_paged_prefill_step,
                                 make_paged_serve_step, make_prefill_step,
                                 make_serve_step)
+from repro.kernels.paged_attention.ops import copied_positions
 from repro.models import get_model
+from repro.models.transformer import paged_kernel_engages
 from repro.serve_mem import BlockPool, BlockTables
 from repro.serve_mem.blocks import blocks_for_tokens
 
@@ -558,6 +560,12 @@ class PagedServeLoop:
                                   max_blocks=self.max_blocks_per_seq)
         self.cache = self.model.init_paged_decode(num_blocks,
                                                   block_size)[0]
+        # which read the decode program's attention lowers to on the
+        # pool's platform: the Pallas kernel over live blocks, or the
+        # gather of every row's whole view (``read_positions`` follows it)
+        pool = self.cache["k"]
+        self.kernel_reads = paged_kernel_engages(
+            cfg, block_size, pool.dtype, next(iter(pool.devices())).platform)
         # one compile per prefill BUCKET (chunks are bucket-padded) and
         # ONE decode program (fixed (concurrency, W) dispatch shape); both
         # donate the pool, so it is updated in place
@@ -604,6 +612,18 @@ class PagedServeLoop:
         return self.history.measured_invocations(self.loop_id)
 
     # ----------------------------------------------------------- internals
+    def _read_positions(self, fills: np.ndarray, made: np.ndarray) -> int:
+        """Context positions one decode dispatch's attention read.  The
+        Pallas kernel copies, at each step a row runs, that row's blocks
+        below its length (its fill, the step's earlier tokens and the
+        step's own); a frozen or empty row reads nothing.  The gather
+        reads every row's whole ``max_context`` view at every step."""
+        if not self.kernel_reads:
+            return self.concurrency * self.max_context * self.decode_steps
+        steps = [f + j + 1 for f, m in zip(fills.tolist(), made.tolist())
+                 for j in range(m)]
+        return copied_positions(steps, self.block_size)
+
     def _fill_of(self, req: Request) -> int:
         """Cached KV positions: the prompt plus one per generated token
         except the newest (its KV lands at the next dispatch)."""
@@ -644,9 +664,6 @@ class PagedServeLoop:
         self.dispatch_log = []
         self.prefill_log = []
         C, W = self.concurrency, self.max_blocks_per_seq
-        # context positions one dispatch's attention reads: every row's
-        # whole gathered view, each step (see gather_kv_paged)
-        read_positions = C * W * self.block_size * self.decode_steps
         eos_arr = jnp.asarray(-1 if self.eos_id is None else self.eos_id,
                               jnp.int32)
 
@@ -889,7 +906,8 @@ class PagedServeLoop:
                              "live_rows": C - len(self._dead_rows),
                              "fills": lens[rows].tolist(),
                              "made": made.tolist(),
-                             "read_positions": read_positions})
+                             "read_positions": self._read_positions(
+                                 lens[rows], made)})
                         self._dispatches += 1
                         progressed = True
                         for r, produced in zip(rows, made.tolist()):

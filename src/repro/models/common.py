@@ -31,7 +31,7 @@ __all__ = [
     "ParamBuilder", "rms_norm", "make_rope", "apply_rope", "apply_mrope",
     "sinusoidal_positions", "attention", "blockwise_attention", "mlp_swiglu",
     "mlp_gelu", "decode_attention", "scatter_kv", "gather_kv_paged",
-    "scatter_kv_paged", "paged_decode_attention",
+    "scatter_kv_paged",
 ]
 
 Tree = Dict[str, Any]
@@ -355,9 +355,10 @@ def gather_kv_paged(pool: jax.Array, tables: jax.Array) -> jax.Array:
     """
     B, W = tables.shape
     _, BS, C = pool.shape
-    # every row's whole W*BS view is read, whatever its fill: the paged
-    # serve loop's dispatch_log counts "read_positions" as rows * W * BS
-    # * decode steps, and that count must follow any change in what is read
+    # every row's whole W*BS view is read, whatever its fill: where paged
+    # decode takes this gather, the serve loop's dispatch_log counts
+    # "read_positions" as rows * W * BS * decode steps, and that count must
+    # follow any change in what is read (PagedServeLoop._read_positions)
     got = jnp.take(pool, jnp.clip(tables, 0), axis=0)    # (B, W, BS, C)
     return got.reshape(B, W * BS, C)
 
@@ -384,32 +385,6 @@ def scatter_kv_paged(pool: jax.Array, new: jax.Array, cur: jax.Array,
     blk = jnp.where(ok, blk, NB)                 # OOB -> dropped write
     return pool.at[blk, cur % BS].set(new[:, 0].astype(pool.dtype),
                                       mode="drop")
-
-
-def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
-                           v_pool: jax.Array, tables: jax.Array,
-                           cur_len: jax.Array) -> jax.Array:
-    """Single-token decode attention reading K/V through block tables.
-
-    ``q (B, 1, H, hd)`` against one layer's paged pools ``(NB, BS, C)``
-    where ``C = KV*hd``: the per-request views are gathered
-    (:func:`gather_kv_paged`) and fed to the one true
-    :func:`decode_attention` with per-row length masking — positions at
-    or beyond ``cur_len[b]`` (including every gathered garbage entry)
-    are masked, so the result equals dense decode attention over a
-    ``max_len = W*BS`` cache row holding the same sequence.
-    """
-    B = q.shape[0]
-    hd = q.shape[-1]
-    with jax.named_scope("kv_gather"):
-        k = gather_kv_paged(k_pool, tables)          # (B, W*BS, C)
-        v = gather_kv_paged(v_pool, tables)
-    S = k.shape[1]
-    kv_heads = k.shape[-1] // hd
-    with jax.named_scope("attention"):
-        return decode_attention(
-            q, k.reshape(B, S, kv_heads, hd).astype(q.dtype),
-            v.reshape(B, S, kv_heads, hd).astype(q.dtype), cur_len)
 
 
 # ----------------------------------------------------------------- MLPs

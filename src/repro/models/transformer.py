@@ -17,6 +17,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.paged_attention.ops import paged_attention
+from repro.kernels.paged_attention.ops import supports as paged_kernel_supports
 from repro.models.config import ModelConfig
 from repro.models.common import (ParamBuilder, _repeat_kv, apply_mrope,
                                  apply_rope, decode_attention,
@@ -30,7 +32,7 @@ __all__ = ["init_params", "forward", "init_cache", "init_batched_cache",
            "decode_step", "batched_decode_step", "fused_decode_steps",
            "insert_prefill", "prefill", "init_paged_cache",
            "paged_decode_step", "fused_paged_decode_steps",
-           "prefill_paged_chunk"]
+           "prefill_paged_chunk", "paged_kernel_engages"]
 
 Tree = Dict[str, Any]
 
@@ -314,25 +316,36 @@ def insert_prefill(cache: Tree, pref: Tree, slot: jax.Array) -> Tree:
     return {"k": k, "v": v, "len": ln}
 
 
+def _dense_attend(cfg: ModelConfig, q: jax.Array, kc: jax.Array,
+                  vc: jax.Array, attend_len: jax.Array) -> jax.Array:
+    """:func:`decode_attention` over flat ``(B, S, KV*hd)`` cache rows."""
+    shape = kc.shape[:2] + (cfg.num_kv_heads, cfg.head_dim)
+    return decode_attention(q, kc.reshape(shape).astype(q.dtype),
+                            vc.reshape(shape).astype(q.dtype), attend_len)
+
+
 def _decode_forward(params: Tree, cfg: ModelConfig,
                     inputs: Dict[str, jax.Array], cache: Tree,
                     positions: jax.Array, kv_append, attend_len: jax.Array,
                     cap_e: Optional[jax.Array],
-                    kv_view=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                    attend=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The one-token decode body shared by per-slot, batched and paged paths.
 
     The paths differ ONLY in how a layer's new K/V row lands in the
     cache (``kv_append(cache_2d, new_(B,1,kv))``: ``dynamic_update_slice``
     at a scalar length vs a masked per-row scatter vs a block-table paged
-    scatter), in how the cache is read back (``kv_view``: identity for the
-    dense layouts, a block-table gather for the paged pool), and in the
-    position/length values fed to rotary and attention masking — everything
-    else (qkv, attention, residual, MLP/MoE, final norm, head) is this one
-    function, so the engines cannot drift apart.
+    scatter), in how attention reads the cache back (``attend(q, kc,
+    vc)``: by default :func:`decode_attention` over the dense rows with
+    ``attend_len`` masking; the paged pool supplies its own reader, see
+    :func:`paged_decode_step`), and in the position/length values fed to
+    rotary and attention masking — everything else (qkv, residual,
+    MLP/MoE, final norm, head) is this one function, so the engines
+    cannot drift apart.
 
     Each stage runs in a named scope (``embed``, ``qkv``, ``kv_append``,
-    ``kv_gather``, ``attention``, ``attn_out``, ``mlp``, ``head``), so a
-    device trace can tell the program's operations apart.
+    ``attention``, ``attn_out``, ``mlp``, ``head``; the paged gather adds
+    ``kv_gather``), so a device trace can tell the program's operations
+    apart.
 
     Returns (logits (B, V), new_k, new_v).
     """
@@ -355,18 +368,11 @@ def _decode_forward(params: Tree, cfg: ModelConfig,
         with jax.named_scope("kv_append"):
             kc = kv_append(kc, k.reshape(B, 1, cfg.kv_dim))
             vc = kv_append(vc, v.reshape(B, 1, cfg.kv_dim))
-        with jax.named_scope("kv_gather"):
-            kcv = kv_view(kc) if kv_view is not None else kc
-            vcv = kv_view(vc) if kv_view is not None else vc
-        S_max = kcv.shape[1]
-        with jax.named_scope("attention"):
-            a = decode_attention(
-                q,
-                kcv.reshape(B, S_max, cfg.num_kv_heads, cfg.head_dim
-                            ).astype(q.dtype),
-                vcv.reshape(B, S_max, cfg.num_kv_heads, cfg.head_dim
-                            ).astype(q.dtype),
-                attend_len)
+        if attend is not None:
+            a = attend(q, kc, vc)
+        else:
+            with jax.named_scope("attention"):
+                a = _dense_attend(cfg, q, kc, vc, attend_len)
         with jax.named_scope("attn_out"):
             a = a.reshape(B, 1, cfg.q_dim)
             x = x + jnp.einsum("bsq,qd->bsd", a, lp["attn"]["wo"])
@@ -512,22 +518,72 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     return cache, specs
 
 
+def paged_kernel_engages(cfg: ModelConfig, block_size: int, dtype,
+                         platform: str = "tpu") -> bool:
+    """Whether the paged decode program, lowered for ``platform``, reads
+    K/V through the Pallas kernel: on a TPU, for the shapes the kernel
+    takes (whole 128-lane heads, blocks of whole tiles of a bf16 or f32
+    pool).  Everywhere else it gathers every row's whole view."""
+    return platform == "tpu" and paged_kernel_supports(cfg.head_dim,
+                                                       block_size, dtype)
+
+
+def _paged_attend(cfg: ModelConfig, pool: jax.Array, tables: jax.Array,
+                  cur: jax.Array, active: jax.Array, interpret: bool):
+    """The paged decode's attention reader, ``attend(q, kc, vc)`` over
+    one layer's pools ``(NB, BS, C)`` (``pool`` is the stacked
+    ``(L, NB, BS, C)`` one: its block size and dtype choose the path).
+
+    The gather materializes every row's whole ``(B, W*BS, C)`` view and
+    runs the dense :func:`decode_attention` on it.  The kernel
+    (``repro.kernels.paged_attention``) copies only each active row's
+    blocks below its length, and is chosen at lowering where
+    :func:`paged_kernel_engages` says so (``interpret=True`` runs it in
+    interpret mode on any platform).  Both give an active row the same
+    attention; an inactive row's result is discarded by the caller."""
+    def gather(q, kc, vc):
+        with jax.named_scope("kv_gather"):
+            k = gather_kv_paged(kc, tables)          # (B, W*BS, C)
+            v = gather_kv_paged(vc, tables)
+        with jax.named_scope("attention"):
+            return _dense_attend(cfg, q, k, v, cur + 1)
+
+    live = jnp.where(active, cur + 1, 0)
+
+    def kernel(q, kc, vc):
+        with jax.named_scope("attention"):
+            return paged_attention(q[:, 0], kc, vc, tables, live,
+                                   interpret=interpret)[:, None]
+
+    if interpret:
+        return kernel
+    if not paged_kernel_engages(cfg, pool.shape[2], pool.dtype):
+        return gather
+    return lambda q, kc, vc: jax.lax.platform_dependent(
+        q, kc, vc, tpu=kernel, default=gather)
+
+
 def paged_decode_step(params: Tree, cfg: ModelConfig,
                       inputs: Dict[str, jax.Array], cache: Tree, *,
                       tables: jax.Array, lengths: jax.Array,
                       active: Optional[jax.Array] = None,
-                      cap_e: Optional[jax.Array] = None
+                      cap_e: Optional[jax.Array] = None,
+                      kernel_interpret: bool = False
                       ) -> Tuple[jax.Array, Tree, jax.Array]:
     """One-token decode across every row of a paged-KV pool.
 
     ``tables (B, W)`` int32 maps each row's logical blocks onto pool
     blocks (``-1`` = unassigned); ``lengths (B,)`` is each row's fill.
     The body is the same :func:`_decode_forward` as the dense engines —
-    only the append (block-table scatter) and the cache read (block-table
-    gather to a ``(B, W*BS, C)`` view) differ, so an active row's math is
-    identical to :func:`batched_decode_step` over a dense ``max_len =
+    only the append (block-table scatter) and the attention's read of the
+    cache differ (:func:`_paged_attend`: on a TPU the Pallas kernel reads
+    each active row's live blocks; elsewhere a block-table gather to a
+    ``(B, W*BS, C)`` view feeds the dense attention), so an active row's
+    math is that of :func:`batched_decode_step` over a dense ``max_len =
     W*BS`` cache holding the same sequence — the paged-vs-dense
-    equivalence guarantee.
+    equivalence guarantee, exact on the gather and to rounding (the
+    kernel's online softmax) on the kernel.  ``kernel_interpret=True``
+    runs the kernel in interpret mode, on any platform and shape.
 
     Returns (logits (B, V), updated cache, updated lengths).
     """
@@ -542,7 +598,8 @@ def paged_decode_step(params: Tree, cfg: ModelConfig,
                                                   tables),
         attend_len=cur + 1,
         cap_e=cap_e,
-        kv_view=lambda c: gather_kv_paged(c, tables))
+        attend=_paged_attend(cfg, cache["k"], tables, cur, active,
+                             kernel_interpret))
     return logits, {"k": new_k, "v": new_v}, cur + active.astype(jnp.int32)
 
 
@@ -553,7 +610,8 @@ def fused_paged_decode_steps(params: Tree, cfg: ModelConfig,
                              active: Optional[jax.Array] = None,
                              remaining: Optional[jax.Array] = None,
                              eos_id: Optional[jax.Array] = None,
-                             cap_e: Optional[jax.Array] = None
+                             cap_e: Optional[jax.Array] = None,
+                             kernel_interpret: bool = False
                              ) -> Tuple[jax.Array, Tree, jax.Array,
                                         jax.Array, jax.Array]:
     """Run up to ``num_steps`` greedy tokens per row through the paged
@@ -566,6 +624,7 @@ def fused_paged_decode_steps(params: Tree, cfg: ModelConfig,
     scattering into a block it does not own, which is the memory-pressure
     edge the serve loop turns into a preemption decision.  Budget and EOS
     freezes behave exactly as in the dense fused engine.
+    ``kernel_interpret`` as in :func:`paged_decode_step`.
 
     Returns ``(tokens (B, num_steps), cache, lengths, active,
     remaining)``; frozen steps emit -1.
@@ -586,7 +645,8 @@ def fused_paged_decode_steps(params: Tree, cfg: ModelConfig,
         tok, k, v, ln, act, rem = carry
         logits, new_cache, new_ln = paged_decode_step(
             params, cfg, {"tokens": tok}, {"k": k, "v": v},
-            tables=tables, lengths=ln, active=act, cap_e=cap_e)
+            tables=tables, lengths=ln, active=act, cap_e=cap_e,
+            kernel_interpret=kernel_interpret)
         with jax.named_scope("sample"):
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)     # (B,)
             emit = jnp.where(act, nxt, -1)

@@ -7,8 +7,12 @@ positions onto pool blocks, and the host-side manager here does the
 allocate / grow / release / watermark accounting that admission,
 chunked prefill, and preemption decisions are made against.
 
-`repro.models.common.gather_kv_paged` / `scatter_kv_paged` are the
-device twins: they read and write the pool through the same tables.
+The device twins read and write the pool through the same tables:
+`repro.models.common.scatter_kv_paged` appends; paged decode reads each
+row's live blocks with the Pallas kernel of
+`repro.kernels.paged_attention` on a TPU, and
+`repro.models.common.gather_kv_paged` gathers whole views elsewhere and
+for chunked prefill.
 """
 
 from repro.serve_mem.blocks import BlockPool, BlockTables

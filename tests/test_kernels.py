@@ -16,6 +16,9 @@ from repro.kernels.sched_matmul.ref import sched_matmul_ref
 from repro.kernels.flash_attention.ops import mha
 from repro.kernels.linear_scan.ops import ssd, wkv
 from repro.kernels.linear_scan.ref import linear_attention_ref
+from repro.kernels.paged_attention.ops import (copied_positions,
+                                               paged_attention, supports)
+from repro.kernels.paged_attention.ref import paged_attention_ref
 
 RNG = np.random.default_rng(0)
 
@@ -156,3 +159,70 @@ def test_wkv_strong_decay_no_overflow():
     yr, _ = linear_attention_ref(r, k, v, lw, u=u, inclusive=False)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                rtol=3e-4, atol=3e-4)
+
+
+# --------------------------------------------------- paged decode attention
+@pytest.mark.parametrize(
+    "heads,kv,block_size,pages_per_copy,consecutive,dtype", [
+        pytest.param(16, 2, 16, 1, False, jnp.float32, id="gqa8-copy1"),
+        pytest.param(16, 2, 16, 4, False, jnp.float32, id="gqa8-copy4"),
+        pytest.param(16, 2, 16, 4, True, jnp.float32,
+                     id="gqa8-copy4-consecutive"),
+        pytest.param(16, 2, 16, None, True, jnp.bfloat16,
+                     id="gqa8-bf16-default"),
+        pytest.param(4, 4, 8, 2, False, jnp.float32, id="gqa1-copy2"),
+        pytest.param(4, 4, 8, 3, True, jnp.bfloat16,
+                     id="gqa1-bf16-copy3-consecutive"),
+    ])
+def test_paged_attention_matches_gather_oracle(heads, kv, block_size,
+                                               pages_per_copy, consecutive,
+                                               dtype):
+    """The kernel reads each row's live blocks through its table and
+    matches the gather + dense decode attention oracle: ragged lengths
+    (0, 1, BS-1, BS, BS+1, the whole W*BS, one short), ``-1`` past each row's
+    blocks, and an inactive row (length 0) that still holds blocks; a
+    row of length 0 gets zeros.  qwen2.5-3b's GQA (16 heads on 2, hd
+    128) and one query head per KV head; blocks scattered over the pool,
+    or handed out in order as a fresh pool does (runs of consecutive
+    blocks, which move in one copy), broken once and reversed once."""
+    hd, W, BS = 128, 6, block_size
+    lengths = [0, 1, BS - 1, BS, BS + 1, W * BS, W * BS - 1, 0]
+    B = len(lengths)
+    nb = B * W + 3
+    rng = np.random.default_rng(heads + BS)
+    q = jnp.asarray(rng.normal(size=(B, heads, hd)), dtype)
+    k_pool = jnp.asarray(rng.normal(size=(nb, BS, kv * hd)), dtype)
+    v_pool = jnp.asarray(rng.normal(size=(nb, BS, kv * hd)), dtype)
+    tables = np.full((B, W), -1, np.int32)
+    order = np.arange(nb) if consecutive else rng.permutation(nb)
+    blocks = iter(order.tolist())
+    for b, n in enumerate(lengths):
+        held = W if b == B - 1 else -(-n // BS)   # the last row is inactive
+        tables[b, :held] = [next(blocks) for _ in range(held)]
+    if consecutive:
+        tables[5, 2] = nb - 1                     # a run broken
+        tables[4, :2] = tables[4, 1::-1]          # a run reversed
+    lens = jnp.asarray(lengths, jnp.int32)
+    out = paged_attention(q, k_pool, v_pool, jnp.asarray(tables), lens,
+                          pages_per_copy=pages_per_copy, interpret=True)
+    ref = paged_attention_ref(q, k_pool, v_pool, jnp.asarray(tables), lens)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(ref, np.float32)[live],
+                               **_tol(dtype))
+    assert not np.asarray(out, np.float32)[~live].any()
+
+
+def test_paged_attention_shape_test_and_copy_count():
+    """The shape test takes whole 128-lane heads and blocks of whole bf16
+    (16-row) or f32 (8-row) tiles; the copy count is each row's blocks
+    below its length, whole."""
+    assert supports(128, 16, jnp.bfloat16) and supports(256, 32, jnp.bfloat16)
+    assert supports(128, 8, jnp.float32)
+    assert not supports(96, 16, jnp.bfloat16)          # phi3-mini's heads
+    assert not supports(128, 8, jnp.bfloat16)
+    assert not supports(128, 16, jnp.float8_e4m3fn)
+    assert copied_positions([0, 1, 15, 16, 17, 96], 16) == (
+        0 + 16 + 16 + 16 + 32 + 96)
+    assert copied_positions([], 16) == 0

@@ -23,12 +23,20 @@ bucket-padded, so compile count is bounded by the BUCKET count no matter
 how many distinct prompt lengths (or UDS chunk sizes) the trace produces.
 """
 
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.launch.serve import (PagedServeLoop, Request, ServeLoop,
                                 bucket_length, plan_prefill_chunks)
+from repro.launch.steps import make_paged_serve_step
+from repro.models import get_model
+from repro.models.transformer import paged_kernel_engages
 from repro.serve_mem import BlockPool, BlockTables, make_mixed_trace
 from repro.serve_mem.blocks import blocks_for_tokens
 
@@ -392,17 +400,33 @@ def test_mixed_trace_deterministic_and_mixed():
     assert any(not np.array_equal(x.prompt, y.prompt) for x, y in zip(a, c))
 
 
+def _kernel_loop(cfg, **kw):
+    """A paged loop whose decode program takes the Pallas kernel, in
+    interpret mode: the CPU's stand-in for the TPU lowering."""
+    loop = PagedServeLoop(cfg, **kw)
+    model = dataclasses.replace(
+        loop.model, fused_paged_decode=functools.partial(
+            loop.model.fused_paged_decode, kernel_interpret=True))
+    loop._decode = jax.jit(make_paged_serve_step(model, loop.decode_steps),
+                           donate_argnums=(2,))
+    loop.kernel_reads = True
+    return loop
+
+
 def test_prefill_and_dispatch_logs_count_the_work(cfg):
     """``prefill_log`` holds one {rid, start, length, bucket} per chunk,
     tiling each admission's tokens; its padding equals an independent
     ``bucket_length`` count over the planned chunks.  Each
     ``dispatch_log`` entry's ``fills``/``made`` are its rows' cached
-    positions and tokens, and ``read_positions`` what the gather path
-    reads."""
-    loop = PagedServeLoop(cfg, num_blocks=64, block_size=BLOCK_SIZE,
-                          max_context=MAX_LEN, concurrency=8,
-                          decode_steps=2, prefill_chunk=16,
-                          scheduler="static")
+    positions and tokens, and ``read_positions`` what the program's
+    attention reads: on the gather path (the CPU's) every row's whole
+    view each step; on the kernel path each running row's blocks below
+    its length, each step it runs — the same tokens, fewer positions."""
+    kw = dict(num_blocks=64, block_size=BLOCK_SIZE, max_context=MAX_LEN,
+              concurrency=8, decode_steps=2, prefill_chunk=16,
+              scheduler="static")
+    loop = PagedServeLoop(cfg, **kw)
+    assert not loop.kernel_reads
     reqs = make_requests(5, lo=4, hi=40, max_new=5)
     loop.run(reqs)
     by_rid = {}
@@ -427,6 +451,67 @@ def test_prefill_and_dispatch_logs_count_the_work(cfg):
     # a row's fill advances by the tokens it made, dispatch to dispatch
     first = loop.dispatch_log[0]
     assert sorted(first["fills"]) == sorted(int(r.prompt.size) for r in reqs)
+
+    kernel = _kernel_loop(cfg, **kw)
+    kreqs = make_requests(5, lo=4, hi=40, max_new=5)
+    kernel.run(kreqs)
+    assert [r.generated for r in kreqs] == [r.generated for r in reqs]
+    assert len(kernel.dispatch_log) == len(loop.dispatch_log)
+    for d, g in zip(kernel.dispatch_log, loop.dispatch_log):
+        assert (d["fills"], d["made"]) == (g["fills"], g["made"])
+        blocks = sum(-(-(f + j + 1) // BLOCK_SIZE)
+                     for f, m in zip(d["fills"], d["made"])
+                     for j in range(m))
+        assert d["read_positions"] == blocks * BLOCK_SIZE
+        assert d["read_positions"] < g["read_positions"]
+
+
+def test_fused_paged_decode_kernel_matches_gather(cfg):
+    """The tiny config's fused paged decode, with the kernel forced in
+    interpret mode, makes the same greedy tokens as the gather path over
+    the same pool: rows of ragged fills, a frozen row and an empty one,
+    ``-1`` past every row's blocks, budgets that run out mid-dispatch."""
+    model = get_model(cfg)
+    params, _ = model.init(jax.random.PRNGKey(3), jnp.bfloat16)
+    W, BS = 4, BLOCK_SIZE
+    pool, _ = model.init_paged_decode(24, BS)
+    rng = np.random.default_rng(3)
+    pool = {n: jnp.asarray(rng.normal(size=p.shape) * 0.5, p.dtype)
+            for n, p in pool.items()}
+    fills = np.array([3, BS, 2 * BS + 1, 9, 0], np.int32)
+    tables = np.full((5, W), -1, np.int32)
+    blocks = iter(rng.permutation(24).tolist())
+    for b, f in enumerate(fills):
+        held = -(-(f + 4) // BS)                 # room for the dispatch
+        tables[b, :held] = [next(blocks) for _ in range(held)]
+    args = dict(num_steps=4, tables=jnp.asarray(tables),
+                lengths=jnp.asarray(fills),
+                limits=jnp.asarray(-(-(fills + 4) // BS) * BS),
+                active=jnp.asarray([True, True, True, False, False]),
+                remaining=jnp.asarray([4, 2, 4, 4, 0], jnp.int32))
+    tokens = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab_size, (5, 1)),
+                                    jnp.int32)}
+    out = {}
+    for interpret in (False, True):
+        step = jax.jit(functools.partial(model.fused_paged_decode,
+                                         kernel_interpret=interpret, **args))
+        toks, _, ln, act, rem = step(params, tokens, pool)
+        out[interpret] = [np.asarray(x) for x in (toks, ln, act, rem)]
+    for got, want in zip(out[True], out[False]):
+        np.testing.assert_array_equal(got, want)
+    assert (out[True][0][:2, :2] >= 0).all() and (out[True][0][3:] == -1).all()
+
+
+def test_paged_kernel_engages_by_platform_and_shape():
+    """The decode program takes the kernel only when lowered for a TPU,
+    and only for the shapes the kernel takes: qwen2.5-3b's 128-wide heads
+    in bf16 blocks of 16, not phi3-mini's 96, not an fp8 pool."""
+    qwen, phi3 = get_config("qwen2.5-3b"), get_config("phi3-mini-3.8b")
+    assert paged_kernel_engages(qwen, 16, jnp.bfloat16)
+    assert not paged_kernel_engages(qwen, 16, jnp.bfloat16, "cpu")
+    assert not paged_kernel_engages(qwen, 8, jnp.bfloat16)
+    assert not paged_kernel_engages(qwen, 16, jnp.float8_e4m3fn)
+    assert not paged_kernel_engages(phi3, 16, jnp.bfloat16)
 
 
 def test_logs_reset_per_run(paged_loop):

@@ -8,6 +8,8 @@ and needs no chip.  The topology is described inside a fixture, never at
 import: only the worker that runs this file loads the TPU library.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -28,6 +30,12 @@ NUM_BLOCKS = 640
 CONCURRENCY = 8
 PREFILL_CHUNK = 512
 DECODE_STEPS = 4
+
+# the serving shapes of the benchmark's qwen2.5-3b.longprompt cell
+# (benchmarks/chip/configs/qwen2.5-3b.json)
+CELL_CONCURRENCY = 32
+CELL_MAX_CONTEXT = 4192
+CELL_NUM_BLOCKS = 8320
 
 
 @pytest.fixture(scope="module")
@@ -105,6 +113,36 @@ def test_paged_prefill_chunk_fits_one_v5e(one_chip, qwen):
         _sds(one_chip, (W,), i32), _sds(one_chip, (), i32),
         _sds(one_chip, (), i32)).compile()
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_paged_decode_reads_the_pool_through_the_kernel(one_chip):
+    """At the benchmark cell's serving shapes, the paged decode program
+    lowered for a v5e fits the chip, holds the Pallas paged-attention
+    kernel, and no longer materializes any row's whole (32, 4192, ...)
+    view, nor the (32, 262, 16, ...) gather that built it."""
+    # tied embeddings, as published and as the benchmark serves them
+    cfg = dataclasses.replace(get_config("qwen2.5-3b"), tie_embeddings=True)
+    model = get_model(cfg)
+    params, _ = model.init(jax.random.PRNGKey(0), jnp.bfloat16,
+                           abstract=True)
+    pool, _ = model.init_paged_decode(CELL_NUM_BLOCKS, BLOCK_SIZE,
+                                      abstract=True)
+    C, W = CELL_CONCURRENCY, CELL_MAX_CONTEXT // BLOCK_SIZE
+    i32 = jnp.int32
+    step = jax.jit(make_paged_serve_step(model, DECODE_STEPS),
+                   donate_argnums=(2,))
+    compiled = step.lower(
+        _on(one_chip, params), {"tokens": _sds(one_chip, (C, 1), i32)},
+        _on(one_chip, pool), _sds(one_chip, (C, W), i32),
+        _sds(one_chip, (C,), i32), _sds(one_chip, (C,), i32),
+        _sds(one_chip, (C,), jnp.bool_), _sds(one_chip, (C,), i32),
+        _sds(one_chip, (), i32)).compile()
+    # the pool is donated: its output aliases its argument
+    assert compiled.memory_analysis().peak_memory_in_bytes < V5E_HBM_BYTES
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert f"[{C},{W * BLOCK_SIZE}," not in hlo
+    assert f"[{C},{W},{BLOCK_SIZE}," not in hlo
 
 
 def _kernel_cases():
