@@ -47,25 +47,25 @@ def copied_positions(lengths, block_size: int) -> int:
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                    tables: jax.Array, lengths: jax.Array, *,
-                    pages_per_copy: int | None = None,
+                    layer: jax.Array, tables: jax.Array, lengths: jax.Array,
+                    *, pages_per_copy: int | None = None,
                     interpret: bool = False) -> jax.Array:
-    """q ``(B, H, hd)`` against one layer's pools ``(NB, BS, KV*hd)``
-    through ``tables (B, W)``; row ``b`` attends its first ``lengths[b]``
-    positions and a row of length 0 gets zeros.  Returns ``(B, H, hd)``
-    in q's dtype.  ``pages_per_copy`` (default: ``SPAN`` positions'
-    worth, at most ``W``) is the number of blocks each step of the
-    kernel's loop copies and computes; runs of up to ``RUN`` of them
-    (the largest divisor of ``pages_per_copy``) move in one copy where
-    they are consecutive in the pool."""
+    """q ``(B, H, hd)`` against layer ``layer`` of the layer-stacked pools
+    ``(L, NB, BS, KV*hd)`` through ``tables (B, W)``; row ``b`` attends its
+    first ``lengths[b]`` positions and a row of length 0 gets zeros.
+    Returns ``(B, H, hd)`` in q's dtype.  ``pages_per_copy`` (default:
+    ``SPAN`` positions' worth, at most ``W``) is the number of blocks each
+    step of the kernel's loop copies and computes; runs of up to ``RUN``
+    of them (the largest divisor of ``pages_per_copy``) move in one copy
+    where they are consecutive in the pool."""
     B, H, hd = q.shape
-    BS = k_pool.shape[1]
-    kv = k_pool.shape[2] // hd
+    BS = k_pool.shape[2]
+    kv = k_pool.shape[3] // hd
     if pages_per_copy is None:
         pages_per_copy = max(1, min(SPAN // BS, tables.shape[1]))
     run_pages = max(r for r in range(1, RUN + 1) if pages_per_copy % r == 0)
     out = paged_decode_attention(
         q.astype(jnp.float32).reshape(B, kv, H // kv, hd), k_pool, v_pool,
-        tables, lengths, pages_per_copy=pages_per_copy, run_pages=run_pages,
-        interpret=interpret)
+        layer, tables, lengths, pages_per_copy=pages_per_copy,
+        run_pages=run_pages, interpret=interpret)
     return out.reshape(B, H, hd).astype(q.dtype)

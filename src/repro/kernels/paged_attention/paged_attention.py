@@ -1,12 +1,13 @@
 """Single-token paged decode attention — Pallas TPU kernel.
 
 Each decode row's keys and values live in fixed-size blocks of one
-layer's pool ``(NB, BS, KV*hd)``, found through the row's block table.
-The kernel reads them straight from the pool, and only the blocks below
-the row's length:
+layer of the layer-stacked pool ``(L, NB, BS, KV*hd)``, found through the
+row's block table.  The kernel reads them straight from the pool, at the
+layer it is given, and only the blocks below the row's length:
 
-  * grid = (rows,); the block tables and lengths are scalar-prefetched
-    into SMEM and the pools stay in HBM (``memory_space=pl.ANY``);
+  * grid = (rows,); the block tables, lengths and layer are
+    scalar-prefetched into SMEM and the stacked pools stay in HBM
+    (``memory_space=pl.ANY``), so no layer's pool is sliced out of them;
   * a row's live blocks are copied ``pages_per_copy`` at a time into a
     ring of ``DEPTH`` VMEM tiles ``(pages_per_copy, BS, KV*hd)``: the
     copies of the next ``DEPTH - 1`` groups are in flight while a group
@@ -44,12 +45,13 @@ NEG_INF = -1e30
 DEPTH = 4
 
 
-def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
+def _kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
             k_buf, v_buf, sem, whole_ref, m_ref, l_ref, acc_ref, *,
             block_size: int, pages_per_copy: int, run_pages: int,
             pages_per_row: int, num_blocks: int, kv_heads: int,
             head_dim: int, scale: float):
     b = pl.program_id(0)
+    layer = layer_ref[0]
     length = lengths_ref[b]
     n_pages = jnp.minimum((length + block_size - 1) // block_size,
                           pages_per_row)
@@ -63,10 +65,10 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
         return tables_ref[b * pages_per_row + page]
 
     def copies(src, dst, slot):
-        return (pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, dst],
-                                      sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, dst],
-                                      sem.at[1, slot]))
+        return (pltpu.make_async_copy(k_hbm.at[layer, src],
+                                      k_buf.at[slot, dst], sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, src],
+                                      v_buf.at[slot, dst], sem.at[1, slot]))
 
     def page_by_page(g, slot, lo, hi, action):
         """One copy per live page ``lo <= i < hi`` of group ``g``."""
@@ -178,20 +180,21 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
 @functools.partial(jax.jit, static_argnames=("pages_per_copy", "run_pages",
                                              "interpret"))
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
-                           v_pool: jax.Array, tables: jax.Array,
-                           lengths: jax.Array, *, pages_per_copy: int,
-                           run_pages: int, interpret: bool = False
-                           ) -> jax.Array:
-    """q ``(B, KV, G, hd)`` float32 (grouped by KV head); pools ``(NB, BS,
-    KV*hd)``; tables ``(B, W)`` int32 (``-1`` past a row's blocks);
-    lengths ``(B,)`` int32, each at most ``W*BS``.  Returns ``(B, KV, G,
-    hd)`` float32: row ``b`` attends its first ``lengths[b]`` positions.
+                           v_pool: jax.Array, layer: jax.Array,
+                           tables: jax.Array, lengths: jax.Array, *,
+                           pages_per_copy: int, run_pages: int,
+                           interpret: bool = False) -> jax.Array:
+    """q ``(B, KV, G, hd)`` float32 (grouped by KV head); layer-stacked
+    pools ``(L, NB, BS, KV*hd)``, read at ``layer`` (an int32 scalar);
+    tables ``(B, W)`` int32 (``-1`` past a row's blocks); lengths ``(B,)``
+    int32, each at most ``W*BS``.  Returns ``(B, KV, G, hd)`` float32: row
+    ``b`` attends its first ``lengths[b]`` positions.
     Each step of the kernel's loop computes ``pages_per_copy`` blocks;
     ``run_pages`` consecutive blocks (dividing ``pages_per_copy``) move
     in one copy where the table holds them on consecutive pool blocks.
     """
     B, KV, G, hd = q.shape
-    NB, BS, C = k_pool.shape
+    _, NB, BS, C = k_pool.shape
     W = tables.shape[1]
     assert C == KV * hd and v_pool.shape == k_pool.shape, (q.shape,
                                                           k_pool.shape)
@@ -200,11 +203,12 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         _kernel, block_size=BS, pages_per_copy=pages_per_copy,
         run_pages=run_pages, pages_per_row=W, num_blocks=NB, kv_heads=KV, head_dim=hd,
         scale=1.0 / math.sqrt(hd))
-    row = pl.BlockSpec((1, KV, G, hd), lambda b, tables, lengths: (b, 0, 0, 0))
+    row = pl.BlockSpec((1, KV, G, hd),
+                       lambda b, tables, lengths, layer: (b, 0, 0, 0))
     kernel = pl.pallas_call(
         body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B,),
             in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
@@ -225,4 +229,5 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         interpret=interpret,
     )
     return kernel(jnp.asarray(tables, jnp.int32).reshape(-1),
-                  jnp.asarray(lengths, jnp.int32), q, k_pool, v_pool)
+                  jnp.asarray(lengths, jnp.int32),
+                  jnp.asarray(layer, jnp.int32).reshape(1), q, k_pool, v_pool)

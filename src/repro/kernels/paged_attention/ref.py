@@ -10,15 +10,17 @@ from repro.models.common import decode_attention, gather_kv_paged
 
 
 def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                        tables: jax.Array, lengths: jax.Array) -> jax.Array:
-    """q ``(B, H, hd)``; pools ``(NB, BS, KV*hd)``; tables ``(B, W)``;
-    lengths ``(B,)``.  Returns ``(B, H, hd)`` in q's dtype.  A row of
-    length 0 attends nothing; its output is meaningless (uniform weights
-    over masked positions), where the kernel writes zeros."""
+                        layer: jax.Array, tables: jax.Array,
+                        lengths: jax.Array) -> jax.Array:
+    """q ``(B, H, hd)``; layer-stacked pools ``(L, NB, BS, KV*hd)``, read
+    at ``layer``; tables ``(B, W)``; lengths ``(B,)``.  Returns ``(B, H,
+    hd)`` in q's dtype.  A row of length 0 attends nothing; its output is
+    meaningless (uniform weights over masked positions), where the kernel
+    writes zeros."""
     B, H, hd = q.shape
-    S = tables.shape[1] * k_pool.shape[1]
-    kv = k_pool.shape[2] // hd
-    k = gather_kv_paged(k_pool, tables).reshape(B, S, kv, hd)
-    v = gather_kv_paged(v_pool, tables).reshape(B, S, kv, hd)
+    S = tables.shape[1] * k_pool.shape[2]
+    kv = k_pool.shape[3] // hd
+    k = gather_kv_paged(k_pool, layer, tables).reshape(B, S, kv, hd)
+    v = gather_kv_paged(v_pool, layer, tables).reshape(B, S, kv, hd)
     return decode_attention(q[:, None], k.astype(q.dtype), v.astype(q.dtype),
                             lengths)[:, 0]

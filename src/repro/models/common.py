@@ -323,59 +323,68 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def scatter_kv(cache: jax.Array, new: jax.Array, cur: jax.Array,
-               active: jax.Array) -> jax.Array:
-    """Masked per-row KV append: write ``new`` (B, 1, C) into ``cache``
-    (B, S, C) at position ``cur[b]`` for every row with ``active[b]``;
-    inactive rows (and every other position) pass through untouched.
+def scatter_kv(cache: jax.Array, layer: jax.Array, new: jax.Array,
+               cur: jax.Array, active: jax.Array) -> jax.Array:
+    """Masked per-row KV append into a layer-stacked cache: write ``new``
+    (B, 1, C) into ``cache`` (L, B, S, C) at ``(layer, b, cur[b])`` for
+    every row with ``active[b]``; inactive rows, rows already full
+    (``cur[b] >= S``) and every other entry pass through untouched.
 
     This is the batched-decode twin of ``dynamic_update_slice_in_dim``: each
-    slot of a stacked serving cache appends at its *own* sequence position.
+    slot of a stacked serving cache appends at its *own* sequence position,
+    in place (dropped writes are out-of-bounds positions, XLA
+    ``mode="drop"``).
     """
-    S = cache.shape[1]
-    hit = (jnp.arange(S)[None, :] == jnp.reshape(cur, (-1, 1)))   # (B, S)
-    hit = hit & jnp.reshape(active, (-1, 1))
-    return jnp.where(hit[..., None], new.astype(cache.dtype), cache)
+    B, S = cache.shape[1:3]
+    pos = jnp.where(jnp.asarray(active).astype(bool),
+                    jnp.broadcast_to(jnp.asarray(cur, jnp.int32), (B,)), S)
+    return cache.at[layer, jnp.arange(B), pos].set(
+        new[:, 0].astype(cache.dtype), mode="drop")
 
 
 # ------------------------------------------------------------- paged KV
-def gather_kv_paged(pool: jax.Array, tables: jax.Array) -> jax.Array:
-    """Materialize per-request KV views from a paged pool.
+def gather_kv_paged(pool: jax.Array, layer: jax.Array,
+                    tables: jax.Array) -> jax.Array:
+    """Materialize per-request KV views of one layer of a paged pool.
 
-    ``pool`` is one layer's block store ``(NB, BS, C)`` — ``NB`` blocks
-    of ``BS`` token positions each; ``tables (B, W)`` int32 maps request
-    ``b``'s logical block ``w`` (token positions ``[w*BS, (w+1)*BS)``)
-    onto a pool block, ``-1`` padding unassigned entries.  Returns the
-    dense view ``(B, W*BS, C)`` — identical in shape and content (at
-    every position below the request's fill) to the stacked dense
-    cache's row, so the attention math downstream is the same function.
+    ``pool`` is the layer-stacked block store ``(L, NB, BS, C)`` — per
+    layer, ``NB`` blocks of ``BS`` token positions each; ``tables (B, W)``
+    int32 maps request ``b``'s logical block ``w`` (token positions
+    ``[w*BS, (w+1)*BS)``) onto a pool block, ``-1`` padding unassigned
+    entries.  Returns layer ``layer``'s dense view ``(B, W*BS, C)`` —
+    identical in shape and content (at every position below the request's
+    fill) to the stacked dense cache's row, so the attention math
+    downstream is the same function.  One gather over (layer, block):
+    the layer's whole ``(NB, BS, C)`` pool is never sliced out.
     Unassigned/garbage entries are gathered from block 0 and must be
     masked by the caller's length masking, exactly like the dense
     cache's unwritten tail.
     """
     B, W = tables.shape
-    _, BS, C = pool.shape
+    _, _, BS, C = pool.shape
     # every row's whole W*BS view is read, whatever its fill: where paged
     # decode takes this gather, the serve loop's dispatch_log counts
     # "read_positions" as rows * W * BS * decode steps, and that count must
     # follow any change in what is read (PagedServeLoop._read_positions)
-    got = jnp.take(pool, jnp.clip(tables, 0), axis=0)    # (B, W, BS, C)
+    got = pool[layer, jnp.clip(tables, 0)]               # (B, W, BS, C)
     return got.reshape(B, W * BS, C)
 
 
-def scatter_kv_paged(pool: jax.Array, new: jax.Array, cur: jax.Array,
-                     active: jax.Array, tables: jax.Array) -> jax.Array:
-    """Masked per-request KV append into a paged pool.
+def scatter_kv_paged(pool: jax.Array, layer: jax.Array, new: jax.Array,
+                     cur: jax.Array, active: jax.Array,
+                     tables: jax.Array) -> jax.Array:
+    """Masked per-request KV append into one layer of a paged pool.
 
-    The paged twin of :func:`scatter_kv`: write ``new (B, 1, C)`` at
-    request ``b``'s logical position ``cur[b]`` — pool block
-    ``tables[b, cur[b] // BS]``, offset ``cur[b] % BS`` — for every row
-    with ``active[b]``.  Inactive rows, rows whose position falls on an
-    unassigned (``-1``) table entry, and rows past their table's width
-    are dropped via an out-of-bounds index (XLA ``mode="drop"``), so a
-    frozen or unallocated slot can never corrupt a live block.
+    The paged twin of :func:`scatter_kv`: write ``new (B, 1, C)`` into the
+    layer-stacked pool ``(L, NB, BS, C)`` at layer ``layer``, request
+    ``b``'s logical position ``cur[b]`` — pool block ``tables[b, cur[b] //
+    BS]``, offset ``cur[b] % BS`` — for every row with ``active[b]``, in
+    place.  Inactive rows, rows whose position falls on an unassigned
+    (``-1``) table entry, and rows past their table's width are dropped
+    via an out-of-bounds block index (XLA ``mode="drop"``), so a frozen or
+    unallocated slot can never corrupt a live block.
     """
-    NB, BS, _ = pool.shape
+    _, NB, BS, _ = pool.shape
     B, W = tables.shape
     cur = jnp.asarray(cur, jnp.int32)
     widx = jnp.clip(cur // BS, 0, W - 1)
@@ -383,8 +392,8 @@ def scatter_kv_paged(pool: jax.Array, new: jax.Array, cur: jax.Array,
     ok = (jnp.asarray(active).astype(bool) & (blk >= 0)
           & (cur < W * BS))
     blk = jnp.where(ok, blk, NB)                 # OOB -> dropped write
-    return pool.at[blk, cur % BS].set(new[:, 0].astype(pool.dtype),
-                                      mode="drop")
+    return pool.at[layer, blk, cur % BS].set(new[:, 0].astype(pool.dtype),
+                                             mode="drop")
 
 
 # ----------------------------------------------------------------- MLPs

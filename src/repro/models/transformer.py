@@ -336,7 +336,8 @@ def _expert_counters(sizes: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 def _dense_attend(cfg: ModelConfig, q: jax.Array, kc: jax.Array,
                   vc: jax.Array, attend_len: jax.Array) -> jax.Array:
-    """:func:`decode_attention` over flat ``(B, S, KV*hd)`` cache rows."""
+    """:func:`decode_attention` over flat ``(B, S, KV*hd)`` cache rows (one
+    layer's)."""
     shape = kc.shape[:2] + (cfg.num_kv_heads, cfg.head_dim)
     return decode_attention(q, kc.reshape(shape).astype(q.dtype),
                             vc.reshape(shape).astype(q.dtype), attend_len)
@@ -352,15 +353,20 @@ def _decode_forward(params: Tree, cfg: ModelConfig,
     """The one-token decode body shared by per-slot, batched and paged paths.
 
     The paths differ ONLY in how a layer's new K/V row lands in the
-    cache (``kv_append(cache_2d, new_(B,1,kv))``: ``dynamic_update_slice``
+    cache (``kv_append(cache, l, new_(B,1,kv))``: ``dynamic_update_slice``
     at a scalar length vs a masked per-row scatter vs a block-table paged
-    scatter), in how attention reads the cache back (``attend(q, kc,
-    vc)``: by default :func:`decode_attention` over the dense rows with
-    ``attend_len`` masking; the paged pool supplies its own reader, see
-    :func:`paged_decode_step`), and in the position/length values fed to
-    rotary and attention masking — everything else (qkv, residual,
-    MLP/MoE, final norm, head) is this one function, so the engines
-    cannot drift apart.
+    scatter), in how attention reads the cache back (``attend(q, kc, vc,
+    l)``: by default :func:`decode_attention` over layer ``l``'s dense
+    rows with ``attend_len`` masking; the paged pool supplies its own
+    reader, see :func:`paged_decode_step`), and in the position/length
+    values fed to rotary and attention masking — everything else (qkv,
+    residual, MLP/MoE, final norm, head) is this one function, so the
+    engines cannot drift apart.
+
+    The layer-stacked caches ``(L, ...)`` are the layer scan's carry, not
+    scanned inputs: each layer writes its K/V into them in place at its
+    layer index ``l`` and reads them back there, so no call slices a
+    layer's cache out of the stack or copies the stack whole.
 
     Each stage runs in a named scope (``embed``, ``qkv``, ``kv_append``,
     ``attention``, ``attn_out``, ``mlp``, ``head``; the paged gather adds
@@ -376,6 +382,10 @@ def _decode_forward(params: Tree, cfg: ModelConfig,
     Returns (logits (B, V), new_k, new_v, the held experts' slots per
     layer ``(L, G)``, or None where the held-share layer did not run).
     """
+    if attend is None:
+        def attend(q, kc, vc, l):
+            with jax.named_scope("attention"):
+                return _dense_attend(cfg, q, kc[l], vc[l], attend_len)
     with jax.named_scope("embed"):
         if cfg.frontend != "none":
             x = inputs["embeds"].astype(params["embed"]["tok"].dtype)
@@ -387,29 +397,25 @@ def _decode_forward(params: Tree, cfg: ModelConfig,
     pos3d = inputs.get("positions_3d")  # (3,B,1) for qwen2-vl
     held = cfg.is_moe and moe_valid is not None
 
-    def body(x, layer):
-        lp, kc, vc = layer                      # kc/vc: (B, S, KV*hd) flat
+    def body(carry, layer):
+        x, kc, vc = carry                       # kc/vc: (L, ...) stacked
+        lp, l = layer
         with jax.named_scope("qkv"):
             h = rms_norm(x, lp["ln1"])
             q, k, v = _attn_qkv(lp, cfg, h)
             q, k = _position_rotate(cfg, q, k, positions, pos3d)
         with jax.named_scope("kv_append"):
-            kc = kv_append(kc, k.reshape(B, 1, cfg.kv_dim))
-            vc = kv_append(vc, v.reshape(B, 1, cfg.kv_dim))
-        if attend is not None:
-            a = attend(q, kc, vc)
-        else:
-            with jax.named_scope("attention"):
-                a = _dense_attend(cfg, q, kc, vc, attend_len)
+            kc = kv_append(kc, l, k.reshape(B, 1, cfg.kv_dim))
+            vc = kv_append(vc, l, v.reshape(B, 1, cfg.kv_dim))
+        a = attend(q, kc, vc, l)
         with jax.named_scope("attn_out"):
             a = a.reshape(B, 1, cfg.q_dim)
             x = x + jnp.einsum("bsq,qd->bsd", a, lp["attn"]["wo"])
-        ys = (kc, vc)
+        sizes = ()
         with jax.named_scope("mlp"):
             h = rms_norm(x, lp["ln2"])
             if held:
                 out, sizes = _held_ffn(cfg, lp, h, moe_valid[:, None])
-                ys += (sizes,)
             elif cfg.is_moe:
                 out, _ = moe_ffn(h, lp["moe"]["router"], lp["moe"]["w_gate"],
                                  lp["moe"]["w_up"], lp["moe"]["w_down"], cfg,
@@ -421,15 +427,17 @@ def _decode_forward(params: Tree, cfg: ModelConfig,
                 out = mlp_gelu(h, lp["mlp"]["wi"], lp["mlp"]["bi"],
                                lp["mlp"]["wo"], lp["mlp"]["bo"])
             x = x + out
-        return x, ys
+        return (x, kc, vc), sizes
 
-    x, ys = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    (x, new_k, new_v), sizes = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"])
         head = (params["embed"]["tok"].T if cfg.tie_embeddings
                 else params["lm_head"])
         logits = jnp.einsum("bsd,dv->bsv", x, head)[:, 0, :cfg.vocab_size]
-    return logits, ys[0], ys[1], (ys[2] if held else None)
+    return logits, new_k, new_v, (sizes if held else None)
 
 
 def batched_decode_step(params: Tree, cfg: ModelConfig,
@@ -455,7 +463,7 @@ def batched_decode_step(params: Tree, cfg: ModelConfig,
     logits, new_k, new_v, _ = _decode_forward(
         params, cfg, inputs, cache,
         positions=cur[:, None],                     # (B, 1) per-slot
-        kv_append=lambda c, new: scatter_kv(c, new, cur, active),
+        kv_append=lambda c, l, new: scatter_kv(c, l, new, cur, active),
         attend_len=cur + 1,
         cap_e=cap_e)
     new_cache = {"k": new_k, "v": new_v,
@@ -561,9 +569,9 @@ def paged_kernel_engages(cfg: ModelConfig, block_size: int, dtype,
 
 def _paged_attend(cfg: ModelConfig, pool: jax.Array, tables: jax.Array,
                   cur: jax.Array, active: jax.Array, interpret: bool):
-    """The paged decode's attention reader, ``attend(q, kc, vc)`` over
-    one layer's pools ``(NB, BS, C)`` (``pool`` is the stacked
-    ``(L, NB, BS, C)`` one: its block size and dtype choose the path).
+    """The paged decode's attention reader, ``attend(q, kc, vc, l)`` over
+    layer ``l`` of the stacked pools ``(L, NB, BS, C)`` (``pool`` is one
+    of them: its block size and dtype choose the path).
 
     The gather materializes every row's whole ``(B, W*BS, C)`` view and
     runs the dense :func:`decode_attention` on it.  The kernel
@@ -572,26 +580,26 @@ def _paged_attend(cfg: ModelConfig, pool: jax.Array, tables: jax.Array,
     :func:`paged_kernel_engages` says so (``interpret=True`` runs it in
     interpret mode on any platform).  Both give an active row the same
     attention; an inactive row's result is discarded by the caller."""
-    def gather(q, kc, vc):
+    def gather(q, kc, vc, l):
         with jax.named_scope("kv_gather"):
-            k = gather_kv_paged(kc, tables)          # (B, W*BS, C)
-            v = gather_kv_paged(vc, tables)
+            k = gather_kv_paged(kc, l, tables)       # (B, W*BS, C)
+            v = gather_kv_paged(vc, l, tables)
         with jax.named_scope("attention"):
             return _dense_attend(cfg, q, k, v, cur + 1)
 
     live = jnp.where(active, cur + 1, 0)
 
-    def kernel(q, kc, vc):
+    def kernel(q, kc, vc, l):
         with jax.named_scope("attention"):
-            return paged_attention(q[:, 0], kc, vc, tables, live,
+            return paged_attention(q[:, 0], kc, vc, l, tables, live,
                                    interpret=interpret)[:, None]
 
     if interpret:
         return kernel
     if not paged_kernel_engages(cfg, pool.shape[2], pool.dtype):
         return gather
-    return lambda q, kc, vc: jax.lax.platform_dependent(
-        q, kc, vc, tpu=kernel, default=gather)
+    return lambda q, kc, vc, l: jax.lax.platform_dependent(
+        q, kc, vc, l, tpu=kernel, default=gather)
 
 
 def paged_decode_step(params: Tree, cfg: ModelConfig,
@@ -629,8 +637,8 @@ def paged_decode_step(params: Tree, cfg: ModelConfig,
     logits, new_k, new_v, sizes = _decode_forward(
         params, cfg, inputs, cache,
         positions=cur[:, None],
-        kv_append=lambda c, new: scatter_kv_paged(c, new, cur, active,
-                                                  tables),
+        kv_append=lambda c, l, new: scatter_kv_paged(c, l, new, cur, active,
+                                                     tables),
         attend_len=cur + 1,
         cap_e=None,
         attend=_paged_attend(cfg, cache["k"], tables, cur, active,
@@ -757,15 +765,16 @@ def prefill_paged_chunk(params: Tree, cfg: ModelConfig,
     scale = 1.0 / math.sqrt(cfg.head_dim)
     chunk_pos = start + jnp.arange(Cb, dtype=jnp.int32)      # (Cb,)
 
-    def body(x, layer):
-        lp, kc, vc = layer                      # kc/vc: (NB, BS, C)
+    def body(carry, layer):
+        x, kc, vc = carry                       # kc/vc: (L, NB, BS, C)
+        lp, l = layer
         with jax.named_scope("qkv"):
             h = rms_norm(x, lp["ln1"])
             q, k, v = _attn_qkv(lp, cfg, h)
             q, k = _position_rotate(cfg, q, k, positions, pos3d)
         with jax.named_scope("kv_gather"):
-            past_k = gather_kv_paged(kc, tab_b)     # (1, S_past, C)
-            past_v = gather_kv_paged(vc, tab_b)
+            past_k = gather_kv_paged(kc, l, tab_b)  # (1, S_past, C)
+            past_v = gather_kv_paged(vc, l, tab_b)
         with jax.named_scope("attention"):
             keys = jnp.concatenate(
                 [past_k.reshape(B, S_past, cfg.num_kv_heads, cfg.head_dim
@@ -789,8 +798,7 @@ def prefill_paged_chunk(params: Tree, cfg: ModelConfig,
             h = rms_norm(x, lp["ln2"])
             if cfg.is_moe:
                 real = jnp.arange(Cb)[None, :] < length      # not the pad
-                out, sizes = _held_ffn(cfg, lp, h, real)
-                counted = (sizes,)
+                out, counted = _held_ffn(cfg, lp, h, real)
             elif cfg.mlp == "swiglu":
                 out = mlp_swiglu(h, lp["mlp"]["wi_gate"], lp["mlp"]["wi_up"],
                                  lp["mlp"]["wo"])
@@ -804,15 +812,16 @@ def prefill_paged_chunk(params: Tree, cfg: ModelConfig,
         with jax.named_scope("kv_append"):
             write_ok = jnp.arange(Cb) < length
             kc = scatter_kv_paged(
-                kc, k.reshape(Cb, 1, cfg.kv_dim), chunk_pos, write_ok,
+                kc, l, k.reshape(Cb, 1, cfg.kv_dim), chunk_pos, write_ok,
                 jnp.broadcast_to(tables, (Cb, W)))
             vc = scatter_kv_paged(
-                vc, v.reshape(Cb, 1, cfg.kv_dim), chunk_pos, write_ok,
+                vc, l, v.reshape(Cb, 1, cfg.kv_dim), chunk_pos, write_ok,
                 jnp.broadcast_to(tables, (Cb, W)))
-        return x, (kc, vc) + counted
+        return (x, kc, vc), counted
 
-    x, (ks, vs, *counted) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
+    (x, ks, vs), counted = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"])
         head = (params["embed"]["tok"].T if cfg.tie_embeddings
@@ -821,7 +830,7 @@ def prefill_paged_chunk(params: Tree, cfg: ModelConfig,
                                               keepdims=False)
         logits = jnp.einsum("bd,dv->bv", x_last, head)[:, :cfg.vocab_size]
     return ((logits, {"k": ks, "v": vs})
-            + (_expert_counters(counted[0]) if counted else ()))
+            + (_expert_counters(counted) if cfg.is_moe else ()))
 
 
 def decode_step(params: Tree, cfg: ModelConfig, inputs: Dict[str, jax.Array],
@@ -836,8 +845,8 @@ def decode_step(params: Tree, cfg: ModelConfig, inputs: Dict[str, jax.Array],
     logits, new_k, new_v, _ = _decode_forward(
         params, cfg, inputs, cache,
         positions=jnp.full((B, 1), cur, dtype=jnp.int32),
-        kv_append=lambda c, new: jax.lax.dynamic_update_slice_in_dim(
-            c, new.astype(c.dtype), cur, axis=1),
+        kv_append=lambda c, l, new: jax.lax.dynamic_update_slice(
+            c, new[None].astype(c.dtype), (l, 0, cur, 0)),
         attend_len=cur + 1,
         cap_e=cap_e)
     new_cache = {"k": new_k, "v": new_v, "len": cur + 1}

@@ -162,6 +162,11 @@ def test_wkv_strong_decay_no_overflow():
 
 
 # --------------------------------------------------- paged decode attention
+PAGED_LAYERS = 4
+
+
+@pytest.mark.parametrize("layer", [0, 2, PAGED_LAYERS - 1],
+                         ids=["first", "middle", "last"])
 @pytest.mark.parametrize(
     "heads,kv,block_size,pages_per_copy,consecutive,dtype", [
         pytest.param(16, 2, 16, 1, False, jnp.float32, id="gqa8-copy1"),
@@ -179,24 +184,27 @@ def test_wkv_strong_decay_no_overflow():
     ])
 def test_paged_attention_matches_gather_oracle(heads, kv, block_size,
                                                pages_per_copy, consecutive,
-                                               dtype):
-    """The kernel reads each row's live blocks through its table and
-    matches the gather + dense decode attention oracle: ragged lengths
-    (0, 1, BS-1, BS, BS+1, the whole W*BS, one short), ``-1`` past each row's
-    blocks, and an inactive row (length 0) that still holds blocks; a
-    row of length 0 gets zeros.  qwen2.5-3b's GQA (16 heads on 2, hd
-    128), qwen3-moe-235b-a22b's (64 heads on 4) and one query head per
-    KV head; blocks scattered over the pool,
-    or handed out in order as a fresh pool does (runs of consecutive
-    blocks, which move in one copy), broken once and reversed once."""
-    hd, W, BS = 128, 6, block_size
+                                               dtype, layer):
+    """The kernel reads each row's live blocks through its table, at one
+    layer of a layer-stacked pool, and matches the gather + dense decode
+    attention oracle: ragged lengths (0, 1, BS-1, BS, BS+1, the whole
+    W*BS, one short), ``-1`` past each row's blocks, and an inactive row
+    (length 0) that still holds blocks; a row of length 0 gets zeros.
+    qwen2.5-3b's GQA (16 heads on 2, hd 128), qwen3-moe-235b-a22b's (64
+    heads on 4) and one query head per KV head; blocks scattered over the
+    pool, or handed out in order as a fresh pool does (runs of
+    consecutive blocks, which move in one copy), broken once and
+    reversed once.  Every layer of the pool holds different data, and the
+    first, a middle and the last layer are read, so a kernel that reads
+    any other layer fails."""
+    hd, W, BS, L = 128, 6, block_size, PAGED_LAYERS
     lengths = [0, 1, BS - 1, BS, BS + 1, W * BS, W * BS - 1, 0]
     B = len(lengths)
     nb = B * W + 3
     rng = np.random.default_rng(heads + BS)
     q = jnp.asarray(rng.normal(size=(B, heads, hd)), dtype)
-    k_pool = jnp.asarray(rng.normal(size=(nb, BS, kv * hd)), dtype)
-    v_pool = jnp.asarray(rng.normal(size=(nb, BS, kv * hd)), dtype)
+    k_pool = jnp.asarray(rng.normal(size=(L, nb, BS, kv * hd)), dtype)
+    v_pool = jnp.asarray(rng.normal(size=(L, nb, BS, kv * hd)), dtype)
     tables = np.full((B, W), -1, np.int32)
     order = np.arange(nb) if consecutive else rng.permutation(nb)
     blocks = iter(order.tolist())
@@ -207,15 +215,28 @@ def test_paged_attention_matches_gather_oracle(heads, kv, block_size,
         tables[5, 2] = nb - 1                     # a run broken
         tables[4, :2] = tables[4, 1::-1]          # a run reversed
     lens = jnp.asarray(lengths, jnp.int32)
-    out = paged_attention(q, k_pool, v_pool, jnp.asarray(tables), lens,
+    at = jnp.int32(layer)
+    out = paged_attention(q, k_pool, v_pool, at, jnp.asarray(tables), lens,
                           pages_per_copy=pages_per_copy, interpret=True)
-    ref = paged_attention_ref(q, k_pool, v_pool, jnp.asarray(tables), lens)
+    ref = paged_attention_ref(q, k_pool, v_pool, at, jnp.asarray(tables),
+                              lens)
+    # the oracle reads the layer it is given, and only that layer
+    alone = paged_attention_ref(q, k_pool[layer][None], v_pool[layer][None],
+                                jnp.int32(0), jnp.asarray(tables), lens)
+    np.testing.assert_array_equal(np.asarray(ref, np.float32),
+                                  np.asarray(alone, np.float32))
     assert out.shape == q.shape and out.dtype == q.dtype
     live = np.asarray(lengths) > 0
     np.testing.assert_allclose(np.asarray(out, np.float32)[live],
                                np.asarray(ref, np.float32)[live],
                                **_tol(dtype))
     assert not np.asarray(out, np.float32)[~live].any()
+    for other in set(range(L)) - {layer}:
+        elsewhere = paged_attention_ref(q, k_pool, v_pool, jnp.int32(other),
+                                        jnp.asarray(tables), lens)
+        assert not np.allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(elsewhere, np.float32)[live],
+                               **_tol(dtype))
 
 
 def test_paged_attention_shape_test_and_copy_count():

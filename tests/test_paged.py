@@ -218,6 +218,87 @@ def test_plan_prefill_chunks_follows_the_clause():
 
 
 # ---------------------------------------------------------------------------
+# the KV helpers on a layer-stacked pool
+# ---------------------------------------------------------------------------
+HELPER_LAYERS = 3
+
+
+def _stacked_pool(rng, nb=12, bs=4, c=8):
+    """A (L, NB, BS, C) pool whose every layer holds different data."""
+    return jnp.asarray(rng.normal(size=(HELPER_LAYERS, nb, bs, c)),
+                       jnp.bfloat16)
+
+
+@pytest.mark.parametrize("layer", range(HELPER_LAYERS))
+def test_scatter_kv_paged_writes_only_its_layer(layer):
+    """An append at layer ``l`` changes exactly the ``(l, block, offset)``
+    entry of each active row whose position lands on an assigned block
+    inside its table, and every other entry of every layer stays
+    bit-identical: an inactive row, a ``-1`` table entry and a position
+    past ``W*BS`` are dropped."""
+    from repro.models.common import scatter_kv_paged
+    rng = np.random.default_rng(10 + layer)
+    pool = _stacked_pool(rng)
+    C = pool.shape[-1]
+    tables = np.array([[3, 7, -1], [5, 0, 9], [1, -1, -1], [2, 4, 6],
+                       [8, 10, 11]], np.int32)
+    cur = np.array([5, 0, 6, 9, 12], np.int32)   # last: past W*BS = 12
+    active = np.array([True, True, True, False, True])
+    new = jnp.asarray(rng.normal(size=(5, 1, C)), jnp.bfloat16)
+    got = np.asarray(scatter_kv_paged(pool, jnp.int32(layer), new,
+                                      jnp.asarray(cur), jnp.asarray(active),
+                                      jnp.asarray(tables)), np.float32)
+    want = np.asarray(pool, np.float32).copy()
+    # row 0: block 7 offset 1; row 1: block 5 offset 0; row 2 lands on -1,
+    # row 3 is inactive, row 4 is past its table: dropped
+    for row, blk, off in [(0, 7, 1), (1, 5, 0)]:
+        want[layer, blk, off] = np.asarray(new[row, 0], np.float32)
+    np.testing.assert_array_equal(got, want)
+    other = [i for i in range(HELPER_LAYERS) if i != layer]
+    np.testing.assert_array_equal(got[other],
+                                  np.asarray(pool, np.float32)[other])
+
+
+@pytest.mark.parametrize("layer", range(HELPER_LAYERS))
+def test_scatter_kv_writes_only_its_layer(layer):
+    """The dense twin: an append at layer ``l`` of a ``(L, B, S, C)``
+    cache writes ``(l, b, cur[b])`` of each active row with room, and
+    nothing else; a full row and an inactive row are dropped."""
+    from repro.models.common import scatter_kv
+    rng = np.random.default_rng(20 + layer)
+    cache = jnp.asarray(rng.normal(size=(HELPER_LAYERS, 4, 6, 8)),
+                        jnp.bfloat16)
+    cur = np.array([0, 5, 6, 2], np.int32)        # row 2 is full
+    active = np.array([True, True, True, False])
+    new = jnp.asarray(rng.normal(size=(4, 1, 8)), jnp.bfloat16)
+    got = np.asarray(scatter_kv(cache, jnp.int32(layer), new,
+                                jnp.asarray(cur), jnp.asarray(active)),
+                     np.float32)
+    want = np.asarray(cache, np.float32).copy()
+    for row in (0, 1):
+        want[layer, row, cur[row]] = np.asarray(new[row, 0], np.float32)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("layer", range(HELPER_LAYERS))
+def test_gather_kv_paged_reads_its_layer(layer):
+    """The gather at layer ``l`` equals the per-layer gather of ``pool[l]``
+    through the clipped tables (``-1`` reads block 0), and differs from
+    every other layer's."""
+    from repro.models.common import gather_kv_paged
+    rng = np.random.default_rng(30 + layer)
+    pool = _stacked_pool(rng)
+    _, _, BS, C = pool.shape
+    tables = jnp.asarray([[3, 7, -1], [11, 0, 9]], jnp.int32)
+    got = np.asarray(gather_kv_paged(pool, jnp.int32(layer), tables))
+    one = jnp.take(pool[layer], jnp.clip(tables, 0), axis=0)
+    np.testing.assert_array_equal(got, np.asarray(one).reshape(2, 3 * BS, C))
+    for other in set(range(HELPER_LAYERS)) - {layer}:
+        assert not np.array_equal(
+            got, np.asarray(gather_kv_paged(pool, jnp.int32(other), tables)))
+
+
+# ---------------------------------------------------------------------------
 # engine equivalence (module-scoped loops: compile once, swap schedulers)
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")

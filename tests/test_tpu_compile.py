@@ -10,6 +10,7 @@ import: only the worker that runs this file loads the TPU library.
 
 import dataclasses
 import json
+import re
 from pathlib import Path
 
 import jax
@@ -86,6 +87,23 @@ def _device_bytes(compiled) -> int:
             + m.temp_size_in_bytes)
 
 
+def _assert_pool_updated_in_place(compiled, pool) -> None:
+    """The stacked ``(L, NB, BS, C)`` k/v pools cross the layer scan as its
+    carry: the optimized program holds no copy of a stacked pool, no array
+    of one layer's whole ``(NB, BS, C)`` pool, and less scratch than one
+    k pool, so neither pool is ever held twice."""
+    L, NB, BS, C = pool["k"].shape
+    stacked = f"bf16[{L},{NB},{BS},{C}]"
+    hlo = compiled.as_text()
+    # "%name = <shape or (tuple of shapes)> <opcode>(operands), ..."
+    ops = re.findall(r"= (\(.*?\)|\S+) ([\w-]+)\(", hlo)
+    assert not [op for shape, op in ops
+                if op in ("copy", "copy-start") and stacked in shape]
+    assert f"bf16[{NB},{BS},{C}]" not in hlo
+    k_bytes = L * NB * BS * C * pool["k"].dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < k_bytes
+
+
 @pytest.fixture(scope="module")
 def qwen(one_chip):
     """qwen2.5-3b at published widths in bf16, as shapes on one chip."""
@@ -124,25 +142,33 @@ def test_paged_prefill_chunk_fits_one_v5e(one_chip, qwen):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
-def test_paged_decode_reads_the_pool_through_the_kernel(one_chip):
-    """At the benchmark cell's serving shapes, the paged decode program
-    lowered for a v5e fits the chip, holds the Pallas paged-attention
-    kernel, and no longer materializes any row's whole (32, 4192, ...)
-    view, nor the (32, 262, 16, ...) gather that built it."""
-    # tied embeddings, as published and as the benchmark serves them
+@pytest.fixture(scope="module")
+def qwen_cell(one_chip):
+    """qwen2.5-3b tied, as published and as the benchmark serves it, with
+    the cell's 8,320-block pool, as shapes on one chip."""
     cfg = dataclasses.replace(get_config("qwen2.5-3b"), tie_embeddings=True)
     model = get_model(cfg)
     params, _ = model.init(jax.random.PRNGKey(0), jnp.bfloat16,
                            abstract=True)
     pool, _ = model.init_paged_decode(CELL_NUM_BLOCKS, BLOCK_SIZE,
                                       abstract=True)
+    return model, _on(one_chip, params), _on(one_chip, pool)
+
+
+def test_paged_decode_reads_the_pool_through_the_kernel(one_chip, qwen_cell):
+    """At the benchmark cell's serving shapes, the paged decode program
+    lowered for a v5e fits the chip, holds the Pallas paged-attention
+    kernel, and no longer materializes any row's whole (32, 4192, ...)
+    view, nor the (32, 262, 16, ...) gather that built it; the pool is
+    updated in place through the step scan and the layer scan."""
+    model, params, pool = qwen_cell
     C, W = CELL_CONCURRENCY, CELL_MAX_CONTEXT // BLOCK_SIZE
     i32 = jnp.int32
     step = jax.jit(make_paged_serve_step(model, DECODE_STEPS),
                    donate_argnums=(2,))
     compiled = step.lower(
-        _on(one_chip, params), {"tokens": _sds(one_chip, (C, 1), i32)},
-        _on(one_chip, pool), _sds(one_chip, (C, W), i32),
+        params, {"tokens": _sds(one_chip, (C, 1), i32)}, pool,
+        _sds(one_chip, (C, W), i32),
         _sds(one_chip, (C,), i32), _sds(one_chip, (C,), i32),
         _sds(one_chip, (C,), jnp.bool_), _sds(one_chip, (C,), i32),
         _sds(one_chip, (), i32)).compile()
@@ -152,6 +178,24 @@ def test_paged_decode_reads_the_pool_through_the_kernel(one_chip):
     assert "tpu_custom_call" in hlo
     assert f"[{C},{W * BLOCK_SIZE}," not in hlo
     assert f"[{C},{W},{BLOCK_SIZE}," not in hlo
+    _assert_pool_updated_in_place(compiled, pool)
+
+
+def test_paged_prefill_chunk_updates_the_cell_pool_in_place(one_chip,
+                                                            qwen_cell):
+    """At the benchmark cell's shapes, a 512-token prefill chunk fits the
+    chip and writes the stacked pool in place: no copy of it and no one
+    layer's whole pool."""
+    model, params, pool = qwen_cell
+    W = CELL_MAX_CONTEXT // BLOCK_SIZE
+    i32 = jnp.int32
+    step = jax.jit(make_paged_prefill_step(model), donate_argnums=(2,))
+    compiled = step.lower(
+        params, {"tokens": _sds(one_chip, (1, PREFILL_CHUNK), i32)}, pool,
+        _sds(one_chip, (W,), i32), _sds(one_chip, (), i32),
+        _sds(one_chip, (), i32)).compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes < V5E_HBM_BYTES
+    _assert_pool_updated_in_place(compiled, pool)
 
 
 @pytest.fixture(scope="module")
@@ -175,8 +219,9 @@ def moe_share(one_chip):
 def test_moe_share_programs_fit_one_v5e(one_chip, moe_share, program):
     """Both serving programs of the MoE cell compile for a v5e with 256
     MiB to spare; the decode program reads the pool through the Pallas
-    paged-attention kernel (GQA 16: 64 query heads on 4), and the expert
-    layer is the compiler's grouped matmul (``ragged-dot``)."""
+    paged-attention kernel (GQA 16: 64 query heads on 4), the expert
+    layer is the compiler's grouped matmul (``ragged-dot``), and both
+    update the stacked pool in place."""
     model, params, pool, s = moe_share
     C, W = s["concurrency"], s["max_context"] // s["block_size"]
     i32 = jnp.int32
@@ -199,9 +244,10 @@ def test_moe_share_programs_fit_one_v5e(one_chip, moe_share, program):
     hlo = compiled.as_text()
     assert "ragged-dot" in hlo
     if program == "serve_step":
-        pages = f"bf16[{s['num_blocks']},{s['block_size']},512]"
+        pages = f"bf16[{','.join(map(str, pool['k'].shape))}]"
         assert any("tpu_custom_call" in line and pages in line
                    for line in hlo.splitlines())
+    _assert_pool_updated_in_place(compiled, pool)
 
 
 def _kernel_cases():
