@@ -30,15 +30,10 @@ lint:           ## ruff over the whole tree (ruff.toml) + docs registry sync
 
 smoke:          ## public-API smoke: quickstart + clause-string dry runs (CI job)
 	$(PYTHON) examples/quickstart.py
+	$(PYTHON) -m repro.launch.serve --arch rwkv6-3b --smoke \
+	    --requests 4 --max-concurrency 2 --scheduler "guided,4" --max-new 4
 	$(PYTHON) -m repro.launch.serve --arch qwen2.5-3b --smoke \
-	    --requests 4 --slots 2 --scheduler "guided,4" --max-new 4
-	$(PYTHON) -m repro.launch.serve --arch qwen2.5-3b --smoke \
-	    --requests 4 --slots 2 --scheduler "guided,4" --max-new 4 --per-slot
-	$(PYTHON) -m repro.launch.serve --arch qwen2.5-3b --smoke \
-	    --requests 4 --slots 2 --scheduler "guided,4" --max-new 8 \
-	    --decode-steps 8
-	$(PYTHON) -m repro.launch.serve --arch qwen2.5-3b --smoke \
-	    --requests 8 --scheduler "guided,4" --max-new 8 --paged-kv \
+	    --requests 8 --scheduler "guided,4" --max-new 8 \
 	    --num-blocks 24 --block-size 8 --max-concurrency 8 \
 	    --decode-steps 4
 	$(PYTHON) -m pytest -q tests/test_serve.py
@@ -61,7 +56,7 @@ smoke:          ## public-API smoke: quickstart + clause-string dry runs (CI job
 	    --steps 2 --batch 8 --seq-len 64 --hosts 4 --microbatches 2 \
 	    --scheduler "hier(host=awf, device=guided,4)"
 	$(PYTHON) -m repro.launch.serve --arch qwen2.5-3b --smoke \
-	    --requests 4 --slots 2 --scheduler auto --max-new 4
+	    --requests 4 --max-concurrency 2 --scheduler auto --max-new 4
 	XLA_FLAGS=--xla_force_host_platform_device_count=4 \
 	    $(PYTHON) -m repro.launch.train --arch qwen2.5-3b --smoke \
 	    --steps 2 --batch 4 --seq-len 64 --hosts 4 \
@@ -71,7 +66,7 @@ smoke:          ## public-API smoke: quickstart + clause-string dry runs (CI job
 	    --steps 4 --batch 8 --seq-len 64 --hosts 4 --elastic \
 	    --kill-hosts 2,3 --kill-at 2
 	$(PYTHON) -m repro.launch.serve --arch qwen2.5-3b --smoke \
-	    --requests 8 --scheduler dynamic --max-new 6 --paged-kv \
+	    --requests 8 --scheduler dynamic --max-new 6 \
 	    --num-blocks 48 --block-size 8 --max-concurrency 8 \
 	    --kill-rows 3 --kill-at-dispatch 2
 	XLA_FLAGS=--xla_force_host_platform_device_count=4 \

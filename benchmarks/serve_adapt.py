@@ -1,6 +1,6 @@
 """Adaptive-replan benchmark: the telemetry -> history -> replan loop.
 
-Two stages, both serialized machine-readably (CI: ``--json
+Four stages, all serialized machine-readably (CI: ``--json
 BENCH_serve.json`` uploaded as an artifact, ``--gate`` as the exit code):
 
 1. **Executor steady state** (pure host, no JAX): an AWF loop over a team
@@ -14,26 +14,13 @@ BENCH_serve.json`` uploaded as an artifact, ``--gate`` as the exit code):
    numbers.
 
 2. **Serve smoke** (real model, CPU-runnable smoke config): two
-   ``ServeLoop.run()`` invocations under AWF admission.  The first run's
-   per-chunk wall times (prefill + decode, the fixed feedback bug) flush
-   at stream close; the second run plans admission from the learned slot
-   rates.  Reported: tok/s, measured epoch, per-slot telemetry.
+   ``ServeLoop.run()`` invocations of the per-slot engine under AWF
+   admission.  The first run's per-chunk wall times (prefill + decode)
+   flush at stream close; the second run plans admission from the
+   learned slot rates.  Reported: tok/s, measured epoch, per-slot
+   telemetry.
 
-3. **Batched-decode throughput** (real model): warm ``ServeLoop.run()``
-   timings of the batched engine (one jitted decode call per token across
-   all slots, stacked KV cache) against the per-slot escape hatch (one
-   call per active slot per token).  The gate enforces
-   ``batched_vs_per_slot_speedup >= 3`` — the serve-throughput acceptance
-   criterion for the batched rebuild.
-
-4. **Fused-decode throughput** (real model): warm tok/s of the fused
-   engine at ``decode_steps=8`` (one jitted dispatch per 8 tokens, the
-   on-device ``lax.scan`` loop) against the stepwise ``decode_steps=1``
-   engine, plus the measured ``dispatches_per_token`` of each.  The gate
-   enforces ``fused_speedup >= 1.5`` — the dispatch-amortization
-   acceptance criterion for the fused rebuild.
-
-5. **Auto selection** (pure host, no JAX): the same skewed-worker loop
+3. **Auto selection** (pure host, no JAX): the same skewed-worker loop
    driven by ``schedule(auto)`` and by every fixed candidate clause.
    Reported: each clause's steady-state makespan, auto's selection
    trajectory, and ``auto_vs_best_fixed_ratio`` (best fixed steady
@@ -41,7 +28,7 @@ BENCH_serve.json`` uploaded as an artifact, ``--gate`` as the exit code):
    criterion that auto converges within 10% of the best hand-picked
    clause without being told which.
 
-6. **Paged concurrency** (real model): the paged-KV continuous-batching
+4. **Paged concurrency** (real model): the paged-KV continuous-batching
    engine over the deterministic long/short mixed trace that
    ``tests/test_paged.py`` also exercises (``serve_mem.make_mixed_trace``
    — tests and bench gate the same workload).  Two sub-runs: an *open*
@@ -67,9 +54,6 @@ WORKERS = 8
 STEPS = 10
 SLOW_WORKER = WORKERS - 1
 SLOW_SPEED = 0.25
-SPEEDUP_GATE = 3.0     # batched decode must be >= 3x per-slot tok/s
-FUSED_GATE = 1.5       # fused decode_steps=8 must be >= 1.5x stepwise tok/s
-FUSED_STEPS = 8
 AUTO_RATIO_GATE = 0.9  # auto must reach >= 90% of the best fixed clause
 PAGED_REQUESTS = 120
 PAGED_CONCURRENCY_GATE = 100   # paged engine must hold O(100) in flight
@@ -219,131 +203,6 @@ def serve_smoke(arch: str = "qwen2.5-3b", requests: int = 8,
     }
 
 
-def batched_speedup(arch: str = "qwen2.5-3b", requests: int = 16,
-                    slots: int = 8, max_new: int = 32,
-                    prompt_len: int = 8, max_len: int = 64) -> dict:
-    """Warm tok/s of the batched decode engine vs the per-slot escape hatch.
-
-    Both loops serve the same request set under the same ``dynamic``
-    admission clause; the first run of each pays compilation and warms the
-    caches, the second is timed.  Prompts share one FIXED length so prefill
-    compiles once in the warm run — variable lengths would recompile
-    prefill inside the timed run and drown the decode substrate under
-    identical compile noise on both sides.  The decode-step count is
-    identical (the engines are token-for-token equivalent —
-    ``tests/test_serve.py``), so the ratio isolates the substrate: one
-    jitted call per token for the whole team vs one call per active slot
-    per token.
-    """
-    import numpy as np
-    from repro.configs import get_smoke_config
-    from repro.launch.serve import Request, ServeLoop
-
-    cfg = get_smoke_config(arch)
-    rng = np.random.default_rng(0)
-
-    def make_requests():
-        return [Request(rid=i,
-                        prompt=rng.integers(0, cfg.vocab_size,
-                                            size=prompt_len
-                                            ).astype(np.int32),
-                        max_new=max_new)
-                for i in range(requests)]
-
-    def timed(batched: bool, repeats: int = 3) -> dict:
-        loop = ServeLoop(cfg, slots=slots, max_len=max_len,
-                         scheduler="dynamic", batched=batched)
-        loop.run(make_requests())              # compile + warm
-        best = None
-        for _ in range(repeats):               # best-of-N: shed host noise
-            t0 = time.perf_counter()
-            out = loop.run(make_requests())
-            wall = time.perf_counter() - t0
-            if best is None or wall < best[1]:
-                best = (out, wall)
-        out, wall = best
-        toks = sum(len(v) for v in out.values())
-        return {"mode": loop.mode, "completed": len(out), "tokens": toks,
-                "wall_s": round(wall, 3), "tok_s": round(toks / wall, 2)}
-
-    per_slot = timed(batched=False)
-    batched = timed(batched=True)
-    speedup = round(batched["tok_s"] / per_slot["tok_s"], 3)
-    return {
-        "arch": arch,
-        "slots": slots,
-        "requests": requests,
-        "max_new": max_new,
-        "per_slot": per_slot,
-        "batched": batched,
-        "batched_vs_per_slot_speedup": speedup,
-        "speedup_gate": SPEEDUP_GATE,
-    }
-
-
-def fused_speedup(arch: str = "qwen2.5-3b", requests: int = 16,
-                  slots: int = 8, max_new: int = 32,
-                  prompt_len: int = 8, max_len: int = 64,
-                  decode_steps: int = FUSED_STEPS) -> dict:
-    """Warm tok/s of the fused multi-token engine vs the stepwise one.
-
-    Both loops are batched over the same stacked cache and serve the same
-    request set; the only variable is the dispatch quantum — one jitted
-    call per ``decode_steps`` tokens (an on-device ``lax.scan``) vs one
-    per token.  Token outputs are identical (greedy decode is
-    deterministic — ``tests/test_serve.py`` locks it), so the ratio
-    isolates the Python->XLA round-trip amortization.  Fixed prompt
-    length keeps prefill out of the timing (one bucket, one compile).
-    """
-    import numpy as np
-    from repro.configs import get_smoke_config
-    from repro.launch.serve import Request, ServeLoop
-
-    cfg = get_smoke_config(arch)
-    rng = np.random.default_rng(0)
-
-    def make_requests():
-        return [Request(rid=i,
-                        prompt=rng.integers(0, cfg.vocab_size,
-                                            size=prompt_len
-                                            ).astype(np.int32),
-                        max_new=max_new)
-                for i in range(requests)]
-
-    def timed(steps: int, repeats: int = 3) -> dict:
-        loop = ServeLoop(cfg, slots=slots, max_len=max_len,
-                         scheduler="dynamic", decode_steps=steps)
-        loop.run(make_requests())              # compile + warm
-        best = None
-        for _ in range(repeats):               # best-of-N: shed host noise
-            t0 = time.perf_counter()
-            out = loop.run(make_requests())
-            wall = time.perf_counter() - t0
-            if best is None or wall < best[1]:
-                best = (out, wall, dict(loop.last_stats))
-        out, wall, stats = best
-        toks = sum(len(v) for v in out.values())
-        return {"decode_steps": steps, "completed": len(out),
-                "tokens": toks, "wall_s": round(wall, 3),
-                "tok_s": round(toks / wall, 2),
-                "decode_dispatches": stats["decode_dispatches"],
-                "dispatches_per_token": stats["dispatches_per_token"]}
-
-    stepwise = timed(1)
-    fused = timed(decode_steps)
-    speedup = round(fused["tok_s"] / stepwise["tok_s"], 3)
-    return {
-        "arch": arch,
-        "slots": slots,
-        "requests": requests,
-        "max_new": max_new,
-        "stepwise": stepwise,
-        "fused": fused,
-        "fused_speedup": speedup,
-        "fused_gate": FUSED_GATE,
-    }
-
-
 def paged_concurrency(arch: str = "qwen2.5-3b",
                       requests: int = PAGED_REQUESTS) -> dict:
     """O(100)-way continuous batching through the paged-KV block pool.
@@ -429,8 +288,6 @@ def collect(skip_serve: bool = False) -> dict:
                     "auto": auto_selection()}
     if not skip_serve:
         record["serve"] = serve_smoke()
-        record["batched"] = batched_speedup()
-        record["fused"] = fused_speedup()
         record["paged"] = paged_concurrency()
     ex = record["executor"]
     au = record["auto"]
@@ -446,17 +303,6 @@ def collect(skip_serve: bool = False) -> dict:
         checks["serve_measured_epochs"] = sv["epochs"][-1] >= 2
         checks["serve_completed_all"] = (sv["completed"]
                                          == [sv["requests"]] * 2)
-        bt = record["batched"]
-        checks["batched_speedup_gate"] = (
-            bt["batched_vs_per_slot_speedup"] >= SPEEDUP_GATE)
-        checks["batched_completed_all"] = (
-            bt["batched"]["completed"] == bt["requests"]
-            and bt["per_slot"]["completed"] == bt["requests"])
-        fu = record["fused"]
-        checks["fused_speedup_gate"] = fu["fused_speedup"] >= FUSED_GATE
-        checks["fused_completed_all"] = (
-            fu["fused"]["completed"] == fu["requests"]
-            and fu["stepwise"]["completed"] == fu["requests"])
         pg = record["paged"]
         checks["paged_completed_all"] = (
             pg["open"]["completed"] == pg["requests"]
@@ -488,19 +334,6 @@ def rows(skip_serve: bool = True) -> list:
         sv = rec["serve"]
         out.append(("serve_adapt/serve", 0.0,
                     f"tok_s={sv['tok_s']};epochs={sv['epochs'][-1]}"))
-    if "batched" in rec:
-        bt = rec["batched"]
-        out.append(("serve_adapt/batched", 0.0,
-                    f"speedup={bt['batched_vs_per_slot_speedup']};"
-                    f"batched_tok_s={bt['batched']['tok_s']};"
-                    f"per_slot_tok_s={bt['per_slot']['tok_s']}"))
-    if "fused" in rec:
-        fu = rec["fused"]
-        out.append(("serve_adapt/fused", 0.0,
-                    f"speedup={fu['fused_speedup']};"
-                    f"fused_tok_s={fu['fused']['tok_s']};"
-                    f"stepwise_tok_s={fu['stepwise']['tok_s']};"
-                    f"dispatches_per_token={fu['fused']['dispatches_per_token']}"))
     if "paged" in rec:
         pg = rec["paged"]
         out.append(("serve_adapt/paged", 0.0,
@@ -541,18 +374,6 @@ def main(argv=None) -> int:
         sv = record["serve"]
         print(f"serve: {sv['tok_s']} tok/s warm, epochs {sv['epochs']}, "
               f"imbalance {sv['telemetry'].get('imbalance')}")
-    if "batched" in record:
-        bt = record["batched"]
-        print(f"batched decode: {bt['batched']['tok_s']} tok/s vs "
-              f"per-slot {bt['per_slot']['tok_s']} tok/s -> "
-              f"{bt['batched_vs_per_slot_speedup']}x "
-              f"(gate >= {SPEEDUP_GATE}x)")
-    if "fused" in record:
-        fu = record["fused"]
-        print(f"fused decode x{FUSED_STEPS}: {fu['fused']['tok_s']} tok/s "
-              f"({fu['fused']['dispatches_per_token']} dispatches/token) vs "
-              f"stepwise {fu['stepwise']['tok_s']} tok/s -> "
-              f"{fu['fused_speedup']}x (gate >= {FUSED_GATE}x)")
     if "paged" in record:
         pg = record["paged"]
         op, pr = pg["open"], pg["pressured"]

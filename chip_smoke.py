@@ -8,7 +8,7 @@ runs, printed for orientation only.
     python chip_smoke.py                # one chip: serve qwen2.5-3b
     python chip_smoke.py --four-chips   # four chips: train qwen2.5-3b
 
-One chip: ``PagedServeLoop`` and then the dense batched ``ServeLoop`` each
+One chip: ``PagedServeLoop`` and then the per-slot ``ServeLoop`` each
 serve 8 requests (prompts of 128-1024 tokens drawn from ``--seed``, 32 new
 tokens each) through full-width, full-depth qwen2.5-3b in bfloat16, with
 random weights.  Every request must get exactly its token budget, every
@@ -51,7 +51,7 @@ ARCH = "qwen2.5-3b"
 # softmax over the pool's keys and rounds its probabilities to bfloat16,
 # where forward runs blockwise (online-softmax) attention on long prompts.
 # Each bfloat16 rounding is a relative step of 2**-8; across 36 layers of
-# random weights the paged engine lands near 2e-2, the dense one (same
+# random weights the paged engine lands near 2e-2, the per-slot one (same
 # attention as forward) near 2e-3.  A wrong mask, position or cache row
 # gives errors of order one.
 LOGITS_RTOL = 3e-2
@@ -186,12 +186,12 @@ def serve_paged(cfg, seed: int, **shape) -> Dict[str, float]:
     return serve_phase(loop, cfg, seed)
 
 
-def serve_dense(cfg, seed: int, **shape) -> Dict[str, float]:
+def serve_per_slot(cfg, seed: int, **shape) -> Dict[str, float]:
     from repro.launch.serve import ServeLoop
     shape = {**SERVE, **shape}
     loop = ServeLoop(cfg, slots=shape["concurrency"],
                      max_len=shape["max_context"], scheduler="static",
-                     seed=seed, decode_steps=shape["decode_steps"])
+                     seed=seed)
     return serve_phase(loop, cfg, seed)
 
 
@@ -274,7 +274,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     else:
         _report(f"serve {ARCH} PagedServeLoop", serve_paged(cfg, args.seed))
         gc.collect()
-        _report(f"serve {ARCH} ServeLoop", serve_dense(cfg, args.seed))
+        _report(f"serve {ARCH} ServeLoop", serve_per_slot(cfg, args.seed))
         gc.collect()
         _report("peak_bytes_in_use", {"0": peak_bytes(devices[:1])[0]})
     print(json.dumps({"ok": True,

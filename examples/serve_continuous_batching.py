@@ -1,9 +1,12 @@
-"""Serving example: continuous batching where the admission policy is a UDS.
+"""Serving example: continuous batching where the prefill plan is a UDS.
 
-Requests are loop iterations; decode slots are workers; ``schedule(dynamic,1)``
-is classic continuous batching (an idle slot admits the next request), and
-guided/factoring policies admit request *chunks* when the queue is deep —
-fewer admission decisions at the same utilization.
+``PagedServeLoop`` admits a request as soon as the KV block pool holds its
+prompt, and the schedule clause splits each prompt's prefill into chunks
+that interleave with decode dispatches: ``schedule(static)`` prefills in
+bursts of ``prefill_chunk`` tokens, ``schedule(dynamic,1)`` in the smallest
+chunks (least head-of-line blocking for in-flight decodes, most
+dispatches), and guided/factoring policies start coarse and refine toward
+the prompt's tail.
 
     PYTHONPATH=src python examples/serve_continuous_batching.py
 """
@@ -13,7 +16,7 @@ import time
 import numpy as np
 
 from repro.configs import get_smoke_config
-from repro.launch.serve import Request, ServeLoop
+from repro.launch.serve import PagedServeLoop, Request
 
 
 def main() -> None:
@@ -26,14 +29,18 @@ def main() -> None:
                     max_new=6)
             for i in range(12)]
 
-    for sched in ("dynamic", "guided", "fac2"):
-        loop = ServeLoop(cfg, slots=3, scheduler=sched)
+    for sched in ("static", "guided", "fac2"):
+        loop = PagedServeLoop(cfg, num_blocks=32, block_size=8,
+                              max_context=64, concurrency=4,
+                              scheduler=sched, decode_steps=2,
+                              prefill_chunk=16)
         t0 = time.perf_counter()
         out = loop.run([Request(r.rid, r.prompt, r.max_new) for r in reqs])
         dt = time.perf_counter() - t0
         toks = sum(len(v) for v in out.values())
         print(f"schedule({sched:8s}): {len(out)} requests, {toks} tokens, "
-              f"{dt:.2f}s ({toks/dt:.1f} tok/s)")
+              f"{dt:.2f}s ({toks/dt:.1f} tok/s), "
+              f"{loop.last_stats['prefill_dispatches']} prefill chunks")
 
 
 if __name__ == "__main__":

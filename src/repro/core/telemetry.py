@@ -287,36 +287,13 @@ class LoopTelemetry:
             self._t_first = now - dt
         self._t_last = now
 
-    def add_time_split(self, workers, dt: float, tokens=0) -> None:
-        """Split one measured wall time equally across the open ledgers of
-        ``workers`` — the batched serve step issues ONE jitted call that
-        advances every active slot in lockstep, so each slot is charged
-        ``dt / len(workers)``.  Per-slot attribution stays intact:
-        AWF-family admission still replans from per-slot busy times.
-
-        ``tokens`` may be an int (every worker credited the same count —
-        the one-token-per-dispatch stepwise engine) or a mapping
-        ``{worker: count}`` — the fused multi-token dispatch, where one
-        call advances each slot by its OWN number of tokens (a slot that
-        froze mid-dispatch produced fewer than the dispatch quantum), so
-        the amortized wall-time share and the per-slot token credit stay
-        consistent at any dispatch granularity."""
-        ws = [w for w in workers if w in self._open]
-        if not ws:
-            return
-        share = float(dt) / len(ws)
-        for w in ws:
-            tk = tokens.get(w, 0) if isinstance(tokens, dict) else tokens
-            self.add_time(w, share, tokens=tk)
-
     def add_time_weighted(self, dt: float, weights: Dict[int, float],
                           tokens: Optional[Dict[int, int]] = None) -> None:
         """Split one measured wall time across the open ledgers
         proportionally to ``weights`` — per-host attribution for a
-        lockstep data-parallel train step (the multi-host mirror of
-        :meth:`add_time_split`): ONE jitted step advances every host, so
-        host ``h`` is charged ``dt * w_h / sum(w)``, its modelled share
-        of the step's compute, and credited its own token count.  In an
+        lockstep data-parallel train step: ONE jitted step advances every
+        host, so host ``h`` is charged ``dt * w_h / sum(w)``, its modelled
+        share of the step's compute, and credited its own token count.  In an
         emulated-host run the weights ARE the measurement model (token
         count x injected skew); a real multi-host deployment feeds
         genuine per-host clocks instead.  Hosts without an open ledger

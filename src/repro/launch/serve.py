@@ -1,77 +1,69 @@
 """Serving driver: continuous batching with a UDS request scheduler.
 
-Requests (variable prompt lengths) arrive in a queue; the UDS decides which
-requests form the next decode batch — receiver-initiated self-scheduling
-where decode slots are workers and requests are iterations.  Slots that
-finish (EOS / max tokens) immediately dequeue the next request chunk, i.e.
-``schedule(dynamic, 1)``; guided/factoring variants admit several requests
-per dequeue when the queue is deep.
+Every model family serves through one engine, chosen by what the family
+implements (:func:`engine_for`; the CLI needs no flag for it):
 
-Decode runs **batched and fused** by default: all slots share one stacked
-``[slots, max_len]`` KV cache with per-slot lengths, and each dispatch is
-ONE jitted call that runs ``decode_steps`` tokens for the whole team via an
-on-device ``lax.scan`` (``make_fused_serve_step``) with per-slot stop/EOS/
-length handling carried in the loop state — a slot that finishes its
-request mid-dispatch freezes in place while the others keep decoding.  The
-dispatch quantum ``decode_steps`` is a schedule parameter: T=1 reproduces
-the stepwise engine token for token (greedy decode is deterministic, so
-any T does — locked down in ``tests/test_serve.py``); larger T amortizes
-the Python→XLA round-trip over T tokens at the cost of admission latency
-(idle slots re-enter the team only at dispatch boundaries).
+* :class:`PagedServeLoop` serves every attention family (dense, MoE,
+  audio, vision) over a shared KV block pool (``repro.serve_mem``): cache
+  MEMORY is the scheduled resource.  A request is admitted when blocks
+  for its prompt are free; its prompt prefills in UDS-planned chunks
+  (:func:`plan_prefill_chunks`) that interleave with decode dispatches;
+  its sequence grows block by block as it generates; and under memory
+  pressure the most recently admitted request is preempted — blocks
+  freed, requeued at the front, later re-prefilled with its generated
+  prefix (greedy decode makes the resumed request token-for-token
+  identical to an uninterrupted run).  Each decode dispatch is ONE jitted
+  call that runs ``decode_steps`` tokens for every active row through an
+  on-device ``lax.scan`` (``make_paged_serve_step``); a row freezes in
+  place on device when its budget is spent, when it emits EOS, or when
+  its fill reaches the blocks it holds (the host then grows its table,
+  or preempts).  The dispatch quantum T = ``decode_steps`` trades
+  admission latency (new requests join only at dispatch boundaries) for
+  fewer Python→XLA round-trips; every T gives the same tokens.  See
+  docs/SCHEDULING.md, "Paged KV and continuous batching".
+* :class:`ServeLoop` is the per-slot engine: a fixed set of decode slots,
+  each with its own batch-1 cache or recurrent state, one jitted call per
+  active slot per token.  It serves the ``ssm``/``hybrid`` families,
+  whose state has no place in the block pool yet, and is the token
+  reference the paged engine is tested against.  Slots are workers and
+  requests iterations: a slot that finishes (EOS / budget) dequeues the
+  next request chunk through the UDS, i.e. ``schedule(dynamic, 1)``;
+  guided/factoring variants admit several requests per dequeue when the
+  queue is deep.  Attention families' prompts are right-padded to
+  power-of-two length *buckets* before the jitted prefill — causal masking
+  makes the padded prefix math identical, so a long tail of distinct
+  prompt lengths compiles one program per bucket instead of one per
+  length.
 
-Admission prefills a request at batch=1 and scatters its cache into the
-slot's row (``model.insert_prefill``), so in-flight slots are untouched.
-Prompts are right-padded to power-of-two length *buckets* before the
-jitted prefill — causal masking makes the padded prefix math identical, so
-a long tail of distinct prompt lengths compiles one program per bucket
-instead of one per length (~0.8s per avoided recompile on the smoke
-config).  The per-slot escape hatch (``batched=False`` / ``--per-slot``:
-one jit call per active slot per token over per-slot batch-1 caches)
-remains token-for-token identical, and is the automatic fallback for
-SSM/hybrid families.  UDS admission semantics are IDENTICAL in both modes:
-the scheduler sees the same slots, the same dequeue order, and the same
-chunk feedback protocol.
+In both engines a request whose ``prompt + max_new`` exceeds the context
+ceiling is admitted but **truncated**: its generation budget is clamped to
+the capacity and the truncation is reported per request
+(``Request.truncated``, ``last_stats["truncated"]``) — never silently
+padded or dropped.  A prompt that alone exceeds the ceiling is refused
+loudly.
 
-A request whose ``prompt + max_new`` exceeds the cache is admitted but
-**truncated**: its generation budget is clamped to cache capacity and the
-truncation is reported per request (``Request.truncated``,
-``last_stats["truncated"]``) — never silently padded or dropped.  A prompt
-that alone exceeds ``max_len`` is still refused loudly.
+Both engines are instrumented with
+:class:`~repro.core.telemetry.LoopTelemetry`.  In :class:`ServeLoop` every
+chunk's **full wall time** — the prefill of each of its requests plus
+every decode call of their generations — is attributed to the slot that
+served it, fed back through ``stream.next`` (so within-invocation
+adaptive strategies like AWF-B rebalance admission mid-run), and flushed
+into the loop's ``LoopHistory`` when the stream closes.  The flush bumps
+the history's measured epoch, so a cached adaptive plan for this loop is
+invalidated and the *next* ``run()`` replans admission from the measured
+slot speeds (AWF timestep).  ``history`` persists across calls — pass one
+in to persist across processes (it serializes with checkpoints).
 
-The loop is instrumented with :class:`~repro.core.telemetry.LoopTelemetry`:
-every chunk's **full wall time** — the prefill of each of its requests plus
-every decode dispatch of their generations — is attributed to the slot that
-served it (one fused dispatch's wall time splits equally across the slots
-it advanced, each credited its OWN produced-token count), fed back through
-``stream.next`` (so within-invocation adaptive strategies like AWF-B
-rebalance admission mid-run), and flushed into the loop's ``LoopHistory``
-when the stream closes.  The flush bumps the history's measured epoch, so
-a cached adaptive plan for this loop is invalidated and the *next*
-``run()`` replans admission from the measured slot speeds (AWF timestep).
-``ServeLoop.history`` persists across calls — pass one in to persist
-across processes (it serializes with checkpoints).
-
-**Paged mode** (``--paged-kv`` / :class:`PagedServeLoop`) replaces the
-stacked per-slot cache with a shared block pool (``repro.serve_mem``):
-cache MEMORY becomes the scheduled resource.  Requests are admitted when
-blocks for their prompt are free (not when a slot opens), prompts prefill
-in UDS-planned chunks that interleave with decode dispatches, sequences
-grow block-by-block as they generate, and under memory pressure the most
-recently admitted request is preempted — blocks freed, requeued at the
-front, later re-prefilled with its generated prefix (greedy decode makes
-the resumed request token-for-token identical to an uninterrupted run).
-See docs/SCHEDULING.md, "Paged KV and continuous batching".
-
-    python -m repro.launch.serve --arch qwen2.5-3b --smoke --requests 16 \
-        --decode-steps 8
     python -m repro.launch.serve --arch qwen2.5-3b --smoke --requests 32 \
-        --paged-kv --num-blocks 48 --block-size 8 --max-concurrency 16
+        --num-blocks 48 --block-size 8 --max-concurrency 16 --decode-steps 4
+    python -m repro.launch.serve --arch rwkv6-3b --smoke --requests 8
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import inspect
 import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional
@@ -87,9 +79,8 @@ from repro.core import (LoopHistory, LoopSpec, LoopTelemetry,
                         get_engine)
 from repro.core.spec import SpecLike, describe, resolve
 from repro.launch.compile_cache import enable_compile_cache
-from repro.launch.steps import (make_fused_serve_step, make_paged_prefill_step,
-                                make_paged_serve_step, make_prefill_step,
-                                make_serve_step)
+from repro.launch.steps import (make_paged_prefill_step, make_paged_serve_step,
+                                make_prefill_step, make_serve_step)
 from repro.kernels.paged_attention.ops import copied_positions
 from repro.models import get_model
 from repro.models.transformer import paged_kernel_engages
@@ -97,7 +88,7 @@ from repro.serve_mem import BlockPool, BlockTables
 from repro.serve_mem.blocks import blocks_for_tokens
 
 __all__ = ["ServeLoop", "PagedServeLoop", "Request", "bucket_length",
-           "plan_prefill_chunks", "main"]
+           "plan_prefill_chunks", "engine_for", "main"]
 
 # smallest prefill bucket: tiny prompts share one program instead of
 # compiling at 1, 2, 3, ... tokens
@@ -144,29 +135,25 @@ class Request:
 
 
 class ServeLoop:
-    """Continuous batching over a fixed decode-slot count.
+    """Continuous batching over a fixed decode-slot count, per slot.
 
-    ``decode_steps`` is the dispatch quantum: tokens generated per jitted
-    call in batched mode (1 = the stepwise engine).  ``history`` carries
+    Each slot owns a batch-1 cache (or recurrent state) of ``max_len``;
+    every decode call advances one slot by one token.  ``history`` carries
     measured per-slot chunk times across ``run()`` invocations — the
     serving steady state's feedback channel.  After each run,
     ``last_stats`` holds the telemetry summary (per-slot busy time,
-    tokens, tok/s, decode dispatch counts, truncations, measured epoch).
-    Parameters and KV cache are bfloat16, as in training, so a published
-    config fits one accelerator.
+    tokens, tok/s, decode call counts, truncations, measured epoch).
+    Parameters and caches are bfloat16, as in training.
     """
 
     def __init__(self, cfg, *, slots: int = 4, max_len: int = 256,
                  scheduler: SpecLike = "dynamic", seed: int = 0,
                  history: Optional[LoopHistory] = None,
-                 batched: bool = True, decode_steps: int = 1,
                  eos_id: Optional[int] = None):
         self.cfg = cfg
         self.model = get_model(cfg)
         self.slots = slots
         self.max_len = max_len
-        if decode_steps < 1:
-            raise ValueError(f"decode_steps must be >= 1, got {decode_steps}")
         key = jax.random.PRNGKey(seed)
         self.params, _ = self.model.init(key, jnp.bfloat16)
         # any schedule-clause form: spec, "guided,4", "uds:name", "runtime",
@@ -182,45 +169,23 @@ class ServeLoop:
         # passed as a traced scalar (causal masking makes the padded math
         # identical), so a long tail of distinct lengths stops triggering
         # ~0.8s recompiles mid-serve.  SSM/hybrid prefills absorb pad
-        # tokens into their recurrent state, so only attention families
-        # (those with a batched decode path) bucket.
+        # tokens into their recurrent state and take no length, so only
+        # attention families bucket.
         self._prefill = jax.jit(make_prefill_step(self.model,
                                                   max_len=max_len))
-        self._bucketed = self.model.batched_decode is not None
-        # SSM/hybrid families have no stacked-cache decode yet: fall back
-        # to the per-slot path rather than refuse to serve
-        self.batched = bool(batched and self.model.batched_decode is not None)
-        self.decode_steps = decode_steps if self.batched else 1
-        if self.batched:
-            # one stacked [slots, max_len] cache, per-slot lengths; ONE
-            # jitted dispatch per decode_steps tokens across all active
-            # slots (an on-device scan with per-slot stop handling)
-            # the cache argument is donated: each dispatch updates it in
-            # place instead of holding an input and an output copy
-            self._decode_fused = jax.jit(
-                make_fused_serve_step(self.model, self.decode_steps),
-                donate_argnums=(2,))
-            self._insert = jax.jit(self.model.insert_prefill,
-                                   donate_argnums=(0,))
-            self.cache = self.model.init_batched_decode(slots, max_len)[0]
-            self.caches = None
-        else:
-            # per-slot state: one cache per slot (batch=1), one jit call
-            # per active slot per token — the escape hatch / SSM path
-            self._decode = jax.jit(make_serve_step(self.model),
-                                   donate_argnums=(2,))
-            self.caches = [self.model.init_decode(1, max_len)[0]
-                           for _ in range(slots)]
+        self._bucketed = ("length"
+                          in inspect.signature(self.model.prefill).parameters)
+        # one cache per slot (batch=1); the cache argument is donated, so
+        # each call updates it in place
+        self._decode = jax.jit(make_serve_step(self.model),
+                               donate_argnums=(2,))
+        self.caches = [self.model.init_decode(1, max_len)[0]
+                       for _ in range(slots)]
         self.active: Dict[int, Request] = {}
-        self._dispatches = 0
         self._decoded = 0
         # last-position logits (V,) of the most recent prefill, kept for
         # comparison against a reference forward pass
         self.last_prefill_logits: Optional[jax.Array] = None
-
-    @property
-    def mode(self) -> str:
-        return "batched" if self.batched else "per_slot"
 
     @property
     def prefill_compiles(self) -> int:
@@ -228,7 +193,7 @@ class ServeLoop:
         metric: mixed prompt lengths must not grow this per-length)."""
         return self._prefill._cache_size()
 
-    def _prefill_into(self, slot: int, req: Request) -> int:
+    def _prefill_into(self, slot: int, req: Request) -> None:
         P = int(req.prompt.size)
         # the cache holds the prompt plus one KV per decode step; capacity
         # is how many tokens can be generated before the fill hits max_len
@@ -256,22 +221,15 @@ class ServeLoop:
         else:
             inputs = {"tokens": jnp.asarray(tokens[None, :])}
             logits, cache = self._prefill(self.params, inputs)
-        if self.batched:
-            # masked scatter into the slot's row of the stacked cache;
-            # every other (possibly in-flight) slot is untouched
-            self.cache = self._insert(self.cache, cache, slot)
-        else:
-            self.caches[slot] = cache
+        self.caches[slot] = cache
         self.last_prefill_logits = logits[0]
-        tok = int(jnp.argmax(logits, -1)[0])
-        req.generated = [tok]
-        return tok
+        req.generated = [int(jnp.argmax(logits, -1)[0])]
 
-    def _finished_at_admission(self, req: Request, tok: int) -> bool:
-        """Budget of 1 (or an immediate EOS) completes at prefill."""
+    def _finished(self, req: Request) -> bool:
+        """Budget spent, or the newest token is EOS."""
         if len(req.generated) >= req.budget:
             return True
-        return self.eos_id is not None and tok == self.eos_id
+        return self.eos_id is not None and req.generated[-1] == self.eos_id
 
     def run(self, requests: List[Request]) -> Dict[int, List[int]]:
         """Schedule + serve all requests to completion."""
@@ -288,24 +246,19 @@ class ServeLoop:
         for req in requests:
             if req.t_arrive is None:
                 req.t_arrive = now
-        queue: Deque[Request] = deque(requests)
         pending: Dict[int, Deque[Request]] = {s: deque()
                                               for s in range(self.slots)}
         # per-chunk wall time of the slot's *previous* chunk (prefill +
-        # all decode dispatches), consumed by the next dequeue and then
+        # all decode calls), consumed by the next dequeue and then
         # cleared — never a stale prefill-only value
         elapsed: Dict[int, Optional[float]] = {s: None
                                                for s in range(self.slots)}
         results: Dict[int, List[int]] = {}
         truncated: List[int] = []
-        slots_open = set(range(self.slots))
         exhausted = set()
-        self._dispatches = 0
         self._decoded = 0
-        eos_arr = jnp.asarray(-1 if self.eos_id is None else self.eos_id,
-                              jnp.int32)
 
-        def finish(s: int, req: Request) -> None:
+        def finish(req: Request) -> None:
             results[req.rid] = req.generated
             req.t_finish = time.perf_counter()
             if req.truncated:
@@ -314,10 +267,8 @@ class ServeLoop:
         while len(results) < len(requests):
             # admission: idle slots dequeue request chunks via the UDS,
             # reporting the measured wall time of their previous chunk
-            for s in list(slots_open):
-                if s in self.active or pending[s]:
-                    continue
-                if s in exhausted:
+            for s in range(self.slots):
+                if s in self.active or pending[s] or s in exhausted:
                     continue
                 chunk = stream.next(s, elapsed[s])
                 elapsed[s] = None              # consumed by this dequeue
@@ -334,75 +285,32 @@ class ServeLoop:
                     t0 = time.perf_counter()
                     if req.t_admit is None:
                         req.t_admit = t0
-                    tok = self._prefill_into(s, req)
+                    self._prefill_into(s, req)
                     t1 = time.perf_counter()
                     if req.t_first is None:
                         req.t_first = t1
                     telemetry.add_time(s, t1 - t0, tokens=1)
                     progressed = True
-                    if self._finished_at_admission(req, tok):
-                        finish(s, req)
+                    if self._finished(req):    # budget of 1, or EOS first
+                        finish(req)
                         if not pending[s]:
                             elapsed[s] = telemetry.end(s)
                     else:
                         self.active[s] = req
-            # one decode dispatch across active slots
+            # one decode call per active slot
             done_slots = []
-            if self.batched and self.active:
-                act = sorted(self.active)
-                last = np.zeros((self.slots, 1), np.int32)
-                mask = np.zeros((self.slots,), bool)
-                rem = np.zeros((self.slots,), np.int32)
-                for s in act:
-                    req = self.active[s]
-                    last[s, 0] = req.generated[-1]
-                    mask[s] = True
-                    rem[s] = req.budget - len(req.generated)
+            for s, req in list(self.active.items()):
                 t0 = time.perf_counter()
-                toks, self.cache, act_out, rem_out = self._decode_fused(
-                    self.params, {"tokens": jnp.asarray(last)},
-                    self.cache, jnp.asarray(mask), jnp.asarray(rem),
-                    eos_arr)
-                toks = np.asarray(toks)     # device sync: true wall time
-                act_out = np.asarray(act_out)
-                rem_out = np.asarray(rem_out)
-                dt = time.perf_counter() - t0
-                self._dispatches += 1
-                # one call served every active slot in lockstep: equal
-                # wall-time shares keep per-slot attribution (AWF still
-                # replans per slot), each slot credited the tokens IT
-                # produced before freezing
-                produced = {s: int(rem[s] - rem_out[s]) for s in act}
-                telemetry.add_time_split(act, dt, tokens=produced)
-                self._decoded += sum(produced.values())
+                tok, self.caches[s] = self._decode(
+                    self.params, {"tokens": jnp.asarray([[req.generated[-1]]])},
+                    self.caches[s])
+                req.generated.append(int(tok[0]))
+                telemetry.add_time(s, time.perf_counter() - t0, tokens=1)
+                self._decoded += 1
                 progressed = True
-                for s in act:
-                    req = self.active[s]
-                    req.generated.extend(
-                        int(t) for t in toks[s, :produced[s]])
-                    if not act_out[s]:      # quota / EOS / capacity freeze
-                        finish(s, req)
-                        done_slots.append(s)
-            else:
-                for s, req in list(self.active.items()):
-                    last = req.generated[-1]
-                    t0 = time.perf_counter()
-                    tok, cache = self._decode(
-                        self.params, {"tokens": jnp.asarray([[last]])},
-                        self.caches[s])
-                    self.caches[s] = cache
-                    req.generated.append(int(tok[0]))
-                    telemetry.add_time(s, time.perf_counter() - t0, tokens=1)
-                    self._dispatches += 1
-                    self._decoded += 1
-                    progressed = True
-                    done = len(req.generated) >= req.budget
-                    if (self.eos_id is not None
-                            and req.generated[-1] == self.eos_id):
-                        done = True
-                    if done:
-                        finish(s, req)
-                        done_slots.append(s)
+                if self._finished(req):
+                    finish(req)
+                    done_slots.append(s)
             for s in done_slots:
                 del self.active[s]
                 if not pending[s]:
@@ -413,13 +321,9 @@ class ServeLoop:
                 break
         stream.close()        # flushes telemetry -> history epoch bump
         self.last_stats = telemetry.summary()
-        self.last_stats["mode"] = self.mode
-        self.last_stats["decode_steps"] = self.decode_steps
-        self.last_stats["decode_dispatches"] = self._dispatches
+        # one decode call per token
+        self.last_stats["decode_dispatches"] = self._decoded
         self.last_stats["decoded_tokens"] = self._decoded
-        self.last_stats["dispatches_per_token"] = (
-            round(self._dispatches / self._decoded, 4) if self._decoded
-            else None)
         self.last_stats["truncated"] = sorted(truncated)
         self.last_stats["prefill_compiles"] = self.prefill_compiles
         self.last_stats["serve_meter"] = meter.summary(requests)
@@ -486,7 +390,7 @@ class PagedServeLoop:
     """Continuous batching over a paged KV block pool.
 
     Where :class:`ServeLoop` schedules a fixed set of ``slots`` (each
-    owning a dense ``max_len`` cache row), this engine schedules cache
+    owning a batch-1 ``max_len`` cache), this engine schedules cache
     MEMORY: every request draws fixed-size KV blocks from one shared
     :class:`~repro.serve_mem.BlockPool` as its sequence grows, so
     concurrency is bounded by total cache tokens, not by a slot count.
@@ -507,7 +411,7 @@ class PagedServeLoop:
       run (locked in ``tests/test_paged.py``).
     * **finish** — completed requests release every block immediately.
 
-    ``max_context`` is the per-request ceiling (the dense engine's
+    ``max_context`` is the per-request ceiling (:class:`ServeLoop`'s
     ``max_len``); budgets clamp/truncate against it exactly as in
     :class:`ServeLoop`.  ``concurrency`` is only the fused dispatch's
     batch width (compiled once) — memory admission happens first.
@@ -603,10 +507,6 @@ class PagedServeLoop:
         # its prefill twin: one {rid, start, length, bucket} per chunk
         # (and an MoE model's EXPERT_COUNTERS)
         self.prefill_log: List[Dict[str, int]] = []
-
-    @property
-    def mode(self) -> str:
-        return "paged"
 
     @property
     def prefill_compiles(self) -> int:
@@ -943,7 +843,6 @@ class PagedServeLoop:
         pf_tel.flush()
         self.last_stats = telemetry.summary()
         self.last_stats.update(meter.summary(requests))
-        self.last_stats["mode"] = self.mode
         self.last_stats["decode_steps"] = self.decode_steps
         self.last_stats["decode_dispatches"] = self._dispatches
         self.last_stats["decoded_tokens"] = self._decoded
@@ -964,59 +863,56 @@ class PagedServeLoop:
         return results
 
 
-def main() -> None:
+def engine_for(cfg) -> type:
+    """The serving engine for ``cfg``'s family: :class:`PagedServeLoop`
+    where the family has a paged-KV decode, the per-slot
+    :class:`ServeLoop` otherwise (the ``ssm``/``hybrid`` families)."""
+    return PagedServeLoop if get_model(cfg).paged_decode is not None \
+        else ServeLoop
+
+
+def main(argv: Optional[List[str]] = None):
+    """Serve a batch of random requests through ``engine_for(cfg)``;
+    returns the loop."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--requests", type=int, default=8)
-    ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--scheduler", default="dynamic",
                     help='schedule clause: "dynamic", "guided,4", '
                          '"uds:name(args)", "runtime" (late-bound from '
                          '$REPRO_SCHEDULE), or "auto" (selected online '
                          "from serve telemetry; see docs/SCHEDULING.md)")
     ap.add_argument("--max-new", type=int, default=8)
-    ap.add_argument("--decode-steps", type=int, default=1,
-                    help="tokens per fused decode dispatch (batched mode): "
-                         "1 = the stepwise engine; 8 amortizes the "
-                         "Python->XLA round-trip over 8 tokens")
-    ap.add_argument("--eos-id", type=int, default=None,
-                    help="stop token id (per-slot on-device stop in fused "
-                         "mode); default: generate to the token budget")
-    ap.add_argument("--batched", dest="batched", action="store_true",
-                    default=True,
-                    help="one jitted dispatch per decode-steps tokens "
-                         "across all active slots over a stacked KV cache "
-                         "(default)")
-    ap.add_argument("--per-slot", dest="batched", action="store_false",
-                    help="escape hatch: one decode call per active slot "
-                         "per token over per-slot batch-1 caches")
-    ap.add_argument("--paged-kv", action="store_true",
-                    help="serve through the paged-KV block pool "
-                         "(continuous batching: admission by free blocks, "
-                         "chunked prefill, preemption under pressure)")
-    ap.add_argument("--num-blocks", type=int, default=64,
-                    help="paged mode: KV blocks in the shared pool")
-    ap.add_argument("--block-size", type=int, default=8,
-                    help="paged mode: token positions per KV block")
-    ap.add_argument("--max-context", type=int, default=64,
-                    help="paged mode: per-request context ceiling "
-                         "(prompt + generated); must be a multiple of "
-                         "--block-size")
-    ap.add_argument("--prefill-chunk", type=int, default=16,
-                    help="paged mode: max tokens per prefill chunk (the "
-                         "UDS plans the chunking under --scheduler)")
     ap.add_argument("--max-concurrency", type=int, default=8,
-                    help="paged mode: fused dispatch batch width (compiled "
-                         "once); memory admission happens first")
+                    help="requests in flight: the paged engine's decode "
+                         "dispatch width (compiled once; memory admission "
+                         "happens first), the per-slot engine's slots")
+    ap.add_argument("--max-context", type=int, default=64,
+                    help="per-request context ceiling (prompt + "
+                         "generated); paged: a multiple of --block-size")
+    ap.add_argument("--eos-id", type=int, default=None,
+                    help="stop token id; default: generate to the token "
+                         "budget")
+    ap.add_argument("--decode-steps", type=int, default=1,
+                    help="paged engine: tokens per fused decode dispatch; "
+                         "1 = stepwise, 8 amortizes the Python->XLA "
+                         "round-trip over 8 tokens")
+    ap.add_argument("--num-blocks", type=int, default=64,
+                    help="paged engine: KV blocks in the shared pool")
+    ap.add_argument("--block-size", type=int, default=8,
+                    help="paged engine: token positions per KV block")
+    ap.add_argument("--prefill-chunk", type=int, default=16,
+                    help="paged engine: max tokens per prefill chunk (the "
+                         "UDS plans the chunking under --scheduler)")
     ap.add_argument("--kill-rows", type=int, default=0,
-                    help="paged mode: injected worker kill — mark this "
+                    help="paged engine: injected worker kill — mark this "
                          "many dispatch rows dead mid-run (drain-and-"
                          "readmit; requires --kill-at-dispatch)")
     ap.add_argument("--kill-at-dispatch", type=int, default=None,
-                    help="paged mode: decode dispatch count at which the "
+                    help="paged engine: decode dispatch count at which the "
                          "injected kill fires")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
@@ -1027,7 +923,8 @@ def main() -> None:
                                         ).astype(np.int32),
                     max_new=args.max_new)
             for i in range(args.requests)]
-    if args.paged_kv:
+    paged = engine_for(cfg) is PagedServeLoop
+    if paged:
         loop = PagedServeLoop(cfg, num_blocks=args.num_blocks,
                               block_size=args.block_size,
                               max_context=args.max_context,
@@ -1039,19 +936,22 @@ def main() -> None:
                               kill_rows=args.kill_rows,
                               kill_at_dispatch=args.kill_at_dispatch)
     else:
-        loop = ServeLoop(cfg, slots=args.slots, scheduler=args.scheduler,
-                         batched=args.batched,
-                         decode_steps=args.decode_steps,
+        if args.kill_rows:
+            ap.error(f"{cfg.name}: --kill-rows needs the paged engine, "
+                     f"which this model family does not have")
+        loop = ServeLoop(cfg, slots=args.max_concurrency,
+                         max_len=args.max_context, scheduler=args.scheduler,
                          eos_id=args.eos_id)
     t0 = time.perf_counter()
     out = loop.run(reqs)
     dt = time.perf_counter() - t0
     toks = sum(len(v) for v in out.values())
-    if args.paged_kv:
-        s = loop.last_stats
-        print(f"served {len(out)} requests, {toks} tokens in {dt:.2f}s "
-              f"({toks/dt:.1f} tok/s, paged decode x{loop.decode_steps}) "
-              f"under schedule({loop.sched_name}); "
+    s = loop.last_stats
+    head = (f"{type(loop).__name__}: served {len(out)} requests, {toks} "
+            f"tokens in {dt:.2f}s ({toks/dt:.1f} tok/s) under "
+            f"schedule({loop.sched_name}); ")
+    if paged:
+        print(head + f"decode x{loop.decode_steps}, "
               f"peak concurrency {s.get('peak_concurrency')}, "
               f"{s.get('peak_blocks_used')}/{loop.num_blocks} blocks peak "
               f"(mean util {s.get('kv_util_mean')}), "
@@ -1064,13 +964,10 @@ def main() -> None:
                   f"(lost {list(ev.lost)}); in-flight requests drained "
                   f"and readmitted on the survivors")
     else:
-        print(f"served {len(out)} requests, {toks} tokens in {dt:.2f}s "
-              f"({toks/dt:.1f} tok/s, {loop.mode} decode x{loop.decode_steps}) "
-              f"under schedule({loop.sched_name}); "
-              f"{loop.last_stats.get('decode_dispatches')} decode dispatches "
-              f"({loop.last_stats.get('dispatches_per_token')} per token), "
+        print(head + f"{s.get('decode_dispatches')} decode calls, "
               f"measured epoch {loop.measured_epoch()}, "
-              f"imbalance {loop.last_stats.get('imbalance')}")
+              f"imbalance {s.get('imbalance')}")
+    return loop
 
 
 if __name__ == "__main__":

@@ -27,8 +27,7 @@ from repro.core.spec import SpecLike
 from repro.sharding import constrain
 
 __all__ = ["chunked_softmax_ce", "make_train_step", "make_fused_train_step",
-           "make_prefill_step", "make_serve_step", "make_batched_serve_step",
-           "make_fused_serve_step", "apply_microbatch_plan",
+           "make_prefill_step", "make_serve_step", "apply_microbatch_plan",
            "plan_microbatches", "split_batch_by_shares", "input_specs",
            "head_weights"]
 
@@ -387,58 +386,12 @@ def make_serve_step(model: Model) -> Callable:
     return serve_step
 
 
-def make_batched_serve_step(model: Model) -> Callable:
-    """One decode step across ALL serving slots of a stacked cache: greedy
-    token per slot + updated cache.  ``active (slots,) bool`` masks the
-    cache/length update for idle slots; their token output is meaningless
-    and discarded by the caller.  One jitted call per generated token for
-    the whole team — the batched ``ServeLoop`` hot path."""
-    if model.batched_decode is None:
-        raise ValueError(
-            f"{model.name}: model family has no batched decode path "
-            f"(use the per-slot serve step)")
-
-    def serve_step(params, batch, cache, active):
-        logits, cache = model.batched_decode(params, batch, cache,
-                                             active=active,
-                                             cap_e=batch.get("cap_e"))
-        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return token, cache
-    return serve_step
-
-
-def make_fused_serve_step(model: Model, num_steps: int) -> Callable:
-    """``num_steps`` greedy decode tokens per dispatch across ALL slots of
-    a stacked cache — ONE jitted call runs a ``lax.scan`` of
-    ``num_steps`` batched decode steps with per-slot stop/EOS/length
-    handling on device (``transformer.fused_decode_steps``).  The batched
-    ``ServeLoop`` hot path: the Python→XLA round-trip is paid once per
-    ``num_steps`` tokens instead of once per token.  ``num_steps=1`` is
-    exactly the stepwise batched engine.
-
-    Returns serve_step(params, batch, cache, active, remaining, eos_id)
-    -> (tokens (slots, num_steps), cache, active, remaining)."""
-    if model.fused_decode is None:
-        raise ValueError(
-            f"{model.name}: model family has no batched decode path "
-            f"(use the per-slot serve step)")
-    if num_steps < 1:
-        raise ValueError(f"num_steps must be >= 1, got {num_steps}")
-
-    def serve_step(params, batch, cache, active, remaining, eos_id):
-        return model.fused_decode(params, batch, cache,
-                                  num_steps=num_steps, active=active,
-                                  remaining=remaining, eos_id=eos_id,
-                                  cap_e=batch.get("cap_e"))
-    return serve_step
-
-
 def make_paged_prefill_step(model: Model) -> Callable:
     """One CHUNK of one prompt through the paged-KV pool — the
     continuous-batching prefill unit.  ``batch["tokens"]`` is a
     bucket-padded ``(1, Cb)`` chunk; ``start``/``length`` are traced
     scalars, so each padded width ``Cb`` compiles exactly once (the paged
-    analogue of the dense engine's one-compile-per-bucket prefill).
+    analogue of the per-slot engine's one-compile-per-bucket prefill).
 
     Returns prefill_chunk(params, batch, cache, tables, start, length)
     -> (logits (1, V) at the chunk's last real token, cache[,
@@ -446,7 +399,7 @@ def make_paged_prefill_step(model: Model) -> Callable:
     if model.paged_prefill_chunk is None:
         raise ValueError(
             f"{model.name}: model family has no paged-KV path "
-            f"(use the dense serve engines)")
+            f"(use ServeLoop's per-slot engine)")
 
     def prefill_chunk(params, batch, cache, tables, start, length):
         return model.paged_prefill_chunk(params, batch, cache,
@@ -457,7 +410,10 @@ def make_paged_prefill_step(model: Model) -> Callable:
 
 def make_paged_serve_step(model: Model, num_steps: int) -> Callable:
     """``num_steps`` greedy tokens per dispatch across every row of a
-    paged-KV block pool — the paged twin of :func:`make_fused_serve_step`.
+    paged-KV block pool — ONE jitted call runs a ``lax.scan`` of
+    ``num_steps`` decode steps with per-row stop/EOS/limit handling on
+    device (``transformer.fused_paged_decode_steps``), so the Python→XLA
+    round-trip is paid once per ``num_steps`` tokens.
     ``tables (B, W)`` / ``lengths (B,)`` come from the host-side block
     manager; ``limits (B,)`` is per-row allocated capacity in tokens, so a
     row that outgrows its blocks freezes mid-dispatch instead of writing
@@ -470,7 +426,7 @@ def make_paged_serve_step(model: Model, num_steps: int) -> Callable:
     if model.fused_paged_decode is None:
         raise ValueError(
             f"{model.name}: model family has no paged-KV path "
-            f"(use the dense serve engines)")
+            f"(use ServeLoop's per-slot engine)")
     if num_steps < 1:
         raise ValueError(f"num_steps must be >= 1, got {num_steps}")
 

@@ -7,24 +7,11 @@ training/serving steps, dry-run, and tests never branch on architecture:
     model.forward(params, inputs)     -> (logits, aux)     # train/prefill
     model.init_decode(batch, max_len) -> (cache/state, specs)
     model.decode(params, inputs, st)  -> (logits, new st)
-    model.prefill(params, inputs, max_len) -> (logits, cache)  # attn archs
+    model.prefill(params, inputs, max_len) -> (logits, cache)
 
-Attention (KV-cache) archs additionally expose the batched serving path —
-one stacked cache with per-slot lengths, one decode call for all slots:
-
-    model.init_batched_decode(slots, max_len) -> (cache, specs)
-    model.batched_decode(params, inputs, cache, active=mask)
-                                      -> (logits (B,V), new cache)
-    model.insert_prefill(cache, prefill_cache, slot) -> cache
-    model.fused_decode(params, inputs, cache, num_steps=T,
-                       active=mask, remaining=rem, eos_id=eos)
-                -> (tokens (B,T), cache, active, remaining)  # T per dispatch
-
-They are ``None`` for state-space / hybrid families (``ServeLoop`` falls
-back to per-slot decode there).
-
-Attention archs also expose the paged-KV serving path (block-pool cache,
-host-side block tables — see ``repro.serve_mem``):
+Attention (KV-cache) archs also expose the paged-KV serving path
+(block-pool cache, host-side block tables — see ``repro.serve_mem``),
+which ``PagedServeLoop`` serves them through:
 
     model.init_paged_decode(num_blocks, block_size) -> (pool, specs)
     model.paged_decode(params, inputs, pool, tables=, lengths=, ...)
@@ -34,6 +21,9 @@ host-side block tables — see ``repro.serve_mem``):
                 -> (tokens (B,T), pool, lengths, active, remaining)
     model.paged_prefill_chunk(params, inputs, pool, tables=, start=,
                               length=) -> (logits (1,V), pool)
+
+They are ``None`` for state-space / hybrid families, which serve through
+the per-slot ``ServeLoop`` over ``init_decode``/``decode``.
 """
 
 from __future__ import annotations
@@ -58,15 +48,6 @@ class Model:
     init_decode: Callable
     decode: Callable
     prefill: Optional[Callable] = None
-    # batched serving path (stacked cache, per-slot lengths); None when the
-    # family has no batched decode implementation
-    init_batched_decode: Optional[Callable] = None
-    batched_decode: Optional[Callable] = None
-    insert_prefill: Optional[Callable] = None
-    # fused multi-token decode: T greedy tokens per dispatch via an
-    # on-device lax.scan over batched_decode, with per-slot stop/length
-    # handling carried in the loop state (None = no batched path)
-    fused_decode: Optional[Callable] = None
     # paged-KV serving path (block pool + host-side block tables); None
     # when the family has no paged implementation
     init_paged_decode: Optional[Callable] = None
@@ -116,18 +97,11 @@ def get_model(cfg: ModelConfig) -> Model:
                      transformer.init_cache(cfg, batch, max_len, **kw)),
         decode=(lambda params, inputs, cache, **kw:
                 transformer.decode_step(params, cfg, inputs, cache, **kw)),
-        prefill=(lambda params, inputs, max_len=None, **kw:
-                 transformer.prefill(params, cfg, inputs, max_len, **kw)),
-        init_batched_decode=(lambda slots, max_len, **kw:
-                             transformer.init_batched_cache(cfg, slots,
-                                                            max_len, **kw)),
-        batched_decode=(lambda params, inputs, cache, **kw:
-                        transformer.batched_decode_step(params, cfg, inputs,
-                                                        cache, **kw)),
-        insert_prefill=transformer.insert_prefill,
-        fused_decode=(lambda params, inputs, cache, **kw:
-                      transformer.fused_decode_steps(params, cfg, inputs,
-                                                     cache, **kw)),
+        # ``length`` marks the real prompt inside a right-padded bucket; a
+        # family whose prefill takes it serves bucketed prompts
+        prefill=(lambda params, inputs, max_len=None, *, length=None, **kw:
+                 transformer.prefill(params, cfg, inputs, max_len,
+                                     length=length, **kw)),
         init_paged_decode=(lambda num_blocks, block_size, **kw:
                            transformer.init_paged_cache(cfg, num_blocks,
                                                         block_size, **kw)),

@@ -30,8 +30,7 @@ from repro.sharding import constrain
 __all__ = [
     "ParamBuilder", "rms_norm", "make_rope", "apply_rope", "apply_mrope",
     "sinusoidal_positions", "attention", "blockwise_attention", "mlp_swiglu",
-    "mlp_gelu", "decode_attention", "scatter_kv", "gather_kv_paged",
-    "scatter_kv_paged",
+    "mlp_gelu", "decode_attention", "gather_kv_paged", "scatter_kv_paged",
 ]
 
 Tree = Dict[str, Any]
@@ -308,8 +307,8 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      cur_len: jax.Array) -> jax.Array:
     """Single-token decode: q (B, 1, H, hd) vs cache (B, S, KV, hd); positions
     >= cur_len are masked out.  ``cur_len`` is a scalar shared by every row
-    or a (B,) vector of per-row lengths (the batched serving cache, where
-    each slot's sequence has its own fill)."""
+    or a (B,) vector of per-row lengths (the paged decode's gathered
+    views, where each row's sequence has its own fill)."""
     groups = q.shape[2] // k_cache.shape[2]
     k = _repeat_kv(k_cache, groups)
     v = _repeat_kv(v_cache, groups)
@@ -323,25 +322,6 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def scatter_kv(cache: jax.Array, layer: jax.Array, new: jax.Array,
-               cur: jax.Array, active: jax.Array) -> jax.Array:
-    """Masked per-row KV append into a layer-stacked cache: write ``new``
-    (B, 1, C) into ``cache`` (L, B, S, C) at ``(layer, b, cur[b])`` for
-    every row with ``active[b]``; inactive rows, rows already full
-    (``cur[b] >= S``) and every other entry pass through untouched.
-
-    This is the batched-decode twin of ``dynamic_update_slice_in_dim``: each
-    slot of a stacked serving cache appends at its *own* sequence position,
-    in place (dropped writes are out-of-bounds positions, XLA
-    ``mode="drop"``).
-    """
-    B, S = cache.shape[1:3]
-    pos = jnp.where(jnp.asarray(active).astype(bool),
-                    jnp.broadcast_to(jnp.asarray(cur, jnp.int32), (B,)), S)
-    return cache.at[layer, jnp.arange(B), pos].set(
-        new[:, 0].astype(cache.dtype), mode="drop")
-
-
 # ------------------------------------------------------------- paged KV
 def gather_kv_paged(pool: jax.Array, layer: jax.Array,
                     tables: jax.Array) -> jax.Array:
@@ -352,13 +332,12 @@ def gather_kv_paged(pool: jax.Array, layer: jax.Array,
     int32 maps request ``b``'s logical block ``w`` (token positions
     ``[w*BS, (w+1)*BS)``) onto a pool block, ``-1`` padding unassigned
     entries.  Returns layer ``layer``'s dense view ``(B, W*BS, C)`` —
-    identical in shape and content (at every position below the request's
-    fill) to the stacked dense cache's row, so the attention math
-    downstream is the same function.  One gather over (layer, block):
-    the layer's whole ``(NB, BS, C)`` pool is never sliced out.
-    Unassigned/garbage entries are gathered from block 0 and must be
-    masked by the caller's length masking, exactly like the dense
-    cache's unwritten tail.
+    identical in content (at every position below the request's fill) to
+    a per-slot cache's row, so the attention math downstream is the same
+    function.  One gather over (layer, block): the layer's whole ``(NB,
+    BS, C)`` pool is never sliced out.  Unassigned/garbage entries are
+    gathered from block 0 and must be masked by the caller's length
+    masking, exactly like a dense cache's unwritten tail.
     """
     B, W = tables.shape
     _, _, BS, C = pool.shape
@@ -375,14 +354,14 @@ def scatter_kv_paged(pool: jax.Array, layer: jax.Array, new: jax.Array,
                      tables: jax.Array) -> jax.Array:
     """Masked per-request KV append into one layer of a paged pool.
 
-    The paged twin of :func:`scatter_kv`: write ``new (B, 1, C)`` into the
-    layer-stacked pool ``(L, NB, BS, C)`` at layer ``layer``, request
-    ``b``'s logical position ``cur[b]`` — pool block ``tables[b, cur[b] //
-    BS]``, offset ``cur[b] % BS`` — for every row with ``active[b]``, in
-    place.  Inactive rows, rows whose position falls on an unassigned
-    (``-1``) table entry, and rows past their table's width are dropped
-    via an out-of-bounds block index (XLA ``mode="drop"``), so a frozen or
-    unallocated slot can never corrupt a live block.
+    Write ``new (B, 1, C)`` into the layer-stacked pool ``(L, NB, BS,
+    C)`` at layer ``layer``, request ``b``'s logical position ``cur[b]``
+    — pool block ``tables[b, cur[b] // BS]``, offset ``cur[b] % BS`` —
+    for every row with ``active[b]``, in place.  Inactive rows, rows
+    whose position falls on an unassigned (``-1``) table entry, and rows
+    past their table's width are dropped via an out-of-bounds block index
+    (XLA ``mode="drop"``), so a frozen or unallocated slot can never
+    corrupt a live block.
     """
     _, NB, BS, _ = pool.shape
     B, W = tables.shape

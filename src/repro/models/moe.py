@@ -16,7 +16,7 @@ sharding propagation on the dispatch/combine scatter-gathers).
 Serving runs :func:`moe_held` instead: the dropless layer of one chip's
 share under expert parallelism (``ModelConfig.experts_held`` and
 ``expert_offset``), with no capacity, which the paged programs call for
-every MoE config.  Training and the dense engines keep :func:`moe_ffn`.
+every MoE config.  Training and the per-slot engine keep :func:`moe_ffn`.
 """
 
 from __future__ import annotations

@@ -23,15 +23,13 @@ from repro.models.config import ModelConfig
 from repro.models.common import (ParamBuilder, _repeat_kv, apply_mrope,
                                  apply_rope, decode_attention,
                                  gather_kv_paged, make_rope, mlp_gelu,
-                                 mlp_swiglu, rms_norm, scatter_kv,
-                                 scatter_kv_paged, sinusoidal_positions)
+                                 mlp_swiglu, rms_norm, scatter_kv_paged,
+                                 sinusoidal_positions)
 from repro.models.moe import moe_ffn, moe_held
 from repro.sharding import constrain, current_rules
 
-__all__ = ["init_params", "forward", "init_cache", "init_batched_cache",
-           "decode_step", "batched_decode_step", "fused_decode_steps",
-           "insert_prefill", "prefill", "init_paged_cache",
-           "paged_decode_step", "fused_paged_decode_steps",
+__all__ = ["init_params", "forward", "init_cache", "decode_step", "prefill",
+           "init_paged_cache", "paged_decode_step", "fused_paged_decode_steps",
            "prefill_paged_chunk", "paged_kernel_engages"]
 
 Tree = Dict[str, Any]
@@ -286,36 +284,6 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     return cache, specs
 
 
-def init_batched_cache(cfg: ModelConfig, slots: int, max_len: int,
-                       dtype: jnp.dtype = jnp.bfloat16,
-                       abstract: bool = False) -> Tuple[Tree, Tree]:
-    """Stacked serving cache: one ``(L, slots, max_len, KV*hd)`` buffer per
-    k/v shared by every decode slot, with a **per-slot** length vector
-    ``len (slots,)`` — each slot's sequence has its own fill, so one jitted
-    decode call serves all slots at their respective positions (the batched
-    ``ServeLoop`` layout; see ``batched_decode_step``)."""
-    cache, specs = init_cache(cfg, slots, max_len, dtype, abstract=abstract)
-    z = (jax.ShapeDtypeStruct if abstract
-         else (lambda s, d: jnp.zeros(s, d)))
-    cache["len"] = z((slots,), jnp.int32)
-    specs["len"] = ("batch",)
-    return cache, specs
-
-
-def insert_prefill(cache: Tree, pref: Tree, slot: jax.Array) -> Tree:
-    """Admission scatter: copy a single-request prefill cache (batch=1,
-    same ``max_len``) into row ``slot`` of a stacked batched cache and set
-    that slot's fill to the prompt length.  Other slots are untouched, so
-    admission composes with in-flight decode on every other slot."""
-    k = jax.lax.dynamic_update_index_in_dim(
-        cache["k"], pref["k"][:, 0].astype(cache["k"].dtype), slot, axis=1)
-    v = jax.lax.dynamic_update_index_in_dim(
-        cache["v"], pref["v"][:, 0].astype(cache["v"].dtype), slot, axis=1)
-    ln = jax.lax.dynamic_update_index_in_dim(
-        cache["len"], pref["len"].astype(jnp.int32), slot, axis=0)
-    return {"k": k, "v": v, "len": ln}
-
-
 def _held_ffn(cfg: ModelConfig, lp: Tree, h: jax.Array, valid: jax.Array
               ) -> Tuple[jax.Array, jax.Array]:
     """The paged programs' expert layer: :func:`moe_held` on one layer's
@@ -350,18 +318,19 @@ def _decode_forward(params: Tree, cfg: ModelConfig,
                     attend=None, moe_valid: Optional[jax.Array] = None
                     ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                Optional[jax.Array]]:
-    """The one-token decode body shared by per-slot, batched and paged paths.
+    """The one-token decode body of :func:`decode_step` (per-slot) and
+    :func:`paged_decode_step` (paged).
 
-    The paths differ ONLY in how a layer's new K/V row lands in the
-    cache (``kv_append(cache, l, new_(B,1,kv))``: ``dynamic_update_slice``
-    at a scalar length vs a masked per-row scatter vs a block-table paged
-    scatter), in how attention reads the cache back (``attend(q, kc, vc,
-    l)``: by default :func:`decode_attention` over layer ``l``'s dense
-    rows with ``attend_len`` masking; the paged pool supplies its own
-    reader, see :func:`paged_decode_step`), and in the position/length
-    values fed to rotary and attention masking — everything else (qkv,
-    residual, MLP/MoE, final norm, head) is this one function, so the
-    engines cannot drift apart.
+    The two differ ONLY in how a layer's new K/V row lands in the cache
+    (``kv_append(cache, l, new_(B,1,kv))``: ``dynamic_update_slice`` at a
+    scalar length vs a block-table paged scatter), in how attention reads
+    the cache back (``attend(q, kc, vc, l)``: by default
+    :func:`decode_attention` over layer ``l``'s dense rows with
+    ``attend_len`` masking; the paged pool supplies its own reader, see
+    :func:`paged_decode_step`), and in the position/length values fed to
+    rotary and attention masking — everything else (qkv, residual,
+    MLP/MoE, final norm, head) is this one function, so the engines cannot
+    drift apart.
 
     The layer-stacked caches ``(L, ...)`` are the layer scan's carry, not
     scanned inputs: each layer writes its K/V into them in place at its
@@ -376,8 +345,9 @@ def _decode_forward(params: Tree, cfg: ModelConfig,
 
     ``moe_valid (B,)``, given for an MoE config (the paged engine), runs
     the dropless held-share expert layer (:func:`moe_held`) over the rows
-    it marks and counts their expert slots; otherwise an MoE config runs
-    the capacity layer (:func:`moe_ffn`, ``cap_e``).
+    it marks and counts their expert slots; otherwise (the per-slot
+    :func:`decode_step`) an MoE config runs the capacity layer
+    (:func:`moe_ffn`, ``cap_e``).
 
     Returns (logits (B, V), new_k, new_v, the held experts' slots per
     layer ``(L, G)``, or None where the held-share layer did not run).
@@ -438,103 +408,6 @@ def _decode_forward(params: Tree, cfg: ModelConfig,
                 else params["lm_head"])
         logits = jnp.einsum("bsd,dv->bsv", x, head)[:, 0, :cfg.vocab_size]
     return logits, new_k, new_v, (sizes if held else None)
-
-
-def batched_decode_step(params: Tree, cfg: ModelConfig,
-                        inputs: Dict[str, jax.Array], cache: Tree, *,
-                        active: Optional[jax.Array] = None,
-                        cap_e: Optional[jax.Array] = None
-                        ) -> Tuple[jax.Array, Tree]:
-    """One-token decode across every slot of a stacked cache.
-
-    ``cache`` comes from :func:`init_batched_cache`: per-slot lengths
-    ``len (B,)``.  ``active (B,) bool`` masks the update: inactive slots
-    neither append to their KV rows nor advance their length (their logits
-    row is computed but meaningless — the serve loop discards it), so the
-    math of every active slot is bit-identical to a batch-1 ``decode_step``
-    on that slot's cache — the tested equivalence guarantee.
-
-    Returns (logits (B, V), updated cache).
-    """
-    cur = cache["len"]                              # (B,) per-slot fill
-    B = cur.shape[0]
-    active = (jnp.ones((B,), bool) if active is None
-              else jnp.asarray(active).astype(bool))
-    logits, new_k, new_v, _ = _decode_forward(
-        params, cfg, inputs, cache,
-        positions=cur[:, None],                     # (B, 1) per-slot
-        kv_append=lambda c, l, new: scatter_kv(c, l, new, cur, active),
-        attend_len=cur + 1,
-        cap_e=cap_e)
-    new_cache = {"k": new_k, "v": new_v,
-                 "len": cur + active.astype(jnp.int32)}
-    return logits, new_cache
-
-
-def fused_decode_steps(params: Tree, cfg: ModelConfig,
-                       inputs: Dict[str, jax.Array], cache: Tree, *,
-                       num_steps: int,
-                       active: Optional[jax.Array] = None,
-                       remaining: Optional[jax.Array] = None,
-                       eos_id: Optional[jax.Array] = None,
-                       cap_e: Optional[jax.Array] = None
-                       ) -> Tuple[jax.Array, Tree, jax.Array, jax.Array]:
-    """Run up to ``num_steps`` greedy decode tokens per slot ON DEVICE.
-
-    One ``lax.scan`` over :func:`batched_decode_step` — ONE dispatch per
-    ``num_steps`` tokens instead of one per token, which is the whole
-    point: at production rates the Python→XLA round-trip per token is the
-    serve bottleneck, not the model math.  The dispatch quantum
-    ``num_steps`` is a schedule parameter (``ServeLoop(decode_steps=T)``);
-    ``num_steps=1`` is exactly one ``batched_decode_step`` and reproduces
-    the stepwise engine token for token (greedy decode is deterministic,
-    so any T does).
-
-    Per-slot stop/length handling lives in the loop carry:
-
-    * ``remaining (B,) int32`` — tokens the slot still wants.  A slot
-      freezes in place (no KV append, no length bump, no further tokens)
-      the step its count hits zero, so slots with fewer than ``num_steps``
-      tokens left simply ride out the dispatch frozen.
-    * ``eos_id`` — optional scalar; a slot that emits it freezes on the
-      next step (the EOS token itself is emitted and counted).
-    * cache capacity — a slot whose fill reaches ``max_len`` freezes
-      rather than scattering out of bounds (belt-and-braces: admission
-      budgets already clamp ``remaining`` to cache capacity).
-
-    Returns ``(tokens (B, num_steps) int32, cache, active, remaining)``.
-    Slot ``b``'s real output is the first ``remaining_in[b] -
-    remaining_out[b]`` entries of ``tokens[b]``; frozen steps emit -1.
-    """
-    tok = inputs["tokens"]                          # (B, 1) int32
-    B = cache["len"].shape[0]
-    max_len = cache["k"].shape[2]
-    act = (jnp.ones((B,), bool) if active is None
-           else jnp.asarray(active).astype(bool))
-    rem = (jnp.full((B,), num_steps, jnp.int32) if remaining is None
-           else jnp.asarray(remaining).astype(jnp.int32))
-    act = act & (rem > 0)
-    eos = (jnp.asarray(-1, jnp.int32) if eos_id is None
-           else jnp.asarray(eos_id).astype(jnp.int32))
-
-    def body(carry, _):
-        tok, k, v, ln, act, rem = carry
-        logits, new_cache = batched_decode_step(
-            params, cfg, {"tokens": tok}, {"k": k, "v": v, "len": ln},
-            active=act, cap_e=cap_e)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)     # (B,)
-        emit = jnp.where(act, nxt, -1)
-        rem = rem - act.astype(jnp.int32)
-        ln = new_cache["len"]
-        act = act & (rem > 0) & (nxt != eos) & (ln < max_len)
-        # frozen slots keep feeding their old token (never appended again)
-        tok = jnp.where(act, nxt, tok[:, 0])[:, None]
-        return (tok, new_cache["k"], new_cache["v"], ln, act, rem), emit
-
-    (tok, k, v, ln, act, rem), toks = jax.lax.scan(
-        body, (tok, cache["k"], cache["v"], cache["len"], act, rem),
-        None, length=num_steps)
-    return toks.T, {"k": k, "v": v, "len": ln}, act, rem
 
 
 # -------------------------------------------------------------- paged KV
@@ -612,13 +485,13 @@ def paged_decode_step(params: Tree, cfg: ModelConfig,
 
     ``tables (B, W)`` int32 maps each row's logical blocks onto pool
     blocks (``-1`` = unassigned); ``lengths (B,)`` is each row's fill.
-    The body is the same :func:`_decode_forward` as the dense engines —
-    only the append (block-table scatter) and the attention's read of the
-    cache differ (:func:`_paged_attend`: on a TPU the Pallas kernel reads
-    each active row's live blocks; elsewhere a block-table gather to a
-    ``(B, W*BS, C)`` view feeds the dense attention), so an active row's
-    math is that of :func:`batched_decode_step` over a dense ``max_len =
-    W*BS`` cache holding the same sequence — the paged-vs-dense
+    The body is the same :func:`_decode_forward` as the per-slot
+    :func:`decode_step` — only the append (block-table scatter) and the
+    attention's read of the cache differ (:func:`_paged_attend`: on a TPU
+    the Pallas kernel reads each active row's live blocks; elsewhere a
+    block-table gather to a ``(B, W*BS, C)`` view feeds the dense
+    attention), so an active row's math is that of :func:`decode_step`
+    over a dense cache holding the same sequence — the paged-vs-per-slot
     equivalence guarantee, exact on the gather and to rounding (the
     kernel's online softmax) on the kernel.  ``kernel_interpret=True``
     runs the kernel in interpret mode, on any platform and shape.
@@ -658,15 +531,26 @@ def fused_paged_decode_steps(params: Tree, cfg: ModelConfig,
                              kernel_interpret: bool = False
                              ) -> Tuple[jax.Array, ...]:
     """Run up to ``num_steps`` greedy tokens per row through the paged
-    pool ON DEVICE — the paged twin of :func:`fused_decode_steps`.
+    pool ON DEVICE: one ``lax.scan`` over :func:`paged_decode_step`, ONE
+    dispatch per ``num_steps`` tokens instead of one per token.  The
+    dispatch quantum ``num_steps`` is a schedule parameter
+    (``PagedServeLoop(decode_steps=T)``); greedy decode is deterministic,
+    so every T gives the same tokens.
 
-    Block tables are fixed for the duration of a dispatch (the host
-    allocates before dispatching); ``limits (B,)`` is each row's
-    currently-covered capacity in tokens (``allocated_blocks * BS``) —
-    a row whose fill reaches its limit freezes in place rather than
-    scattering into a block it does not own, which is the memory-pressure
-    edge the serve loop turns into a preemption decision.  Budget and EOS
-    freezes behave exactly as in the dense fused engine.
+    Per-row stop handling lives in the loop carry, and a stopped row
+    freezes in place (no KV append, no length bump, no further tokens):
+
+    * ``remaining (B,) int32`` — tokens the row still wants; it freezes
+      the step its count hits zero;
+    * ``eos_id`` — optional scalar; a row that emits it freezes on the
+      next step (the EOS token itself is emitted and counted);
+    * ``limits (B,)`` — each row's currently-covered capacity in tokens
+      (``allocated_blocks * BS``; block tables are fixed for the duration
+      of a dispatch, the host allocates before dispatching).  A row whose
+      fill reaches its limit freezes rather than scattering into a block
+      it does not own, which is the memory-pressure edge the serve loop
+      turns into a preemption decision.
+
     ``kernel_interpret`` as in :func:`paged_decode_step`.
 
     Returns ``(tokens (B, num_steps), cache, lengths, active,

@@ -2,7 +2,8 @@
 
 The paged engine's acceptance scenario — O(100) concurrent requests with
 a long/short prompt mix under memory pressure — needs one trace both the
-test suite and ``benchmarks/serve_adapt.py`` stage 6 agree on, or the
+test suite and ``benchmarks/serve_adapt.py``'s paged-concurrency stage
+agree on, or the
 bench gates a workload the tests never exercised.  ``make_mixed_trace``
 is that single source: seeded, host-only, and returning plain
 ``(prompt, max_new)`` material the caller wraps into ``Request``s.

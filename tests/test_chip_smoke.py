@@ -78,7 +78,7 @@ def test_check_served_rejects_short_and_out_of_vocab_output(chip_smoke, cfg):
         chip_smoke.check_served({0: [1, 2, 3]}, reqs, cfg.vocab_size)
 
 
-@pytest.mark.parametrize("engine", ["serve_paged", "serve_dense"])
+@pytest.mark.parametrize("engine", ["serve_paged", "serve_per_slot"])
 def test_serve_phase_on_the_smoke_twin(chip_smoke, cfg, engine):
     stats = getattr(chip_smoke, engine)(cfg, 0, **SMOKE_SERVE)
     assert 0 <= stats["logits_rel_err"] <= chip_smoke.LOGITS_RTOL

@@ -1,4 +1,4 @@
-"""Paged-KV serving tests: allocator invariants + paged-vs-dense equivalence.
+"""Paged-KV serving tests: allocator invariants + paged-vs-per-slot equivalence.
 
 The block-table KV subsystem makes cache memory the scheduled resource, so
 its correctness splits into two layers, each locked here:
@@ -13,10 +13,12 @@ its correctness splits into two layers, each locked here:
 * **Engine equivalence** (device side): the paged engine — chunked
   prefill through block tables, fused paged decode, preemption with
   evict→readmit — serves token-for-token the SAME generations as the
-  dense batched :class:`ServeLoop` for every schedule family, including
-  runs where memory pressure forces at least one preemption (greedy
-  decode is deterministic, so a readmitted request must resume exactly
-  where an uninterrupted run would be).
+  per-slot :class:`ServeLoop` for every schedule family and every
+  dispatch quantum T, including runs where memory pressure forces at
+  least one preemption (greedy decode is deterministic, so a readmitted
+  request must resume exactly where an uninterrupted run would be), and
+  a row that stops inside a fused dispatch (budget, EOS, the context
+  ceiling) freezes there on device.
 
 Plus the chunked-prefill bucketing regression: prefill chunks are
 bucket-padded, so compile count is bounded by the BUCKET count no matter
@@ -260,27 +262,6 @@ def test_scatter_kv_paged_writes_only_its_layer(layer):
 
 
 @pytest.mark.parametrize("layer", range(HELPER_LAYERS))
-def test_scatter_kv_writes_only_its_layer(layer):
-    """The dense twin: an append at layer ``l`` of a ``(L, B, S, C)``
-    cache writes ``(l, b, cur[b])`` of each active row with room, and
-    nothing else; a full row and an inactive row are dropped."""
-    from repro.models.common import scatter_kv
-    rng = np.random.default_rng(20 + layer)
-    cache = jnp.asarray(rng.normal(size=(HELPER_LAYERS, 4, 6, 8)),
-                        jnp.bfloat16)
-    cur = np.array([0, 5, 6, 2], np.int32)        # row 2 is full
-    active = np.array([True, True, True, False])
-    new = jnp.asarray(rng.normal(size=(4, 1, 8)), jnp.bfloat16)
-    got = np.asarray(scatter_kv(cache, jnp.int32(layer), new,
-                                jnp.asarray(cur), jnp.asarray(active)),
-                     np.float32)
-    want = np.asarray(cache, np.float32).copy()
-    for row in (0, 1):
-        want[layer, row, cur[row]] = np.asarray(new[row, 0], np.float32)
-    np.testing.assert_array_equal(got, want)
-
-
-@pytest.mark.parametrize("layer", range(HELPER_LAYERS))
 def test_gather_kv_paged_reads_its_layer(layer):
     """The gather at layer ``l`` equals the per-layer gather of ``pool[l]``
     through the clipped tables (``-1`` reads block 0), and differs from
@@ -307,17 +288,32 @@ def cfg():
 
 
 @pytest.fixture(scope="module")
-def dense_loop(cfg):
-    return ServeLoop(cfg, slots=3, max_len=MAX_LEN, batched=True,
-                     decode_steps=2)
+def per_slot_loop(cfg):
+    """The token reference: the per-slot engine, one batch-1 dense cache
+    per slot."""
+    return ServeLoop(cfg, slots=3, max_len=MAX_LEN)
 
 
 @pytest.fixture(scope="module")
-def paged_loop(cfg):
-    # pool >= N_REQUESTS * max_context: no pressure, pure equivalence
-    return PagedServeLoop(cfg, num_blocks=64, block_size=BLOCK_SIZE,
-                          max_context=MAX_LEN, concurrency=8,
-                          decode_steps=2, prefill_chunk=16)
+def paged_loops(cfg):
+    """Paged loops by dispatch quantum T, built on first use (one
+    compile each) and shared across the module's tests.  The pool holds
+    N_REQUESTS * max_context: no pressure, pure equivalence."""
+    loops = {}
+
+    def get(decode_steps: int) -> PagedServeLoop:
+        if decode_steps not in loops:
+            loops[decode_steps] = PagedServeLoop(
+                cfg, num_blocks=64, block_size=BLOCK_SIZE,
+                max_context=MAX_LEN, concurrency=8,
+                decode_steps=decode_steps, prefill_chunk=16)
+        return loops[decode_steps]
+    return get
+
+
+@pytest.fixture(scope="module")
+def paged_loop(paged_loops):
+    return paged_loops(2)
 
 
 @pytest.fixture(scope="module")
@@ -335,25 +331,81 @@ def run_loop(loop, scheduler, requests):
     return loop.run(requests)
 
 
+@pytest.mark.parametrize("decode_steps", [1, 2, 4, 16])
 @pytest.mark.parametrize("clause", ["static", "guided,2", "awf"])
-def test_paged_dense_token_equivalence(clause, dense_loop, paged_loop):
+def test_paged_token_equivalence(clause, decode_steps, per_slot_loop,
+                                 paged_loops):
     """The tentpole guarantee: where both engines fit the working set,
     the paged engine serves token-for-token the same generations as the
-    dense batched engine, under every schedule family."""
-    out_d = run_loop(dense_loop, clause, make_requests(42))
-    out_p = run_loop(paged_loop, clause, make_requests(42))
+    per-slot engine, under every schedule family and at every dispatch
+    quantum T; each run flushes one measured epoch with full token
+    credit, and T > 1 takes fewer decode dispatches than T = 1."""
+    out_d = run_loop(per_slot_loop, clause, make_requests(42))
+    loop = paged_loops(decode_steps)
+    out_p = run_loop(loop, clause, make_requests(42))
+    assert loop.decode_steps == decode_steps
     assert sorted(out_p) == list(range(N_REQUESTS))
     assert out_p == out_d
-    assert paged_loop.last_stats["preemptions"] == 0
-    assert paged_loop.pool.used == 0        # every block returned
+    assert loop.measured_epoch() == per_slot_loop.measured_epoch() == 1
+    assert (loop.last_stats["decoded_tokens"]
+            == per_slot_loop.last_stats["decoded_tokens"])
+    assert loop.last_stats["preemptions"] == 0
+    assert loop.pool.used == 0              # every block returned
+    if decode_steps > 1:
+        stepwise = paged_loops(1)
+        run_loop(stepwise, clause, make_requests(42))
+        assert (loop.last_stats["decode_dispatches"]
+                < stepwise.last_stats["decode_dispatches"])
 
 
-def test_preemption_preserves_tokens(dense_loop, tight_loop):
+@pytest.mark.parametrize("decode_steps", [1, 8])
+def test_max_len_mid_dispatch_truncation(cfg, per_slot_loop, paged_loops,
+                                         decode_steps):
+    """A row whose context fills MID-fused-dispatch (prompt near
+    max_context, quantum spanning the cap) freezes at capacity and
+    reports the truncation — the per-slot engine's tokens at every
+    dispatch quantum."""
+    prompt = (np.arange(MAX_LEN - 3, dtype=np.int32) % 16)     # capacity 4
+    ref = run_loop(per_slot_loop, "static",
+                   [Request(rid=0, prompt=prompt.copy(), max_new=10)])
+    loop = paged_loops(decode_steps)
+    reqs = [Request(rid=0, prompt=prompt, max_new=10)]
+    out = run_loop(loop, "static", reqs)
+    assert len(out[0]) == 4                    # capacity, not max_new
+    assert out == ref
+    assert reqs[0].truncated
+    assert loop.last_stats["truncated"] == [0]
+    assert loop.pool.used == 0
+
+
+@pytest.mark.parametrize("decode_steps", [1, 8])
+def test_paged_eos_freezes_row_mid_dispatch(cfg, per_slot_loop, paged_loops,
+                                            decode_steps):
+    """A row that emits EOS inside a fused dispatch freezes in place (no
+    tokens past EOS) where the per-slot engine stops."""
+    rng = np.random.default_rng(3)
+    prompt = rng.integers(0, cfg.vocab_size, size=8).astype(np.int32)
+    ref = run_loop(per_slot_loop, "static",
+                   [Request(rid=0, prompt=prompt.copy(), max_new=8)])[0]
+    eos = ref[3]                    # force a stop 4 tokens in
+    loop = paged_loops(decode_steps)
+    loop.eos_id = eos               # an argument of the program: no compile
+    try:
+        out = run_loop(loop, "static",
+                       [Request(rid=0, prompt=prompt.copy(), max_new=8)])
+    finally:
+        loop.eos_id = None
+    assert out[0] == ref[:4]
+    assert out[0][-1] == eos
+    assert loop.pool.used == 0
+
+
+def test_preemption_preserves_tokens(per_slot_loop, tight_loop):
     """Memory pressure forces eviction; the evicted request re-prefills
     its generated prefix on readmission and must resume EXACTLY where an
-    uninterrupted (dense) run would be — token-for-token."""
+    uninterrupted (per-slot) run would be — token-for-token."""
     reqs = make_requests(7, lo=8, hi=32, max_new=12)
-    out_d = run_loop(dense_loop, "dynamic", make_requests(7, lo=8, hi=32,
+    out_d = run_loop(per_slot_loop, "dynamic", make_requests(7, lo=8, hi=32,
                                                           max_new=12))
     out_p = run_loop(tight_loop, "dynamic", reqs)
     assert tight_loop.last_stats["preemptions"] >= 1
@@ -409,27 +461,27 @@ def test_paged_observability(cfg):
     assert loop.measured_epoch() >= 1
 
 
-def test_dense_loop_gains_meter(dense_loop):
-    out = run_loop(dense_loop, "static", make_requests(13))
+def test_dense_loop_gains_meter(per_slot_loop):
+    out = run_loop(per_slot_loop, "static", make_requests(13))
     assert len(out) == N_REQUESTS
-    meter = dense_loop.last_stats["serve_meter"]
+    meter = per_slot_loop.last_stats["serve_meter"]
     assert meter["requests_finished"] == N_REQUESTS
     assert meter["queue_p99_s"] is not None
     assert meter["admission_p99_s"] is not None
     assert meter["preemptions"] == 0
 
 
-def test_truncation_matches_dense(cfg, dense_loop, paged_loop):
+def test_truncation_matches_dense(cfg, per_slot_loop, paged_loop):
     """A request whose prompt + max_new overflows max_context is admitted
     with its budget clamped and REPORTED truncated — same rule, same
-    tokens as the dense engine."""
+    tokens as the per-slot engine."""
     def mk():
         rng = np.random.default_rng(5)
         return [Request(rid=0,
                         prompt=rng.integers(0, cfg.vocab_size,
                                             size=60).astype(np.int32),
                         max_new=20)]
-    out_d = run_loop(dense_loop, "static", mk())
+    out_d = run_loop(per_slot_loop, "static", mk())
     reqs = mk()
     out_p = run_loop(paged_loop, "static", reqs)
     assert out_p == out_d

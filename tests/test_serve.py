@@ -1,25 +1,17 @@
-"""Serving-engine tests: batched/fused/per-slot equivalence and telemetry.
+"""Serving-engine tests: the per-slot engine, its telemetry, and which
+engine each model family serves through.
 
-The batched decode path (one jitted call per token across all slots over a
-stacked ``[slots, max_len]`` KV cache) must be *behaviourally invisible*:
-
-* token-for-token identical generations to the per-slot escape hatch
-  (one jit call per active slot over batch-1 caches), and
-* identical chunk→slot assignments from the UDS admission scheduler,
-
-for every builtin schedule family — static chunking, guided self-scheduling
-and adaptive weighted factoring.  The telemetry loop must keep feeding
-per-slot busy times into the LoopHistory so AWF admission still replans
-per slot (the PR-2 measure stage survives batching).
-
-The FUSED dispatch quantum (``decode_steps=T``: one jitted call runs T
-tokens via an on-device ``lax.scan`` with per-slot stop handling) must be
-equally invisible: greedy decode is deterministic, so every T serves the
-same tokens — locked down for T ∈ {1, 4, 16} under every schedule family,
-plus the mid-dispatch freeze cases (budget exhaustion, EOS, cache
-capacity).  Prefill bucketing (prompts right-padded to power-of-two
-buckets) must not change tokens and must bound compile count by buckets,
-not distinct prompt lengths.
+The per-slot ``ServeLoop`` (one jitted call per active slot per token over
+batch-1 caches) is the engine of the ``ssm``/``hybrid`` families and the
+token reference the paged engine is tested against (``tests/test_paged.py``).
+Its admission semantics are the paper's: slots are workers, requests are
+iterations, and the UDS decides the chunk→slot assignment.  Locked here:
+truncation and refusal at the cache ceiling, partial-team drain, prefill
+bucketing (prompts right-padded to power-of-two buckets must not change
+tokens and must bound compile count by buckets, not distinct prompt
+lengths), the measure stage (per-slot busy times flushed into the
+LoopHistory so AWF admission replans per slot), and the serve CLI's choice
+of engine by what the family implements.
 """
 
 import numpy as np
@@ -28,7 +20,7 @@ import pytest
 from repro.configs import get_smoke_config
 from repro.core import LoopHistory, LoopSpec, get_engine
 from repro.core.spec import resolve
-from repro.launch.serve import Request, ServeLoop
+from repro.launch.serve import PagedServeLoop, Request, ServeLoop, engine_for
 
 SLOTS = 3
 MAX_LEN = 64
@@ -52,16 +44,11 @@ def cfg():
     return get_smoke_config("qwen2.5-3b")
 
 
-# one loop per mode, shared across schedule families (compile once);
-# scheduler and history are swapped per run
-@pytest.fixture(scope="module")
-def batched_loop(cfg):
-    return ServeLoop(cfg, slots=SLOTS, max_len=MAX_LEN, batched=True)
-
-
+# one loop shared across schedule families (compile once); scheduler and
+# history are swapped per run
 @pytest.fixture(scope="module")
 def per_slot_loop(cfg):
-    return ServeLoop(cfg, slots=SLOTS, max_len=MAX_LEN, batched=False)
+    return ServeLoop(cfg, slots=SLOTS, max_len=MAX_LEN)
 
 
 def run_with(loop: ServeLoop, scheduler, seed: int):
@@ -75,134 +62,69 @@ def run_with(loop: ServeLoop, scheduler, seed: int):
     return out, chunks
 
 
-# ------------------------------------------------------------- equivalence
-@pytest.mark.parametrize("clause", ["static", "guided,2", "awf"])
-def test_batched_token_and_assignment_equivalence(clause, batched_loop,
-                                                  per_slot_loop):
-    """The tentpole guarantee: under every builtin schedule family the
-    batched engine serves the same tokens to the same requests, admitted
-    through the same chunk→slot assignments, as the per-slot path."""
-    out_b, chunks_b = run_with(batched_loop, clause, seed=42)
-    out_p, chunks_p = run_with(per_slot_loop, clause, seed=42)
-    assert batched_loop.mode == "batched"
-    assert per_slot_loop.mode == "per_slot"
-    assert sorted(out_b) == list(range(N_REQUESTS))
-    assert out_b == out_p                      # token-for-token identical
-    assert chunks_b == chunks_p                # same UDS admission decisions
-
-
-def test_batched_is_the_default(cfg, batched_loop):
-    assert ServeLoop.__init__.__kwdefaults__["batched"] is True
-    assert batched_loop.batched
-    # stacked cache: one buffer for all slots, per-slot lengths
-    assert batched_loop.cache["len"].shape == (SLOTS,)
-    assert batched_loop.cache["k"].shape[1] == SLOTS
-    assert batched_loop.caches is None
-
-
+# ------------------------------------------------------------ engine choice
 def test_ssm_family_falls_back_to_per_slot():
-    """rwkv6 has no stacked-cache decode yet: requesting batched serving
-    must degrade to the per-slot path instead of refusing to serve."""
+    """rwkv6 has no paged-KV decode (its state is recurrent, not KV
+    blocks): the SSM family serves per-slot, unbucketed, to completion."""
     from repro.models import get_model
     cfg = get_smoke_config("rwkv6-3b")
-    assert get_model(cfg).batched_decode is None
+    assert get_model(cfg).paged_decode is None
+    assert engine_for(cfg) is ServeLoop
+    loop = ServeLoop(cfg, slots=2, max_len=MAX_LEN)
+    assert not loop._bucketed           # pad tokens would enter its state
+    rng = np.random.default_rng(4)
+    out = loop.run([Request(rid=i, max_new=MAX_NEW,
+                            prompt=rng.integers(0, cfg.vocab_size, size=6
+                                                ).astype(np.int32))
+                    for i in range(3)])
+    assert sorted(out) == [0, 1, 2]
+    assert all(len(v) == MAX_NEW for v in out.values())
 
 
-def test_over_capacity_request_is_truncated_and_reported(batched_loop):
+ENGINES = {"qwen2.5-3b": PagedServeLoop, "qwen3-moe-235b-a22b": PagedServeLoop,
+           "rwkv6-3b": ServeLoop, "zamba2-2.7b": ServeLoop}
+
+
+@pytest.mark.parametrize("arch", ENGINES)
+def test_serve_cli_picks_engine_by_family(arch, monkeypatch):
+    """With no flag, the serve CLI serves attention families through the
+    paged engine and the recurrent ones per slot, and every request of
+    the run gets its budget."""
+    from repro.launch import serve
+    # the CLI's persistent compile cache would stay on for later tests
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    engine = ENGINES[arch]
+    assert engine_for(get_smoke_config(arch)) is engine
+    loop = serve.main(["--arch", arch, "--smoke", "--requests", "2",
+                       "--max-new", "2", "--max-concurrency", "2"])
+    assert type(loop) is engine
+    assert loop.last_stats["decoded_tokens"] == 2 * (2 - 1)
+
+
+def test_over_capacity_request_is_truncated_and_reported(per_slot_loop):
     """prompt + max_new beyond max_len is admitted with the generation
     budget clamped to cache capacity, and the truncation is REPORTED per
     request — never silently padded (dropped KV appends would corrupt the
     generation) and never refused (the request is serveable)."""
     prompt = np.arange(MAX_LEN - 2, dtype=np.int32) % 16      # capacity 3
-    batched_loop.scheduler = "dynamic"
-    batched_loop.history = LoopHistory()
+    per_slot_loop.scheduler = "dynamic"
+    per_slot_loop.history = LoopHistory()
     reqs = [Request(rid=0, prompt=prompt, max_new=8)]
-    out = batched_loop.run(reqs)
+    out = per_slot_loop.run(reqs)
     assert len(out[0]) == MAX_LEN - len(prompt) + 1            # clamped
     assert reqs[0].truncated
-    assert batched_loop.last_stats["truncated"] == [0]
+    assert per_slot_loop.last_stats["truncated"] == [0]
     assert -1 not in out[0]                    # no frozen-step padding
 
 
-def test_prompt_alone_over_max_len_is_refused(batched_loop):
+def test_prompt_alone_over_max_len_is_refused(per_slot_loop):
     """A prompt that cannot even fit the cache is not serveable at any
     budget: refuse loudly instead of truncating the PROMPT."""
     prompt = np.arange(MAX_LEN + 1, dtype=np.int32) % 16
-    batched_loop.scheduler = "dynamic"
-    batched_loop.history = LoopHistory()
+    per_slot_loop.scheduler = "dynamic"
+    per_slot_loop.history = LoopHistory()
     with pytest.raises(ValueError, match="max_len"):
-        batched_loop.run([Request(rid=0, prompt=prompt, max_new=2)])
-
-
-@pytest.mark.parametrize("decode_steps", [1, 8])
-def test_max_len_mid_dispatch_truncation(cfg, decode_steps):
-    """Regression: a slot whose cache fills MID-fused-dispatch (prompt
-    near max_len, quantum spanning the cap) must freeze at capacity and
-    report the truncation — same tokens at every dispatch quantum."""
-    prompt = (np.arange(MAX_LEN - 3, dtype=np.int32) % 16)     # capacity 4
-    loop = ServeLoop(cfg, slots=2, max_len=MAX_LEN,
-                     decode_steps=decode_steps)
-    reqs = [Request(rid=0, prompt=prompt, max_new=10)]
-    out = loop.run(reqs)
-    assert len(out[0]) == 4                    # capacity, not max_new
-    assert reqs[0].truncated
-    assert loop.last_stats["truncated"] == [0]
-    assert int(np.asarray(loop.cache["len"])[0]) <= MAX_LEN
-
-
-# ------------------------------------------------------------ fused decode
-@pytest.fixture(scope="module")
-def fused_loops(cfg):
-    """One loop per dispatch quantum, shared across schedule families
-    (compile once); scheduler and history are swapped per run."""
-    return {t: ServeLoop(cfg, slots=SLOTS, max_len=MAX_LEN, decode_steps=t)
-            for t in (4, 16)}
-
-
-@pytest.mark.parametrize("decode_steps", [4, 16])
-@pytest.mark.parametrize("clause", ["static", "guided,2", "awf"])
-def test_fused_token_and_epoch_equivalence(clause, decode_steps,
-                                           batched_loop, fused_loops):
-    """The fused guarantee: the dispatch quantum is invisible — T tokens
-    per jitted call serve exactly the tokens the stepwise engine (T=1)
-    serves, under every builtin schedule family, and the measure stage
-    still flushes one epoch per run with full token credit.  (Chunk→slot
-    assignments may legitimately differ: admission happens at dispatch
-    boundaries, so only tokens + telemetry epochs are contractual.)"""
-    out_1, _ = run_with(batched_loop, clause, seed=42)
-    fused = fused_loops[decode_steps]
-    out_t, _ = run_with(fused, clause, seed=42)
-    assert fused.decode_steps == decode_steps
-    assert out_t == out_1                      # token-for-token identical
-    assert fused.measured_epoch() == batched_loop.measured_epoch() == 1
-    assert (fused.last_stats["decoded_tokens"]
-            == batched_loop.last_stats["decoded_tokens"])
-    # the point of fusing: strictly fewer host->device dispatches
-    assert (fused.last_stats["decode_dispatches"]
-            < batched_loop.last_stats["decode_dispatches"])
-
-
-def test_stepwise_is_the_default_quantum(batched_loop):
-    """decode_steps=1 (exactly today's engine) stays the default; the
-    fused quantum is opt-in."""
-    assert ServeLoop.__init__.__kwdefaults__["decode_steps"] == 1
-    assert batched_loop.decode_steps == 1
-
-
-def test_fused_eos_freezes_slot_mid_dispatch(cfg):
-    """A slot that emits EOS inside a fused dispatch freezes in place (no
-    tokens past EOS) while the stepwise run stops at the same point."""
-    rng = np.random.default_rng(3)
-    prompt = rng.integers(0, cfg.vocab_size, size=8).astype(np.int32)
-    base = ServeLoop(cfg, slots=1, max_len=MAX_LEN, decode_steps=1)
-    ref = base.run([Request(rid=0, prompt=prompt.copy(), max_new=8)])[0]
-    eos = ref[3]                    # force a stop 4 tokens in
-    for steps in (1, 8):
-        loop = ServeLoop(cfg, slots=1, max_len=MAX_LEN, decode_steps=steps,
-                         eos_id=eos)
-        out = loop.run([Request(rid=0, prompt=prompt.copy(), max_new=8)])
-        assert out[0] == ref[:4], f"decode_steps={steps}"
-        assert out[0][-1] == eos
+        per_slot_loop.run([Request(rid=0, prompt=prompt, max_new=2)])
 
 
 # ------------------------------------------------------- prefill bucketing
@@ -230,22 +152,21 @@ def test_prefill_compiles_once_per_bucket(cfg):
     assert loop.prefill_compiles == len(buckets) < len(lengths)
 
 
-def test_partial_team_drain(batched_loop):
-    """More slots than requests at the tail: the active-slot mask must let
-    a partially-filled team drain without corrupting idle slots."""
-    out, _ = run_with(batched_loop, "dynamic", seed=7)
+def test_partial_team_drain(per_slot_loop):
+    """More slots than requests at the tail: a partially-filled team
+    drains without corrupting idle slots."""
+    out, _ = run_with(per_slot_loop, "dynamic", seed=7)
     assert sorted(out) == list(range(N_REQUESTS))
     assert all(len(v) == MAX_NEW for v in out.values())
 
 
 # --------------------------------------------------------------- telemetry
 def test_batched_busy_times_bump_epoch_and_replan(cfg):
-    """The measure stage survives batching: each run flushes per-slot busy
-    times into the history (epoch bump), and the bumped epoch invalidates
-    the engine's cached adaptive plan, so AWF admission replans from the
-    measured data."""
-    loop = ServeLoop(cfg, slots=2, max_len=MAX_LEN, scheduler="awf",
-                     batched=True)
+    """The measure stage: each run flushes per-slot busy times into the
+    history (epoch bump), and the bumped epoch invalidates the engine's
+    cached adaptive plan, so AWF admission replans from the measured
+    data."""
+    loop = ServeLoop(cfg, slots=2, max_len=MAX_LEN, scheduler="awf")
     assert loop.measured_epoch() == 0
     out1 = loop.run(make_requests(0))
     assert sorted(out1) == list(range(N_REQUESTS))
@@ -254,7 +175,6 @@ def test_batched_busy_times_bump_epoch_and_replan(cfg):
     # per-slot attribution is intact: every slot that served a chunk has
     # positive measured busy time and generated-token credit
     per_worker = loop.last_stats["per_worker"]
-    assert loop.last_stats["mode"] == "batched"
     served = [w for w, st in per_worker.items() if st["chunks"] > 0]
     assert served
     assert all(per_worker[w]["time_s"] > 0 for w in served)
@@ -271,3 +191,13 @@ def test_batched_busy_times_bump_epoch_and_replan(cfg):
     assert loop.measured_epoch() == 2
     plan2 = get_engine().plan(resolve("awf"), spec, history=loop.history)
     assert plan1 is not plan2          # cache invalidated -> replanned
+
+
+def test_serve_cli_refuses_a_kill_without_the_paged_engine(monkeypatch):
+    """An injected row kill is a paged-engine fault; asked of a family
+    that serves per slot, the CLI refuses it instead of ignoring it."""
+    from repro.launch import serve
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    with pytest.raises(SystemExit):
+        serve.main(["--arch", "rwkv6-3b", "--smoke", "--kill-rows", "1",
+                    "--kill-at-dispatch", "1"])

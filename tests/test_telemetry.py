@@ -77,31 +77,6 @@ def test_add_time_weighted_splits_step_time_proportionally():
     tel.add_time_weighted(1.0, {0: 1.0})
 
 
-def test_add_time_split_per_worker_token_credit():
-    """The fused-decode attribution: one dispatch's wall time splits
-    equally across the slots it advanced, but each slot is credited its
-    OWN produced-token count (slots freezing mid-dispatch produce fewer
-    tokens than the quantum)."""
-    tel = LoopTelemetry(LoopHistory(), loop_id="serve", num_workers=3)
-    for s in range(3):
-        tel.begin(s, Chunk(s, s + 1, s))
-    tel.add_time_split([0, 1, 2], 0.9, tokens={0: 8, 1: 3, 2: 8})
-    assert tel.end(0) == pytest.approx(0.3)
-    assert tel.end(1) == pytest.approx(0.3)
-    assert tel.end(2) == pytest.approx(0.3)
-    tel.flush()
-    s = tel.summary()
-    assert s["total_tokens"] == 19
-    assert s["per_worker"][1]["tokens"] == 3
-    # the scalar form still broadcasts one count to every worker
-    tel.begin(0, Chunk(0, 1, 0))
-    tel.begin(1, Chunk(1, 2, 1))
-    tel.add_time_split([0, 1], 0.2, tokens=1)
-    tel.end(0), tel.end(1)
-    tel.flush()
-    assert tel.summary()["per_worker"][0]["tokens"] == 8 + 1
-
-
 def test_flush_closes_open_ledgers_and_bumps_epoch_once():
     hist = LoopHistory()
     tel = LoopTelemetry(hist, loop_id="x", num_workers=1)

@@ -25,8 +25,12 @@ NON_REGISTRY_KINDS = {"runtime"}
 
 
 def documented_names(text: str) -> set:
-    """Backticked first-cell names of the guide's schedule table rows."""
-    return set(re.findall(r"(?m)^\|\s*`([a-z0-9_]+)`\s*\|", text))
+    """Backticked first-cell names of the guide's schedule table rows: the
+    tables of its "Registered schedules" section (other sections' tables,
+    such as the serve loop's counters, name no schedules)."""
+    section = text.split("\n## Registered schedules", 1)[-1]
+    section = section.split("\n## ", 1)[0]
+    return set(re.findall(r"(?m)^\|\s*`([a-z0-9_]+)`\s*\|", section))
 
 
 def main() -> int:
