@@ -469,9 +469,9 @@ class PagedServeLoop:
                                   max_blocks=self.max_blocks_per_seq)
         self.cache = self.model.init_paged_decode(num_blocks,
                                                   block_size)[0]
-        # which read the decode program's attention lowers to on the
-        # pool's platform: the Pallas kernel over live blocks, or the
-        # gather of every row's whole view (``read_positions`` follows it)
+        # which read both programs' attention lowers to on the pool's
+        # platform: the Pallas kernels over live blocks, or the gather of
+        # every row's whole view (``read_positions`` follows it)
         pool = self.cache["k"]
         self.kernel_reads = paged_kernel_engages(
             cfg, block_size, pool.dtype, next(iter(pool.devices())).platform)
@@ -505,7 +505,8 @@ class PagedServeLoop:
         # programs' EXPERT_COUNTERS
         self.dispatch_log: List[Dict[str, Any]] = []
         # its prefill twin: one {rid, start, length, bucket} per chunk
-        # (and an MoE model's EXPERT_COUNTERS)
+        # (and an MoE model's EXPERT_COUNTERS); the chunk's serve.prefill
+        # span adds its read_positions
         self.prefill_log: List[Dict[str, int]] = []
 
     @property
@@ -530,6 +531,15 @@ class PagedServeLoop:
         steps = [f + j + 1 for f, m in zip(fills.tolist(), made.tolist())
                  for j in range(m)]
         return copied_positions(steps, self.block_size)
+
+    def _chunk_read_positions(self, end: int, bucket: int) -> int:
+        """K/V positions one prefill chunk's attention read per layer.  The
+        Pallas kernel copies the request's blocks below its fill after
+        the chunk (``end``); the gather reads the request's whole view
+        and the chunk's own ``bucket`` positions beside it."""
+        if not self.kernel_reads:
+            return self.max_blocks_per_seq * self.block_size + bucket
+        return copied_positions([end], self.block_size)
 
     def _fill_of(self, req: Request) -> int:
         """Cached KV positions: the prompt plus one per generated token
@@ -700,7 +710,10 @@ class PagedServeLoop:
                     entry = {"rid": pf.req.rid, "start": pf.start, "length": n,
                              "bucket": pb}
                     self.prefill_log.append(entry)
-                    with TraceAnnotation("serve.prefill", **entry):
+                    with TraceAnnotation(
+                            "serve.prefill", **entry,
+                            read_positions=self._chunk_read_positions(
+                                pf.start + n, pb)):
                         buf = np.zeros((1, pb), np.int32)
                         buf[0, :n] = pf.tokens[pf.start:pf.start + n]
                         t0 = time.perf_counter()

@@ -17,7 +17,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.paged_attention.ops import paged_attention
+from repro.kernels.paged_attention.ops import (paged_attention,
+                                               paged_prefill_attention)
 from repro.kernels.paged_attention.ops import supports as paged_kernel_supports
 from repro.models.config import ModelConfig
 from repro.models.common import (ParamBuilder, _repeat_kv, apply_mrope,
@@ -475,6 +476,89 @@ def _paged_attend(cfg: ModelConfig, pool: jax.Array, tables: jax.Array,
         q, kc, vc, l, tpu=kernel, default=gather)
 
 
+def _paged_chunk_attend(cfg: ModelConfig, pool: jax.Array,
+                        tables: jax.Array, start: jax.Array,
+                        length: jax.Array, Cb: int, interpret: bool):
+    """The paged prefill's attention and KV append for one chunk (``Cb``
+    queries at positions ``start ..``, ``length`` of them real),
+    ``attend(q, k, v, kc, vc, l) -> (a, kc, vc)`` over layer ``l`` of the
+    stacked pools ``(L, NB, BS, C)`` (``pool`` is one of them: its block
+    size and dtype choose the path, as in :func:`_paged_attend`).
+
+    The gather materializes the request's whole ``(1, W*BS, C)`` view,
+    appends the chunk's own K/V to it and runs a dense attention masked
+    to the past below ``start`` and causally within the chunk; then it
+    scatters the chunk's K/V into the pool.  The kernel
+    (``repro.kernels.paged_attention``) scatters first, and then reads
+    from the pool only the blocks below each query tile's diagonal and
+    the request's fill.  Both write the same pool and give every real
+    query the same attention, to rounding."""
+    B = 1
+    W = tables.shape[-1]
+    BS = pool.shape[2]
+    S_past = W * BS
+    tab_b = jnp.broadcast_to(jnp.asarray(tables, jnp.int32)[None, :],
+                             (1, W))
+    # key validity: past pool positions are real iff below the fill at
+    # chunk start; chunk positions attend causally within the chunk
+    past_ok = jnp.arange(S_past)[None, :] < start            # (1, S_past)
+    tri = (jnp.arange(Cb)[:, None] >= jnp.arange(Cb)[None, :])
+    mask = jnp.concatenate(
+        [jnp.broadcast_to(past_ok, (Cb, S_past)), tri], axis=1)
+    mask = mask[None, None]                                  # (1,1,Cb,S)
+    scale = 1.0 / math.sqrt(cfg.head_dim)
+    chunk_pos = start + jnp.arange(Cb, dtype=jnp.int32)      # (Cb,)
+
+    def append(k, v, kc, vc, l):
+        # scatter the chunk's ROTATED keys (decode appends rotated keys
+        # too) into the request's blocks; positions >= length are pad and
+        # dropped.  Each chunk position is its own "row" of the scatter.
+        with jax.named_scope("kv_append"):
+            write_ok = jnp.arange(Cb) < length
+            kc = scatter_kv_paged(
+                kc, l, k.reshape(Cb, 1, cfg.kv_dim), chunk_pos, write_ok,
+                jnp.broadcast_to(tables, (Cb, W)))
+            vc = scatter_kv_paged(
+                vc, l, v.reshape(Cb, 1, cfg.kv_dim), chunk_pos, write_ok,
+                jnp.broadcast_to(tables, (Cb, W)))
+        return kc, vc
+
+    def gather(q, k, v, kc, vc, l):
+        with jax.named_scope("kv_gather"):
+            past_k = gather_kv_paged(kc, l, tab_b)  # (1, S_past, C)
+            past_v = gather_kv_paged(vc, l, tab_b)
+        with jax.named_scope("attention"):
+            keys = jnp.concatenate(
+                [past_k.reshape(B, S_past, cfg.num_kv_heads, cfg.head_dim
+                                ).astype(q.dtype), k], axis=1)
+            vals = jnp.concatenate(
+                [past_v.reshape(B, S_past, cfg.num_kv_heads, cfg.head_dim
+                                ).astype(q.dtype), v], axis=1)
+            groups = q.shape[2] // keys.shape[2]
+            kk = _repeat_kv(keys, groups)
+            vv = _repeat_kv(vals, groups)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(mask, s, -1e30)
+            probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+            a = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
+        return (a,) + append(k, v, kc, vc, l)
+
+    def kernel(q, k, v, kc, vc, l):
+        kc, vc = append(k, v, kc, vc, l)
+        with jax.named_scope("attention"):
+            a = paged_prefill_attention(q[0], kc, vc, l, tables, start,
+                                        length, interpret=interpret)
+        return a[None], kc, vc
+
+    if interpret:
+        return kernel
+    if not paged_kernel_engages(cfg, BS, pool.dtype):
+        return gather
+    return lambda *args: jax.lax.platform_dependent(
+        *args, tpu=kernel, default=gather)
+
+
 def paged_decode_step(params: Tree, cfg: ModelConfig,
                       inputs: Dict[str, jax.Array], cache: Tree, *,
                       tables: jax.Array, lengths: jax.Array,
@@ -597,7 +681,8 @@ def fused_paged_decode_steps(params: Tree, cfg: ModelConfig,
 def prefill_paged_chunk(params: Tree, cfg: ModelConfig,
                         inputs: Dict[str, jax.Array], cache: Tree, *,
                         tables: jax.Array, start: jax.Array,
-                        length: jax.Array) -> Tuple[jax.Array, ...]:
+                        length: jax.Array, kernel_interpret: bool = False
+                        ) -> Tuple[jax.Array, ...]:
     """Process ONE chunk of one request's prompt through the paged cache.
 
     This is what makes prefill schedulable: instead of one monolithic
@@ -612,6 +697,11 @@ def prefill_paged_chunk(params: Tree, cfg: ModelConfig,
     written).  ``length``/``start`` are traced scalars, so one compile
     serves every chunk of a given padded width ``Cb`` — the
     one-compile-per-bucket guarantee carries over from dense prefill.
+    How the attention reads the prefix is :func:`_paged_chunk_attend`'s
+    choice: on a TPU the Pallas kernel reads the request's live blocks,
+    elsewhere a gather of its whole view feeds a dense masked attention;
+    ``kernel_interpret=True`` runs the kernel in interpret mode, on any
+    platform and shape.
 
     Each stage runs in a named scope (``embed``, ``qkv``, ``kv_gather``,
     ``attention``, ``attn_out``, ``mlp``, ``kv_append``, ``head``), as in
@@ -634,20 +724,8 @@ def prefill_paged_chunk(params: Tree, cfg: ModelConfig,
         x, positions, pos3d = _embed_inputs(
             cfg, params, dict(inputs, positions=positions))
     B = x.shape[0]
-    W = tables.shape[-1]
-    BS = cache["k"].shape[2]
-    S_past = W * BS
-    tab_b = jnp.broadcast_to(jnp.asarray(tables, jnp.int32)[None, :],
-                             (1, W))
-    # key validity: past pool positions are real iff below the fill at
-    # chunk start; chunk positions attend causally within the chunk
-    past_ok = jnp.arange(S_past)[None, :] < start            # (1, S_past)
-    tri = (jnp.arange(Cb)[:, None] >= jnp.arange(Cb)[None, :])
-    mask = jnp.concatenate(
-        [jnp.broadcast_to(past_ok, (Cb, S_past)), tri], axis=1)
-    mask = mask[None, None]                                  # (1,1,Cb,S)
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-    chunk_pos = start + jnp.arange(Cb, dtype=jnp.int32)      # (Cb,)
+    attend = _paged_chunk_attend(cfg, cache["k"], tables, start, length,
+                                 Cb, kernel_interpret)
 
     def body(carry, layer):
         x, kc, vc = carry                       # kc/vc: (L, NB, BS, C)
@@ -656,24 +734,7 @@ def prefill_paged_chunk(params: Tree, cfg: ModelConfig,
             h = rms_norm(x, lp["ln1"])
             q, k, v = _attn_qkv(lp, cfg, h)
             q, k = _position_rotate(cfg, q, k, positions, pos3d)
-        with jax.named_scope("kv_gather"):
-            past_k = gather_kv_paged(kc, l, tab_b)  # (1, S_past, C)
-            past_v = gather_kv_paged(vc, l, tab_b)
-        with jax.named_scope("attention"):
-            keys = jnp.concatenate(
-                [past_k.reshape(B, S_past, cfg.num_kv_heads, cfg.head_dim
-                                ).astype(q.dtype), k], axis=1)
-            vals = jnp.concatenate(
-                [past_v.reshape(B, S_past, cfg.num_kv_heads, cfg.head_dim
-                                ).astype(q.dtype), v], axis=1)
-            groups = q.shape[2] // keys.shape[2]
-            kk = _repeat_kv(keys, groups)
-            vv = _repeat_kv(vals, groups)
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask, s, -1e30)
-            probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-            a = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
+        a, kc, vc = attend(q, k, v, kc, vc, l)
         with jax.named_scope("attn_out"):
             a = a.reshape(B, Cb, cfg.q_dim)
             x = x + jnp.einsum("bsq,qd->bsd", a, lp["attn"]["wo"])
@@ -690,17 +751,6 @@ def prefill_paged_chunk(params: Tree, cfg: ModelConfig,
                 out = mlp_gelu(h, lp["mlp"]["wi"], lp["mlp"]["bi"],
                                lp["mlp"]["wo"], lp["mlp"]["bo"])
             x = x + out
-        # scatter the chunk's ROTATED keys (decode appends rotated keys
-        # too) into the request's blocks; positions >= length are pad and
-        # dropped.  Each chunk position is its own "row" of the scatter.
-        with jax.named_scope("kv_append"):
-            write_ok = jnp.arange(Cb) < length
-            kc = scatter_kv_paged(
-                kc, l, k.reshape(Cb, 1, cfg.kv_dim), chunk_pos, write_ok,
-                jnp.broadcast_to(tables, (Cb, W)))
-            vc = scatter_kv_paged(
-                vc, l, v.reshape(Cb, 1, cfg.kv_dim), chunk_pos, write_ok,
-                jnp.broadcast_to(tables, (Cb, W)))
         return (x, kc, vc), counted
 
     (x, ks, vs), counted = jax.lax.scan(

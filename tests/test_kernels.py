@@ -17,8 +17,11 @@ from repro.kernels.flash_attention.ops import mha
 from repro.kernels.linear_scan.ops import ssd, wkv
 from repro.kernels.linear_scan.ref import linear_attention_ref
 from repro.kernels.paged_attention.ops import (copied_positions,
-                                               paged_attention, supports)
-from repro.kernels.paged_attention.ref import paged_attention_ref
+                                               paged_attention,
+                                               paged_prefill_attention,
+                                               supports)
+from repro.kernels.paged_attention.ref import (paged_attention_ref,
+                                               paged_prefill_attention_ref)
 
 RNG = np.random.default_rng(0)
 
@@ -251,3 +254,69 @@ def test_paged_attention_shape_test_and_copy_count():
     assert copied_positions([0, 1, 15, 16, 17, 96], 16) == (
         0 + 16 + 16 + 16 + 32 + 96)
     assert copied_positions([], 16) == 0
+
+
+# ------------------------------------------------- paged prefill attention
+CHUNK = 512
+
+
+@pytest.mark.parametrize("length", [1, 300, CHUNK])
+@pytest.mark.parametrize("start", [0, 17, 500, 1040])
+@pytest.mark.parametrize("heads,kv", [(16, 2), (64, 4), (8, 8)],
+                         ids=["gqa8", "gqa16", "gqa1"])
+def test_paged_prefill_attention_matches_gather_oracle(heads, kv, start,
+                                                       length):
+    """A 512-query chunk at ``start`` holding ``length`` real queries,
+    against the middle layer of a layer-stacked pool that already holds
+    its keys, through a table whose blocks lie scattered over the pool:
+    each real query matches the gather + dense causal mask oracle.
+    qwen2.5-3b's GQA (16 heads on 2, hd 128), qwen3-moe-235b-a22b's (64
+    on 4) and one query head per KV head.  The kernel gets a pool that is
+    NaN everywhere but at the request's positions below ``start +
+    length`` in the layer it reads, so a copy, score or value of any
+    other position, layer or block would show; the oracle gets the clean
+    pool.  Pad queries get finite rows."""
+    hd, BS, L, layer = 128, 16, 3, 1
+    W = -(-(1040 + CHUNK) // BS) + 3
+    end = start + length
+    rng = np.random.default_rng(heads + start + length)
+    nb = W + 9
+    q = jnp.asarray(rng.normal(size=(CHUNK, heads, hd)), jnp.bfloat16)
+    clean = [rng.normal(size=(L, nb, BS, kv * hd)).astype(np.float32)
+             for _ in range(2)]
+    need = -(-end // BS)
+    table = np.full((W,), -1, np.int32)
+    table[:need] = rng.permutation(nb)[:need]
+    poisoned = [np.full_like(p, np.nan) for p in clean]
+    for pos in range(end):
+        blk, off = table[pos // BS], pos % BS
+        for dirty, p in zip(poisoned, clean):
+            dirty[layer, blk, off] = p[layer, blk, off]
+    args = (jnp.int32(layer), jnp.asarray(table), jnp.int32(start),
+            jnp.int32(length))
+    out = paged_prefill_attention(
+        q, *(jnp.asarray(p, jnp.bfloat16) for p in poisoned), *args,
+        interpret=True)
+    ref = paged_prefill_attention_ref(
+        q, *(jnp.asarray(p, jnp.bfloat16) for p in clean), *args)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    out = np.asarray(out, np.float32)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out[:length],
+                               np.asarray(ref, np.float32)[:length],
+                               **_tol(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("start,length", [(0, 1), (0, 512), (17, 300),
+                                          (500, 12), (1040, 512)])
+def test_paged_prefill_copy_count_and_shape_test(start, length):
+    """A chunk's attention copies, per layer, the request's blocks below
+    ``start + length``, whole: what ``copied_positions`` counts for the
+    serve loop's ``read_positions``.  The kernel takes no head of 96
+    lanes (phi3-mini's), so such a model keeps the gather."""
+    BS = 16
+    blocks = len(range(0, start + length, BS))
+    assert copied_positions([start + length], BS) == blocks * BS
+    assert blocks * BS - BS < start + length <= blocks * BS
+    assert supports(128, BS, jnp.bfloat16)
+    assert not supports(96, BS, jnp.bfloat16)

@@ -36,7 +36,8 @@ import pytest
 from repro.configs import get_config, get_smoke_config
 from repro.launch.serve import (PagedServeLoop, Request, ServeLoop,
                                 bucket_length, plan_prefill_chunks)
-from repro.launch.steps import make_paged_serve_step
+from repro.launch import serve as serve_module
+from repro.launch.steps import make_paged_prefill_step, make_paged_serve_step
 from repro.models import get_model
 from repro.models.transformer import paged_kernel_engages
 from repro.serve_mem import BlockPool, BlockTables, make_mixed_trace
@@ -534,38 +535,72 @@ def test_mixed_trace_deterministic_and_mixed():
 
 
 def _kernel_loop(cfg, **kw):
-    """A paged loop whose decode program takes the Pallas kernel, in
+    """A paged loop whose two programs take the Pallas kernels, in
     interpret mode: the CPU's stand-in for the TPU lowering."""
     loop = PagedServeLoop(cfg, **kw)
     model = dataclasses.replace(
         loop.model, fused_paged_decode=functools.partial(
-            loop.model.fused_paged_decode, kernel_interpret=True))
+            loop.model.fused_paged_decode, kernel_interpret=True),
+        paged_prefill_chunk=functools.partial(
+            loop.model.paged_prefill_chunk, kernel_interpret=True))
     loop._decode = jax.jit(make_paged_serve_step(model, loop.decode_steps),
                            donate_argnums=(2,))
+    loop._prefill_step = jax.jit(make_paged_prefill_step(model),
+                                 donate_argnums=(2,))
     loop.kernel_reads = True
     return loop
 
 
-def test_prefill_and_dispatch_logs_count_the_work(cfg):
+def _prefill_reads(monkeypatch):
+    """Record the ``read_positions`` of every ``serve.prefill`` span the
+    loop opens, in order: a stand-in for the profiler's annotation."""
+    reads = []
+
+    class Span:
+        def __init__(self, name, **meta):
+            if name == "serve.prefill":
+                reads.append(meta["read_positions"])
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **meta):
+            pass
+
+    monkeypatch.setattr(serve_module, "TraceAnnotation", Span)
+    return reads
+
+
+def test_prefill_and_dispatch_logs_count_the_work(cfg, monkeypatch):
     """``prefill_log`` holds one {rid, start, length, bucket} per chunk,
     tiling each admission's tokens; its padding equals an independent
     ``bucket_length`` count over the planned chunks.  Each
     ``dispatch_log`` entry's ``fills``/``made`` are its rows' cached
-    positions and tokens, and ``read_positions`` what the program's
-    attention reads: on the gather path (the CPU's) every row's whole
-    view each step; on the kernel path each running row's blocks below
-    its length, each step it runs — the same tokens, fewer positions."""
+    positions and tokens.  ``read_positions`` (of each dispatch's entry,
+    and of each chunk's ``serve.prefill`` span) is what the program's
+    attention reads: on the gather path (the CPU's) a chunk's whole view
+    and its own bucket per layer, and every row's whole view each decode
+    step; on the kernel path a chunk's blocks below its fill, and each
+    running row's blocks below its length, each step it runs — the same
+    tokens, fewer positions."""
     kw = dict(num_blocks=64, block_size=BLOCK_SIZE, max_context=MAX_LEN,
               concurrency=8, decode_steps=2, prefill_chunk=16,
               scheduler="static")
     loop = PagedServeLoop(cfg, **kw)
     assert not loop.kernel_reads
     reqs = make_requests(5, lo=4, hi=40, max_new=5)
+    reads = _prefill_reads(monkeypatch)
     loop.run(reqs)
+    assert len(reads) == len(loop.prefill_log)
     by_rid = {}
-    for e in loop.prefill_log:
+    W = MAX_LEN // BLOCK_SIZE
+    for e, read in zip(loop.prefill_log, reads):
         assert set(e) == {"rid", "start", "length", "bucket"}
         assert e["bucket"] == bucket_length(e["length"], 16)
+        assert read == W * BLOCK_SIZE + e["bucket"]
         assert e["start"] == sum(c["length"] for c in by_rid.get(e["rid"], []))
         by_rid.setdefault(e["rid"], []).append(e)
     assert {r: sum(c["length"] for c in cs) for r, cs in by_rid.items()} == {
@@ -574,7 +609,6 @@ def test_prefill_and_dispatch_logs_count_the_work(cfg):
               for n in plan_prefill_chunks("static", int(r.prompt.size), max_chunk=16))
     assert sum(e["bucket"] - e["length"] for e in loop.prefill_log) == pad
 
-    W = MAX_LEN // BLOCK_SIZE
     for d in loop.dispatch_log:
         assert len(d["fills"]) == len(d["made"]) == d["rows"]
         assert sum(d["made"]) == d["tokens"]
@@ -587,8 +621,15 @@ def test_prefill_and_dispatch_logs_count_the_work(cfg):
 
     kernel = _kernel_loop(cfg, **kw)
     kreqs = make_requests(5, lo=4, hi=40, max_new=5)
+    gather_reads = list(reads)
+    reads.clear()
     kernel.run(kreqs)
     assert [r.generated for r in kreqs] == [r.generated for r in reqs]
+    assert kernel.prefill_log == loop.prefill_log
+    for e, read, g in zip(kernel.prefill_log, reads, gather_reads):
+        blocks = -(-(e["start"] + e["length"]) // BLOCK_SIZE)
+        assert read == blocks * BLOCK_SIZE
+        assert read < g
     assert len(kernel.dispatch_log) == len(loop.dispatch_log)
     for d, g in zip(kernel.dispatch_log, loop.dispatch_log):
         assert (d["fills"], d["made"]) == (g["fills"], g["made"])
@@ -633,6 +674,63 @@ def test_fused_paged_decode_kernel_matches_gather(cfg):
     for got, want in zip(out[True], out[False]):
         np.testing.assert_array_equal(got, want)
     assert (out[True][0][:2, :2] >= 0).all() and (out[True][0][3:] == -1).all()
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "qwen3-moe-235b-a22b"],
+                         ids=["dense", "moe"])
+def test_prefill_chunk_kernel_matches_gather(arch):
+    """A 90-token prompt in three chunks (32, 32 and 26 in a 32 bucket)
+    through ``prefill_paged_chunk`` with the kernel forced in interpret
+    mode, and through the gather path, from the same empty pool and a
+    table scattered over it: each chunk's last-position logits agree to
+    rounding and pick the same token, the written pool blocks agree to
+    rounding, nothing is written past the prompt or outside its blocks,
+    and an MoE model's expert counters are equal."""
+    cfg = get_smoke_config(arch)
+    model = get_model(cfg)
+    params, _ = model.init(jax.random.PRNGKey(1), jnp.bfloat16)
+    W, NB, Cb, P = 12, 40, 32, 90
+    rng = np.random.default_rng(0)
+    table = rng.permutation(NB)[:W].astype(np.int32)
+    prompt = rng.integers(0, cfg.vocab_size, P)
+    runs = {}
+    for interpret in (False, True):
+        pool, _ = model.init_paged_decode(NB, BLOCK_SIZE)
+        step = jax.jit(functools.partial(model.paged_prefill_chunk,
+                                         kernel_interpret=interpret))
+        start, logits, counted = 0, [], []
+        for n in (32, 32, 26):
+            buf = np.zeros((1, Cb), np.int32)
+            buf[0, :n] = prompt[start:start + n]
+            out = step(params, {"tokens": jnp.asarray(buf)}, pool,
+                       tables=jnp.asarray(table), start=jnp.int32(start),
+                       length=jnp.int32(n))
+            pool = out[1]
+            logits.append(np.asarray(out[0], np.float32))
+            counted.append([int(c) for c in out[2:]])
+            start += n
+        runs[interpret] = (logits, pool, counted)
+    (g_logits, g_pool, g_counted), (k_logits, k_pool, k_counted) = (
+        runs[False], runs[True])
+    for got, want in zip(k_logits, g_logits):
+        np.testing.assert_allclose(got, want,
+                                   atol=0.03 * np.abs(want).max())
+        assert got.argmax() == want.argmax()
+    assert k_counted == g_counted and len(k_counted[0]) == (
+        2 if cfg.is_moe else 0)
+    held = table[:-(-P // BLOCK_SIZE)]
+    for name in ("k", "v"):
+        got = np.asarray(k_pool[name], np.float32)
+        want = np.asarray(g_pool[name], np.float32)
+        np.testing.assert_allclose(got, want,
+                                   atol=0.02 * np.abs(want).max())
+        written = np.zeros(got.shape[1:3], bool)       # (NB, BS)
+        for pos in range(P):
+            written[table[pos // BLOCK_SIZE], pos % BLOCK_SIZE] = True
+        for pool in (got, want):
+            assert np.abs(pool[:, written]).min(axis=-1).max() > 0
+            assert not pool[:, ~written].any()
+        assert written[held].sum() == P
 
 
 def test_paged_kernel_engages_by_platform_and_shape():

@@ -181,19 +181,48 @@ def test_paged_decode_reads_the_pool_through_the_kernel(one_chip, qwen_cell):
     _assert_pool_updated_in_place(compiled, pool)
 
 
-def test_paged_prefill_chunk_updates_the_cell_pool_in_place(one_chip,
-                                                            qwen_cell):
-    """At the benchmark cell's shapes, a 512-token prefill chunk fits the
-    chip and writes the stacked pool in place: no copy of it and no one
-    layer's whole pool."""
+@pytest.fixture(scope="module")
+def qwen_cell_prefill(one_chip, qwen_cell):
+    """The cell's 512-token prefill chunk program, compiled for a v5e."""
     model, params, pool = qwen_cell
     W = CELL_MAX_CONTEXT // BLOCK_SIZE
     i32 = jnp.int32
     step = jax.jit(make_paged_prefill_step(model), donate_argnums=(2,))
-    compiled = step.lower(
+    return step.lower(
         params, {"tokens": _sds(one_chip, (1, PREFILL_CHUNK), i32)}, pool,
         _sds(one_chip, (W,), i32), _sds(one_chip, (), i32),
         _sds(one_chip, (), i32)).compile()
+
+
+def test_paged_prefill_chunk_updates_the_cell_pool_in_place(
+        qwen_cell, qwen_cell_prefill):
+    """At the benchmark cell's shapes, a 512-token prefill chunk fits the
+    chip and writes the stacked pool in place: no copy of it and no one
+    layer's whole pool."""
+    _, _, pool = qwen_cell
+    compiled = qwen_cell_prefill
+    assert compiled.memory_analysis().peak_memory_in_bytes < V5E_HBM_BYTES
+    _assert_pool_updated_in_place(compiled, pool)
+
+
+def test_paged_prefill_chunk_reads_the_pool_through_the_kernel(
+        qwen_cell, qwen_cell_prefill):
+    """At the benchmark cell's shapes, the prefill chunk program lowered
+    for a v5e holds the Pallas chunk-attention kernel, reading the
+    stacked pool, and neither the (1, 16, 512, 4704) f32 score tensor of
+    the dense masked attention nor the request's gathered (1, 4192, ...)
+    view; it fits the chip and updates the pool in place."""
+    _, _, pool = qwen_cell
+    compiled = qwen_cell_prefill
+    W = CELL_MAX_CONTEXT // BLOCK_SIZE
+    hlo = compiled.as_text()
+    pages = f"bf16[{','.join(map(str, pool['k'].shape))}]"
+    assert any("tpu_custom_call" in line and pages in line
+               for line in hlo.splitlines())
+    scores = f"f32[1,16,{PREFILL_CHUNK},{W * BLOCK_SIZE + PREFILL_CHUNK}]"
+    assert scores not in hlo
+    assert f"[1,{W * BLOCK_SIZE}," not in hlo
+    # the pool is donated: its output aliases its argument
     assert compiled.memory_analysis().peak_memory_in_bytes < V5E_HBM_BYTES
     _assert_pool_updated_in_place(compiled, pool)
 
@@ -218,10 +247,10 @@ def moe_share(one_chip):
 @pytest.mark.parametrize("program", ["serve_step", "prefill_chunk"])
 def test_moe_share_programs_fit_one_v5e(one_chip, moe_share, program):
     """Both serving programs of the MoE cell compile for a v5e with 256
-    MiB to spare; the decode program reads the pool through the Pallas
-    paged-attention kernel (GQA 16: 64 query heads on 4), the expert
-    layer is the compiler's grouped matmul (``ragged-dot``), and both
-    update the stacked pool in place."""
+    MiB to spare; both read the pool through a Pallas paged-attention
+    kernel (GQA 16: 64 query heads on 4), the expert layer is the
+    compiler's grouped matmul (``ragged-dot``), and both update the
+    stacked pool in place."""
     model, params, pool, s = moe_share
     C, W = s["concurrency"], s["max_context"] // s["block_size"]
     i32 = jnp.int32
@@ -243,10 +272,9 @@ def test_moe_share_programs_fit_one_v5e(one_chip, moe_share, program):
     assert peak < V5E_HBM_BYTES - MOE_SPARE_BYTES
     hlo = compiled.as_text()
     assert "ragged-dot" in hlo
-    if program == "serve_step":
-        pages = f"bf16[{','.join(map(str, pool['k'].shape))}]"
-        assert any("tpu_custom_call" in line and pages in line
-                   for line in hlo.splitlines())
+    pages = f"bf16[{','.join(map(str, pool['k'].shape))}]"
+    assert any("tpu_custom_call" in line and pages in line
+               for line in hlo.splitlines())
     _assert_pool_updated_in_place(compiled, pool)
 
 
