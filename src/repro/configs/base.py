@@ -53,6 +53,7 @@ def _ensure_loaded() -> None:
     from repro.configs import (  # noqa: F401
         grok_1_314b, qwen3_moe_235b_a22b, rwkv6_3b, qwen2_5_3b, minicpm_2b,
         qwen3_32b, phi3_mini_3_8b, musicgen_large, zamba2_2_7b, qwen2_vl_7b,
+        deepseek_v3,
     )
 
 

@@ -37,6 +37,14 @@ diagonal.  Only groups that reach the diagonal apply the mask; a tile of
 pad queries alone (at or past ``length``) copies nothing and writes
 zeros.
 
+Latent mode (MLA, :func:`mla_decode_attention`, :func:`mla_chunk_attention`):
+one stacked pool ``(L, NB, BS, W)`` of latent rows ``[c_kv | k_pe | 0]``
+that every query head reads, as one KV head of ``H`` grouped queries.
+The ring copies one tile per page group; scores take the whole ``W``-wide
+row against the queries ``[q_lat | q_pe | 0]``, and the values are the
+row's first ``value_dim`` lanes, so a position is read once for keys and
+values alike.
+
 Oracle: ref.py (the block-table gather and the dense masked attention).
 """
 
@@ -50,7 +58,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_decode_attention", "paged_chunk_attention"]
+__all__ = ["paged_decode_attention", "paged_chunk_attention",
+           "mla_decode_attention", "mla_chunk_attention"]
 
 NEG_INF = -1e30
 # tiles in the copy ring: the copies of DEPTH - 1 groups are in flight
@@ -62,13 +71,15 @@ DEPTH = 4
 CHUNK_VMEM = 64 * 2 ** 20
 
 
-def _stream_pages(tables_ref, base, n_pages, layer, k_hbm, v_hbm, k_buf,
-                  v_buf, sem, whole_ref, compute, *, pages_per_copy: int,
-                  run_pages: int, pages_per_row: int, num_blocks: int):
+def _stream_pages(tables_ref, base, n_pages, layer, pools, sem, whole_ref,
+                  compute, *, pages_per_copy: int, run_pages: int,
+                  pages_per_row: int, num_blocks: int):
     """Copy the first ``n_pages`` pages of the table at ``tables_ref[base
     :]`` (layer ``layer`` of the stacked pools) through the ring,
     ``pages_per_copy`` at a time, and call ``compute(g, slot)`` on group
-    ``g`` once its copies have landed in ring tile ``slot``."""
+    ``g`` once its copies have landed in ring tile ``slot``.  ``pools``
+    pairs each stacked pool in HBM with its ring of tiles (k and v; or
+    the one latent pool)."""
     n_groups = (n_pages + pages_per_copy - 1) // pages_per_copy
     runs = pages_per_copy // run_pages
 
@@ -78,10 +89,9 @@ def _stream_pages(tables_ref, base, n_pages, layer, k_hbm, v_hbm, k_buf,
         return tables_ref[base + page]
 
     def copies(src, dst, slot):
-        return (pltpu.make_async_copy(k_hbm.at[layer, src],
-                                      k_buf.at[slot, dst], sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[layer, src],
-                                      v_buf.at[slot, dst], sem.at[1, slot]))
+        return tuple(pltpu.make_async_copy(hbm.at[layer, src],
+                                           buf.at[slot, dst], sem.at[i, slot])
+                     for i, (hbm, buf) in enumerate(pools))
 
     def page_by_page(g, slot, lo, hi, action):
         """One copy per live page ``lo <= i < hi`` of group ``g``."""
@@ -183,18 +193,35 @@ def _online_softmax_step(h, q, k, v, s_ok, v_ok, m_ref, l_ref, acc_ref,
     m_ref[h] = m_new
 
 
-def _head_tiles(k_buf, v_buf, slot, h, span: int, head_dim: int):
-    """KV head ``h``'s lane slice of ring tile ``slot``: ``(span, hd)``."""
+def _head_tiles(bufs, slot, h, span: int, head_dim: int, latent: int):
+    """Keys and values of KV head ``h`` in ring tile ``slot``: its lane
+    slice ``(span, hd)`` of the k and v tiles; or, in latent mode (``latent``
+    the value width), the whole row ``(span, W)`` and its first
+    ``latent`` lanes."""
+    if latent:
+        (buf,) = bufs
+        return (buf[slot].reshape(span, buf.shape[-1]),
+                buf[slot, :, :, :latent].reshape(span, latent))
+    k_buf, v_buf = bufs
     cols = pl.ds(h * head_dim, head_dim)
     return (k_buf[slot, :, :, cols].reshape(span, head_dim),
             v_buf[slot, :, :, cols].reshape(span, head_dim))
 
 
-def _kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sem, whole_ref, m_ref, l_ref, acc_ref, *,
+def _split_refs(refs, n_pools: int):
+    """The refs after the queries: (pools in HBM, output, ring tiles,
+    then the other scratch)."""
+    return (refs[:n_pools], refs[n_pools],
+            refs[n_pools + 1:2 * n_pools + 1], refs[2 * n_pools + 1:])
+
+
+def _kernel(tables_ref, lengths_ref, layer_ref, q_ref, *refs,
             block_size: int, pages_per_copy: int, run_pages: int,
             pages_per_row: int, num_blocks: int, kv_heads: int,
-            head_dim: int, scale: float):
+            head_dim: int, scale: float, latent: int = 0):
+    hbm, o_ref, bufs, (sem, whole_ref, m_ref, l_ref, acc_ref) = _split_refs(
+        refs, 1 if latent else 2)
+    value_dim = latent or head_dim
     b = pl.program_id(0)
     length = lengths_ref[b]
     n_pages = jnp.minimum((length + block_size - 1) // block_size,
@@ -209,16 +236,16 @@ def _kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
         g_len = length - g * span
         groups = q_ref.shape[2]
         live_s = jax.lax.broadcasted_iota(jnp.int32, (groups, span), 1) < g_len
-        live_v = jax.lax.broadcasted_iota(jnp.int32, (span, head_dim), 0) < g_len
+        live_v = jax.lax.broadcasted_iota(jnp.int32, (span, value_dim), 0) < g_len
         for h in range(kv_heads):
-            k, v = _head_tiles(k_buf, v_buf, slot, h, span, head_dim)
+            k, v = _head_tiles(bufs, slot, h, span, head_dim, latent)
             # a page's tail past the length holds whatever the tile held
             # before: it is masked, and its values zeroed
             _online_softmax_step(h, q_ref[0, h], k, v, live_s, live_v,
                                  m_ref, l_ref, acc_ref, scale)
 
     _stream_pages(tables_ref, b * pages_per_row, n_pages, layer_ref[0],
-                  k_hbm, v_hbm, k_buf, v_buf, sem, whole_ref, compute,
+                  tuple(zip(hbm, bufs)), sem, whole_ref, compute,
                   pages_per_copy=pages_per_copy, run_pages=run_pages,
                   pages_per_row=pages_per_row, num_blocks=num_blocks)
     for h in range(kv_heads):
@@ -227,12 +254,13 @@ def _kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def _ring_scratch(pages_per_copy: int, run_pages: int, BS: int, C: int,
-                  dtype) -> list:
-    """The copy ring's k/v tiles, its DMA semaphores and its run flags."""
-    return [pltpu.VMEM((DEPTH, pages_per_copy, BS, C), dtype),
-            pltpu.VMEM((DEPTH, pages_per_copy, BS, C), dtype),
-            pltpu.SemaphoreType.DMA((2, DEPTH)),
-            pltpu.SMEM((DEPTH * (pages_per_copy // run_pages),), jnp.int32)]
+                  dtype, pools: int = 2) -> list:
+    """The copy ring's tiles of each pool (k and v, or the latent one),
+    its DMA semaphores and its run flags."""
+    return ([pltpu.VMEM((DEPTH, pages_per_copy, BS, C), dtype)] * pools
+            + [pltpu.SemaphoreType.DMA((pools, DEPTH)),
+               pltpu.SMEM((DEPTH * (pages_per_copy // run_pages),),
+                          jnp.int32)])
 
 
 @functools.partial(jax.jit, static_argnames=("pages_per_copy", "run_pages",
@@ -287,11 +315,14 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                   jnp.asarray(layer, jnp.int32).reshape(1), q, k_pool, v_pool)
 
 
-def _chunk_kernel(table_ref, scalars_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  k_buf, v_buf, sem, whole_ref, m_ref, l_ref, acc_ref, *,
+def _chunk_kernel(table_ref, scalars_ref, q_ref, *refs,
                   block_size: int, pages_per_copy: int, run_pages: int,
                   pages_per_row: int, num_blocks: int, kv_heads: int,
-                  head_dim: int, q_tile: int, groups: int, scale: float):
+                  head_dim: int, q_tile: int, groups: int, scale: float,
+                  latent: int = 0):
+    hbm, o_ref, bufs, (sem, whole_ref, m_ref, l_ref, acc_ref) = _split_refs(
+        refs, 1 if latent else 2)
+    value_dim = latent or head_dim
     t = pl.program_id(0)
     start, length, layer = scalars_ref[0], scalars_ref[1], scalars_ref[2]
     end = start + length                    # the request's fill after it
@@ -317,9 +348,9 @@ def _chunk_kernel(table_ref, scalars_ref, q_ref, k_hbm, v_hbm, o_ref,
             s_ok = ((key - first) * groups <= row) & (key < end)
             # keys at or past ``last`` were not copied for this tile
             v_ok = g * span + jax.lax.broadcasted_iota(
-                jnp.int32, (span, head_dim), 0) < last
+                jnp.int32, (span, value_dim), 0) < last
         for h in range(kv_heads):
-            k, v = _head_tiles(k_buf, v_buf, slot, h, span, head_dim)
+            k, v = _head_tiles(bufs, slot, h, span, head_dim, latent)
             _online_softmax_step(h, q_ref[h], k, v, s_ok, v_ok, m_ref, l_ref,
                                  acc_ref, scale)
 
@@ -335,8 +366,8 @@ def _chunk_kernel(table_ref, scalars_ref, q_ref, k_hbm, v_hbm, o_ref,
         def _():
             attend(g, slot, False)
 
-    _stream_pages(table_ref, 0, n_pages, layer, k_hbm, v_hbm, k_buf, v_buf,
-                  sem, whole_ref, compute, pages_per_copy=pages_per_copy,
+    _stream_pages(table_ref, 0, n_pages, layer, tuple(zip(hbm, bufs)), sem,
+                  whole_ref, compute, pages_per_copy=pages_per_copy,
                   run_pages=run_pages, pages_per_row=pages_per_row,
                   num_blocks=num_blocks)
     for h in range(kv_heads):
@@ -400,3 +431,109 @@ def paged_chunk_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                          jnp.asarray(length, jnp.int32),
                          jnp.asarray(layer, jnp.int32)])
     return kernel(jnp.asarray(table, jnp.int32), scalars, q, k_pool, v_pool)
+
+
+@functools.partial(jax.jit, static_argnames=("value_dim", "scale",
+                                             "pages_per_copy", "run_pages",
+                                             "interpret"))
+def mla_decode_attention(q: jax.Array, pool: jax.Array, layer: jax.Array,
+                         tables: jax.Array, lengths: jax.Array, *,
+                         value_dim: int, scale: float, pages_per_copy: int,
+                         run_pages: int,
+                         interpret: bool = False) -> jax.Array:
+    """Latent-mode decode: q ``(B, H, W)`` float32, the queries ``[q_lat
+    | q_pe | 0]``; the layer-stacked latent pool ``(L, NB, BS, W)``, read
+    at ``layer``; tables and lengths as in :func:`paged_decode_attention`.
+    Row ``b`` attends its first ``lengths[b]`` positions with softmax
+    scale ``scale``.  Returns ``o_lat (B, H, value_dim)`` float32, the
+    weights' sum of each row's first ``value_dim`` lanes; zeros for a row
+    of length 0."""
+    B, H, W = q.shape
+    _, NB, BS, C = pool.shape
+    assert C == W and value_dim <= W, (q.shape, pool.shape, value_dim)
+    assert pages_per_copy % run_pages == 0, (pages_per_copy, run_pages)
+    body = functools.partial(
+        _kernel, block_size=BS, pages_per_copy=pages_per_copy,
+        run_pages=run_pages, pages_per_row=tables.shape[1], num_blocks=NB,
+        kv_heads=1, head_dim=W, scale=scale, latent=value_dim)
+    spec = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, 1, H, width), lambda b, tables, lengths, layer: (b, 0, 0, 0))
+    kernel = pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[spec(W), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=spec(value_dim),
+            scratch_shapes=_ring_scratch(pages_per_copy, run_pages, BS, C,
+                                         pool.dtype, pools=1) + [
+                pltpu.VMEM((1, H, 1), jnp.float32),
+                pltpu.VMEM((1, H, 1), jnp.float32),
+                pltpu.VMEM((1, H, value_dim), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, 1, H, value_dim), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="mla_decode_attention",
+    )
+    out = kernel(jnp.asarray(tables, jnp.int32).reshape(-1),
+                 jnp.asarray(lengths, jnp.int32),
+                 jnp.asarray(layer, jnp.int32).reshape(1), q[:, None], pool)
+    return out[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("groups", "value_dim", "scale",
+                                             "q_tile", "pages_per_copy",
+                                             "run_pages", "interpret"))
+def mla_chunk_attention(q: jax.Array, pool: jax.Array, layer: jax.Array,
+                        table: jax.Array, start: jax.Array,
+                        length: jax.Array, *, groups: int, value_dim: int,
+                        scale: float, q_tile: int, pages_per_copy: int,
+                        run_pages: int,
+                        interpret: bool = False) -> jax.Array:
+    """Latent-mode prefill chunk: q ``(Cb*G, W)`` with ``G = groups``
+    query heads (row ``i*G + h`` is chunk query ``i``'s head ``h``:
+    ``[q_lat | q_pe | 0]``) against the layer-stacked latent pool ``(L,
+    NB, BS, W)``, which already holds the chunk's rows; ``table``,
+    ``start``, ``length``, ``q_tile``, ``pages_per_copy`` and
+    ``run_pages`` as in :func:`paged_chunk_attention`.  Returns ``o_lat
+    (Cb*G, value_dim)`` in q's dtype; a tile of pad queries alone gets
+    zeros."""
+    R, W = q.shape
+    _, NB, BS, C = pool.shape
+    Cb = R // groups
+    assert C == W and value_dim <= W, (q.shape, pool.shape, value_dim)
+    assert Cb * groups == R and Cb % q_tile == 0, (R, groups, q_tile)
+    assert pages_per_copy % run_pages == 0, (pages_per_copy, run_pages)
+    rows = q_tile * groups
+    body = functools.partial(
+        _chunk_kernel, block_size=BS, pages_per_copy=pages_per_copy,
+        run_pages=run_pages, pages_per_row=table.shape[0], num_blocks=NB,
+        kv_heads=1, head_dim=W, q_tile=q_tile, groups=groups, scale=scale,
+        latent=value_dim)
+    tile = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, rows, width), lambda t, table, scalars: (0, t, 0))
+    kernel = pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(Cb // q_tile,),
+            in_specs=[tile(W), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=tile(value_dim),
+            scratch_shapes=_ring_scratch(pages_per_copy, run_pages, BS, C,
+                                         pool.dtype, pools=1) + [
+                pltpu.VMEM((1, rows, 1), jnp.float32),
+                pltpu.VMEM((1, rows, 1), jnp.float32),
+                pltpu.VMEM((1, rows, value_dim), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((1, R, value_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=CHUNK_VMEM),
+        interpret=interpret,
+        name="mla_chunk_attention",
+    )
+    scalars = jnp.stack([jnp.asarray(start, jnp.int32),
+                         jnp.asarray(length, jnp.int32),
+                         jnp.asarray(layer, jnp.int32)])
+    return kernel(jnp.asarray(table, jnp.int32), scalars, q[None], pool)[0]

@@ -50,3 +50,38 @@ def paged_prefill_attention_ref(q: jax.Array, k_pool: jax.Array,
     s = jnp.where((key <= query) & (key < start + length), s, -1e30)
     out = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v)
     return out.astype(q.dtype)
+
+
+def paged_latent_attention_ref(q: jax.Array, pool: jax.Array,
+                               layer: jax.Array, tables: jax.Array,
+                               lengths: jax.Array, *, value_dim: int,
+                               scale: float) -> jax.Array:
+    """Latent mode: q ``(B, H, W)``; the layer-stacked latent pool ``(L,
+    NB, BS, W)``, read at ``layer``; row ``b`` scores its first
+    ``lengths[b]`` rows whole and sums their first ``value_dim`` lanes,
+    in float32.  Returns ``(B, H, value_dim)`` float32."""
+    rows = gather_kv_paged(pool, layer, tables).astype(jnp.float32)
+    s = jnp.einsum("bhw,btw->bht", q.astype(jnp.float32), rows) * scale
+    live = jnp.arange(rows.shape[1])[None, None, :] < lengths[:, None, None]
+    p = jax.nn.softmax(jnp.where(live, s, -1e30), axis=-1)
+    return jnp.einsum("bht,btc->bhc", p, rows[..., :value_dim])
+
+
+def paged_latent_prefill_attention_ref(q: jax.Array, pool: jax.Array,
+                                       layer: jax.Array, table: jax.Array,
+                                       start: jax.Array, length: jax.Array,
+                                       *, value_dim: int,
+                                       scale: float) -> jax.Array:
+    """Latent mode: q ``(Cb, H, W)`` at positions ``start ..``; the pool
+    holds the chunk's own rows.  Query ``i`` attends the positions ``p <=
+    start + i`` below ``start + length``, in float32.  Returns ``(Cb, H,
+    value_dim)`` in q's dtype."""
+    Cb = q.shape[0]
+    rows = gather_kv_paged(pool, layer, table[None])[0].astype(jnp.float32)
+    s = jnp.einsum("qhw,tw->hqt", q.astype(jnp.float32), rows) * scale
+    key = jnp.arange(rows.shape[0])[None, :]
+    query = start + jnp.arange(Cb)[:, None]
+    s = jnp.where((key <= query) & (key < start + length), s, -1e30)
+    out = jnp.einsum("hqt,tc->qhc", jax.nn.softmax(s, axis=-1),
+                     rows[:, :value_dim])
+    return out.astype(q.dtype)

@@ -4,7 +4,8 @@ Every model family serves through one engine, chosen by what the family
 implements (:func:`engine_for`; the CLI needs no flag for it):
 
 * :class:`PagedServeLoop` serves every attention family (dense, MoE,
-  audio, vision) over a shared KV block pool (``repro.serve_mem``): cache
+  audio, vision; GQA or MLA's latent rows) over a shared KV block pool
+  (``repro.serve_mem``): cache
   MEMORY is the scheduled resource.  A request is admitted when blocks
   for its prompt are free; its prompt prefills in UDS-planned chunks
   (:func:`plan_prefill_chunks`) that interleave with decode dispatches;
@@ -471,8 +472,9 @@ class PagedServeLoop:
                                                   block_size)[0]
         # which read both programs' attention lowers to on the pool's
         # platform: the Pallas kernels over live blocks, or the gather of
-        # every row's whole view (``read_positions`` follows it)
-        pool = self.cache["k"]
+        # every row's whole view (``read_positions`` follows it; for MLA
+        # it counts latent rows)
+        pool = jax.tree.leaves(self.cache)[0]
         self.kernel_reads = paged_kernel_engages(
             cfg, block_size, pool.dtype, next(iter(pool.devices())).platform)
         # one compile per prefill BUCKET (chunks are bucket-padded) and

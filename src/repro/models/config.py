@@ -34,6 +34,23 @@ class ModelConfig:
     experts_held: int = 0
     expert_offset: int = 0
     moe_capacity_factor: float = 1.25
+    # the router: "softmax" keeps each token's top-k by softmax; "sigmoid"
+    # (DeepSeek-V3's noaux_tc) scores each expert by a sigmoid, ranks them
+    # with a learned correction bias, keeps the router_groups_kept best of
+    # router_groups equal groups (a group ranked by its two best biased
+    # scores), takes the top-k inside them, and weights each pick by its
+    # unbiased score, renormalised and times routed_scaling
+    router: str = "softmax"
+    router_groups: int = 1
+    router_groups_kept: int = 1
+    routed_scaling: float = 1.0
+    # SwiGLU experts of width shared_experts * d_ff that every token goes
+    # through beside its routed ones, on every chip alike
+    shared_experts: int = 0
+    # the first first_dense_layers layers are dense SwiGLU of width
+    # dense_d_ff; the experts start after them
+    first_dense_layers: int = 0
+    dense_d_ff: int = 0
     moe_cap_headroom: float = 1.6   # buffer slack for WF2 capacity planning
 
     # --- SSM / linear attention --------------------------------------------
@@ -42,6 +59,27 @@ class ModelConfig:
     conv_kernel: int = 4             # mamba2 depthwise conv width
     scan_chunk: int = 64             # chunk size of the chunked linear scan
 
+    # --- latent attention (MLA, DeepSeek-V2/V3) -----------------------------
+    # kv_lora_rank > 0 selects it: queries go through a q_lora_rank-wide
+    # bottleneck, keys and values come from a kv_lora_rank-wide latent
+    # c_kv and a qk_rope_head_dim-wide rope key shared by every head; the
+    # cache holds only those two (see repro/models/mla.py)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (rope_factor 1: none): the frequencies of
+    # rope_original_max positions stretched by rope_factor between the
+    # yarn_beta_fast and yarn_beta_slow rotations, and the softmax scale
+    # times yarn_mscale(rope_factor, yarn_mscale_all_dim)^2
+    rope_factor: float = 1.0
+    rope_original_max: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 1.0
+
     # --- attention variants --------------------------------------------------
     qk_norm: bool = False            # qwen3
     qkv_bias: bool = False           # qwen2/2.5, qwen2-vl
@@ -49,6 +87,7 @@ class ModelConfig:
     mrope_sections: Optional[Tuple[int, int, int]] = None  # qwen2-vl M-RoPE
     attention: str = "full"          # full | none (rwkv) | hybrid (zamba2)
     positional: str = "rope"         # rope | sinusoidal (musicgen) | none
+    rms_norm_eps: float = 1e-6       # every RMSNorm of the model
 
     # --- MLP variants --------------------------------------------------------
     mlp: str = "swiglu"              # swiglu | gelu (musicgen)
@@ -82,8 +121,18 @@ class ModelConfig:
     optimizer: str = "adamw"
 
     def __post_init__(self):
+        if self.is_mla:      # a query/key head: its nope and rope parts
+            object.__setattr__(self, "head_dim", self.qk_nope_head_dim
+                               + self.qk_rope_head_dim)
         if self.num_heads and self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        if self.router not in ("softmax", "sigmoid") or (
+                self.num_experts % max(self.router_groups, 1)
+                or not 1 <= self.router_groups_kept <= self.router_groups):
+            raise ValueError(
+                f"{self.name}: router {self.router!r} over {self.num_experts} "
+                f"experts in {self.router_groups} groups, "
+                f"{self.router_groups_kept} kept")
         if not (0 <= self.experts_held <= self.num_experts and 0 <= self.expert_offset
                 and self.expert_offset + self.held_experts <= self.num_experts):
             raise ValueError(
@@ -99,6 +148,16 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * (self.head_dim or 0)
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def moe_layers(self) -> int:
+        """Layers after the dense prefix: the expert layers of an MoE
+        config."""
+        return self.num_layers - self.first_dense_layers
 
     @property
     def padded_vocab(self) -> int:
@@ -141,6 +200,14 @@ class ModelConfig:
             return total + L * per_layer
         # attention (dense/moe/hybrid-shared/audio/vlm)
         attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        if self.is_mla:
+            H, r = self.num_heads, self.qk_rope_head_dim
+            attn = (d * self.q_lora_rank + self.q_lora_rank
+                    + self.q_lora_rank * H * self.head_dim
+                    + d * (self.kv_lora_rank + r) + self.kv_lora_rank
+                    + self.kv_lora_rank * H * (self.qk_nope_head_dim
+                                               + self.v_head_dim)
+                    + H * self.v_head_dim * d)
         if self.qkv_bias:
             attn += self.q_dim + 2 * self.kv_dim
         if self.qk_norm:
@@ -164,12 +231,18 @@ class ModelConfig:
             n_shared = 1
             return total + L * m + n_shared * shared
         per_layer = attn + 2 * d           # ln1, ln2
+        if self.first_dense_layers:
+            total += self.first_dense_layers * (per_layer
+                                                + 3 * d * self.dense_d_ff)
         if self.is_moe:
             per_layer += d * self.num_experts              # router
+            if self.router == "sigmoid":
+                per_layer += self.num_experts              # its bias
             per_layer += self.held_experts * 3 * d * f     # expert swiglu
+            per_layer += self.shared_experts * 3 * d * f   # shared swiglu
         else:
             per_layer += mlp
-        return total + L * per_layer
+        return total + self.moe_layers * per_layer
 
     def active_param_count(self) -> int:
         """Active params per token (MoE: at most top-k of the held experts
@@ -180,4 +253,4 @@ class ModelConfig:
         dense_experts = self.held_experts * 3 * d * f
         active_experts = min(self.experts_per_token,
                              self.held_experts) * 3 * d * f
-        return self.param_count() - self.num_layers * (dense_experts - active_experts)
+        return self.param_count() - self.moe_layers * (dense_experts - active_experts)

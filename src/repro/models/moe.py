@@ -31,7 +31,7 @@ from repro.models.config import ModelConfig
 from repro.sharding import constrain
 
 __all__ = ["moe_capacity", "moe_buffer_capacity", "moe_ffn", "moe_held",
-           "router_topk"]
+           "router_topk", "route", "shared_expert"]
 
 
 def moe_capacity(cfg: ModelConfig, seq_len: int) -> int:
@@ -59,6 +59,55 @@ def router_topk(x: jax.Array, w_router: jax.Array, k: int
     return gates, topi.astype(jnp.int32), probs
 
 
+def _sigmoid_group_topk(x: jax.Array, w_router: jax.Array,
+                        bias: jax.Array, cfg: ModelConfig
+                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """DeepSeek-V3's router (``noaux_tc``): sigmoid scores, ranked with
+    the correction ``bias`` added; the experts fall in
+    ``router_groups`` equal groups, each ranked by the sum of its two best
+    biased scores, and only the ``router_groups_kept`` best groups are
+    eligible; the top-k of those by biased score are picked and weighted
+    by their UNbiased scores, renormalised to sum to one, times
+    ``routed_scaling``.  Returns (gates, expert_ids, scores) as
+    :func:`router_topk`."""
+    E, k = cfg.num_experts, cfg.experts_per_token
+    ng, keep = cfg.router_groups, cfg.router_groups_kept
+    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
+                        w_router.astype(jnp.float32))
+    scores = jax.nn.sigmoid(logits)
+    ranked = scores + bias.astype(jnp.float32)
+    grouped = ranked.reshape(ranked.shape[:-1] + (ng, E // ng))
+    group_score = jax.lax.top_k(grouped, min(2, E // ng))[0].sum(axis=-1)
+    _, kept = jax.lax.top_k(group_score, keep)                  # (B,S,keep)
+    eligible = (kept[..., None] == jnp.arange(ng)).any(axis=-2)  # (B,S,ng)
+    eligible = jnp.repeat(eligible, E // ng, axis=-1)
+    _, topi = jax.lax.top_k(jnp.where(eligible, ranked, -jnp.inf), k)
+    top = jnp.take_along_axis(scores, topi, axis=-1)
+    gates = top / (top.sum(axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling
+    return gates, topi.astype(jnp.int32), scores
+
+
+def route(x: jax.Array, w_router: jax.Array, cfg: ModelConfig,
+          bias: Optional[jax.Array] = None
+          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The config's router (``cfg.router``): (gates (B,S,k) f32,
+    expert_ids (B,S,k) int32, per-expert scores (B,S,E) f32).  The
+    sigmoid router needs its correction ``bias (E,)``."""
+    if cfg.router == "sigmoid":
+        return _sigmoid_group_topk(x, w_router, bias, cfg)
+    return router_topk(x, w_router, cfg.experts_per_token)
+
+
+def shared_expert(x: jax.Array, sp) -> jax.Array:
+    """The shared experts: one SwiGLU of width ``shared_experts * d_ff``
+    that every token goes through, ungated."""
+    g = jnp.einsum("bsd,df->bsf", x, sp["wi_gate"])
+    u = jnp.einsum("bsd,df->bsf", x, sp["wi_up"])
+    h = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+         ).astype(x.dtype)
+    return jnp.einsum("bsf,fd->bsd", h, sp["wo"])
+
+
 def moe_ffn(x: jax.Array,
             w_router: jax.Array,
             w_gate: jax.Array,   # (E, D, F)
@@ -66,8 +115,12 @@ def moe_ffn(x: jax.Array,
             w_down: jax.Array,   # (E, F, D)
             cfg: ModelConfig,
             cap_e: Optional[jax.Array] = None,
+            router_bias: Optional[jax.Array] = None,
             ) -> Tuple[jax.Array, jax.Array]:
     """x: (B, S, D) -> (out (B, S, D), expert_load (E,) f32 fraction).
+
+    The router is the config's (:func:`route`; ``router_bias`` is the
+    sigmoid router's correction bias).
 
     ``cap_e``: optional (E,) int32 — UDS-planned per-expert capacity
     (≤ static buffer capacity C); tokens over capacity are dropped
@@ -91,18 +144,19 @@ def moe_ffn(x: jax.Array,
     if (ctx is not None and ctx[0].size > 1
             and not os.environ.get("REPRO_MOE_LOCAL")):  # baseline knob
         return _moe_ffn_shardmap(x, w_router, w_gate, w_up, w_down, cfg,
-                                 cap_e, ctx)
-    return _moe_ffn_local(x, w_router, w_gate, w_up, w_down, cfg, cap_e)
+                                 cap_e, ctx, router_bias)
+    return _moe_ffn_local(x, w_router, w_gate, w_up, w_down, cfg, cap_e,
+                          router_bias)
 
 
-def _moe_ffn_local(x, w_router, w_gate, w_up, w_down, cfg, cap_e
-                   ) -> Tuple[jax.Array, jax.Array]:
+def _moe_ffn_local(x, w_router, w_gate, w_up, w_down, cfg, cap_e,
+                   router_bias=None) -> Tuple[jax.Array, jax.Array]:
     """Single-device reference path (also the shard_map oracle in tests)."""
     B, S, D = x.shape
     E, k = cfg.num_experts, cfg.experts_per_token
     C = moe_buffer_capacity(cfg, S)
 
-    gates, topi, probs = router_topk(x, w_router, k)
+    gates, topi, probs = route(x, w_router, cfg, router_bias)
 
     # position of each slot within its expert, per batch row (so the cumsum
     # never crosses data shards: batch is the data-parallel axis)
@@ -164,12 +218,15 @@ def moe_held(x: jax.Array,
              w_down: jax.Array,   # (G, F, D)
              cfg: ModelConfig,
              valid: Optional[jax.Array] = None,
+             router_bias: Optional[jax.Array] = None,
              ) -> Tuple[jax.Array, jax.Array]:
     """Dropless expert layer over the experts this config holds:
     x (B, S, D) -> (out (B, S, D), sizes (G,) int32).
 
     The router scores all ``num_experts`` in float32 and keeps each
-    token's renormalised top-k (:func:`router_topk`).  The slots whose
+    token's top-k with their gates (:func:`route`: the renormalised
+    softmax top-k, or the sigmoid group-limited router with its
+    correction ``router_bias``).  The slots whose
     expert is held here (``expert_offset`` .. ``+ held_experts - 1``)
     are sorted by local expert, run through the held experts' SwiGLU as
     one grouped matmul (``lax.ragged_dot``), weighted by their gates and
@@ -191,7 +248,7 @@ def moe_held(x: jax.Array,
     k, G = cfg.experts_per_token, cfg.held_experts
     T = B * S
     with jax.named_scope("router"):
-        gates, topi, _ = router_topk(x, w_router, k)
+        gates, topi, _ = route(x, w_router, cfg, router_bias)
         local = topi.reshape(T * k) - cfg.expert_offset
         held = (local >= 0) & (local < G)
         if valid is not None:
@@ -225,8 +282,8 @@ def _axis_tuple(v):
     return (v,) if isinstance(v, str) else tuple(v)
 
 
-def _moe_ffn_shardmap(x, w_router, w_gate, w_up, w_down, cfg, cap_e, ctx
-                      ) -> Tuple[jax.Array, jax.Array]:
+def _moe_ffn_shardmap(x, w_router, w_gate, w_up, w_down, cfg, cap_e, ctx,
+                      router_bias=None) -> Tuple[jax.Array, jax.Array]:
     """Expert-parallel MoE via shard_map.
 
     Layout (from the rule table):
@@ -275,14 +332,14 @@ def _moe_ffn_shardmap(x, w_router, w_gate, w_up, w_down, cfg, cap_e, ctx
     wd_spec = P(expert_axes or None, mlp_axes or None, fsdp_axes or None)
     cap_spec = P(None)
 
-    def local(x_l, router, wg_l, wu_l, wd_l, cap):
+    def local(x_l, router, wg_l, wu_l, wd_l, cap, bias=None):
         # gather the FSDP-sharded dims of this layer's expert weights
         for ax in fsdp_axes:
             wg_l = jax.lax.all_gather(wg_l, ax, axis=1, tiled=True)
             wu_l = jax.lax.all_gather(wu_l, ax, axis=1, tiled=True)
             wd_l = jax.lax.all_gather(wd_l, ax, axis=2, tiled=True)
         Bl = x_l.shape[0]
-        gates, topi, _ = router_topk(x_l, router, k)
+        gates, topi, _ = route(x_l, router, cfg, bias)
         e_flat = topi.reshape(Bl, S * k)
         oh = jax.nn.one_hot(e_flat, E, dtype=jnp.int32)
         pos_flat = jnp.cumsum(oh, axis=1) - 1
@@ -337,12 +394,13 @@ def _moe_ffn_shardmap(x, w_router, w_gate, w_up, w_down, cfg, cap_e, ctx
 
     if cap_e is None:
         cap_e = jnp.full((E,), moe_capacity(cfg, S), jnp.int32)
-    fn = shard_map(
-        local, mesh=mesh,
-        in_specs=(x_spec, P(None, None), wg_spec, wg_spec, wd_spec, cap_spec),
-        out_specs=(x_spec, P(None)),
-        check_rep=False)
-    return fn(x, w_router, w_gate, w_up, w_down, cap_e)
+    args = (x, w_router, w_gate, w_up, w_down, cap_e)
+    specs = (x_spec, P(None, None), wg_spec, wg_spec, wd_spec, cap_spec)
+    if router_bias is not None:
+        args, specs = args + (router_bias,), specs + (P(None),)
+    fn = shard_map(local, mesh=mesh, in_specs=specs,
+                   out_specs=(x_spec, P(None)), check_rep=False)
+    return fn(*args)
 
 
 def load_balancing_loss(probs: jax.Array, topi: jax.Array, E: int) -> jax.Array:

@@ -111,7 +111,7 @@ def _time_mix(cfg: ModelConfig, x: jax.Array, lp: Tree,
         u=lp["tm"]["bonus_u"].astype(jnp.float32),
         inclusive=False, chunk=cfg.scan_chunk, initial_state=state)
     y = y.transpose(0, 2, 1, 3).reshape(B, S, D)
-    y = rms_norm(y, lp["tm"]["out_norm"])
+    y = rms_norm(y, lp["tm"]["out_norm"], cfg.rms_norm_eps)
     y = y * jax.nn.silu(g.astype(jnp.float32)).astype(y.dtype)
     out = jnp.einsum("bsd,de->bse", y, lp["tm"]["wo"])
     return out, S_fin, x[:, -1]
@@ -141,17 +141,17 @@ def forward(params: Tree, cfg: ModelConfig, inputs: Dict[str, jax.Array],
 
     def body(x, lp):
         x = constrain(x, "batch", None, "act_embed")
-        h = rms_norm(x, lp["ln1"])
+        h = rms_norm(x, lp["ln1"], cfg.rms_norm_eps)
         tm, _, _ = _time_mix(cfg, h, lp)
         x = x + tm
-        h = rms_norm(x, lp["ln2"])
+        h = rms_norm(x, lp["ln2"], cfg.rms_norm_eps)
         cm, _ = _channel_mix(h, lp)
         return constrain(x + cm, "batch", None, "act_embed"),             jnp.zeros((1,), jnp.float32)
 
     if remat == "full":
         body = jax.checkpoint(body)
     x, loads = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     if return_hidden:
         return x, loads
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
@@ -168,11 +168,11 @@ def prefill(params: Tree, cfg: ModelConfig, inputs: Dict[str, jax.Array],
 
     def body(x, lp):
         x = constrain(x, "batch", None, "act_embed")
-        h = rms_norm(x, lp["ln1"])
+        h = rms_norm(x, lp["ln1"], cfg.rms_norm_eps)
         tm, S_fin, _ = _time_mix(cfg, h, lp)
         sh_tm = h[:, -1]
         x = x + tm
-        h2 = rms_norm(x, lp["ln2"])
+        h2 = rms_norm(x, lp["ln2"], cfg.rms_norm_eps)
         cm, _ = _channel_mix(h2, lp)
         sh_cm = h2[:, -1]
         return (constrain(x + cm, "batch", None, "act_embed"),
@@ -181,7 +181,7 @@ def prefill(params: Tree, cfg: ModelConfig, inputs: Dict[str, jax.Array],
     if remat == "full":
         body = jax.checkpoint(body)
     x, (wkv, sh_tm, sh_cm) = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = jnp.einsum("bd,dv->bv", x[:, -1], params["lm_head"])
     state = {"wkv": wkv, "shift_tm": sh_tm.astype(x.dtype),
              "shift_cm": sh_cm.astype(x.dtype),
@@ -224,7 +224,7 @@ def decode_step(params: Tree, cfg: ModelConfig, inputs: Dict[str, jax.Array],
 
     def body(x, layer):
         lp, wkv, sh_tm, sh_cm = layer
-        h = rms_norm(x, lp["ln1"])
+        h = rms_norm(x, lp["ln1"], cfg.rms_norm_eps)
         mix = lp["tm"]["mix"]
         streams = [h + (sh_tm.astype(h.dtype) - h) * mix[i] for i in range(5)]
         hr, hk, hv, hg, hw = streams
@@ -244,11 +244,11 @@ def decode_step(params: Tree, cfg: ModelConfig, inputs: Dict[str, jax.Array],
             hshape(r), hshape(k), hshape(v), hshape(log_w), wkv,
             u=lp["tm"]["bonus_u"].astype(jnp.float32), inclusive=False)
         y = y.reshape(B, D)
-        y = rms_norm(y, lp["tm"]["out_norm"])
+        y = rms_norm(y, lp["tm"]["out_norm"], cfg.rms_norm_eps)
         y = y * jax.nn.silu(g.astype(jnp.float32)).astype(y.dtype)
         x = x + jnp.einsum("bd,de->be", y, lp["tm"]["wo"])
 
-        h2 = rms_norm(x, lp["ln2"])
+        h2 = rms_norm(x, lp["ln2"], cfg.rms_norm_eps)
         cmix = lp["cm"]["mix"]
         hk2 = h2 + (sh_cm.astype(h2.dtype) - h2) * cmix[0]
         hr2 = h2 + (sh_cm.astype(h2.dtype) - h2) * cmix[1]
@@ -263,7 +263,7 @@ def decode_step(params: Tree, cfg: ModelConfig, inputs: Dict[str, jax.Array],
     x, (wkv_new, sh_tm_new, sh_cm_new) = jax.lax.scan(
         body, x, (params["layers"], state["wkv"],
                   state["shift_tm"], state["shift_cm"]))
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = jnp.einsum("bd,dv->bv", x, params["lm_head"])
     new_state = {"wkv": wkv_new, "shift_tm": sh_tm_new,
                  "shift_cm": sh_cm_new, "len": state["len"] + 1}

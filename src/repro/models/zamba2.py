@@ -115,7 +115,7 @@ def _mamba_layer(cfg: ModelConfig, x: jax.Array, lp: Tree,
     B, S, D = x.shape
     din, nh, N = mamba_dims(cfg)
     x = constrain(x, "batch", None, "act_embed")
-    h = rms_norm(x, lp["ln"])
+    h = rms_norm(x, lp["ln"], cfg.rms_norm_eps)
     proj = constrain(jnp.einsum("bsd,dp->bsp", h, lp["m"]["in_proj"]),
                      "batch", None, "act_heads")
     z, xin, Bc, Cc, dt = _mamba_split(cfg, proj)
@@ -151,7 +151,7 @@ def _mamba_layer(cfg: ModelConfig, x: jax.Array, lp: Tree,
         y = y.transpose(0, 2, 1, 3)                         # (B,S,nh,hd)
     y = y + xh * lp["m"]["D"].astype(x.dtype)[None, None, :, None]
     y = y.reshape(B, S, din)
-    y = rms_norm(y, lp["m"]["norm"])
+    y = rms_norm(y, lp["m"]["norm"], cfg.rms_norm_eps)
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
     y = constrain(y, "batch", None, "act_heads")
     out = jnp.einsum("bsp,pd->bsd", y, lp["m"]["out_proj"])
@@ -166,7 +166,7 @@ def _shared_block(cfg: ModelConfig, x: jax.Array, x0: jax.Array, sp: Tree,
     """Shared attention+MLP on concat(x, x0). Returns (x + delta, (k,v))."""
     B, S, D = x.shape
     cat = jnp.concatenate([x, x0], axis=-1)
-    h = rms_norm(cat, sp["ln1"])
+    h = rms_norm(cat, sp["ln1"], cfg.rms_norm_eps)
     q = constrain(jnp.einsum("bsd,dq->bsq", h, sp["wq"]).reshape(
         B, S, cfg.num_heads, cfg.head_dim), "batch", None, "act_heads", None)
     k = constrain(jnp.einsum("bsd,dq->bsq", h, sp["wk"]).reshape(
@@ -194,7 +194,8 @@ def _shared_block(cfg: ModelConfig, x: jax.Array, x0: jax.Array, sp: Tree,
             cur_len + 1)
         new_kv = (kc, vc)
     x = x + jnp.einsum("bsq,qd->bsd", a.reshape(B, S, cfg.q_dim), sp["wo"])
-    h2 = rms_norm(jnp.concatenate([x, x0], axis=-1), sp["ln2"])
+    h2 = rms_norm(jnp.concatenate([x, x0], axis=-1), sp["ln2"],
+                  cfg.rms_norm_eps)
     x = x + mlp_swiglu(h2, sp["wi_gate"], sp["wi_up"], sp["wo_mlp"])
     return x, new_kv
 
@@ -231,7 +232,7 @@ def forward(params: Tree, cfg: ModelConfig, inputs: Dict[str, jax.Array],
         return x, jnp.zeros((1,), jnp.float32)
 
     x, loads = jax.lax.scan(outer, x0, grouped)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     if return_hidden:
         return x, loads
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
@@ -269,7 +270,7 @@ def prefill(params: Tree, cfg: ModelConfig, inputs: Dict[str, jax.Array],
         return x, (ssm_fin, conv_fin, kf.astype(x.dtype), vf.astype(x.dtype))
 
     x, (ssm, conv, ks, vs) = jax.lax.scan(outer, x0, grouped)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = jnp.einsum("bd,dv->bv", x[:, -1], params["lm_head"])
     L = cfg.num_layers
     state = {
@@ -336,7 +337,7 @@ def decode_step(params: Tree, cfg: ModelConfig, inputs: Dict[str, jax.Array],
     x, (ssm_new, conv_new, k_new, v_new) = jax.lax.scan(
         outer, x0, (grouped, ssm_g, conv_g,
                     state["shared_k"], state["shared_v"]))
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])[:, 0]
     new_state = {
         "ssm": ssm_new.reshape(cfg.num_layers, *ssm_new.shape[2:]),

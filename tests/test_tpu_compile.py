@@ -307,3 +307,68 @@ def test_pallas_kernel_compiles_for_v5e(one_chip, name):
     args = [_sds(one_chip, s, d) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the benchmark's deepseek-v3.longdoc cell: its serving shapes, at
+# published widths with 1 dense and 1 expert layer of the share
+MLA_FILE = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+            / "configs" / "deepseek-v3.json")
+
+
+@pytest.fixture(scope="module")
+def mla_share(one_chip):
+    """DeepSeek-V3 at published widths, 1 dense and 1 expert layer
+    holding 8 of 256 experts, with the cell's latent pool, as shapes on
+    one chip."""
+    spec = json.loads(MLA_FILE.read_text())
+    cfg = dataclasses.replace(get_config("deepseek-v3"), num_layers=2,
+                              first_dense_layers=1, experts_held=8)
+    model = get_model(cfg)
+    s = spec["serve"]
+    params, _ = model.init(jax.random.PRNGKey(0), jnp.bfloat16, abstract=True)
+    pool, _ = model.init_paged_decode(s["num_blocks"], s["block_size"],
+                                      abstract=True)
+    return model, _on(one_chip, params), _on(one_chip, pool), s
+
+
+@pytest.mark.parametrize("program", ["serve_step", "prefill_chunk"])
+def test_mla_programs_read_the_latent_pool_through_the_kernel(
+        one_chip, mla_share, program):
+    """Both serving programs of the MLA cell compile for a v5e; each holds
+    the latent mode of a Pallas paged-attention kernel over the stacked
+    (L, NB, 16, 640) latent pool, and neither a gathered view of the
+    12,544-position context nor any per-head key or value expanded from
+    it; the pool is updated in place."""
+    model, params, pool, s = mla_share
+    C, W = s["concurrency"], s["max_context"] // s["block_size"]
+    S = s["max_context"]
+    i32 = jnp.int32
+    if program == "serve_step":
+        step = jax.jit(make_paged_serve_step(model, s["decode_steps"]),
+                       donate_argnums=(2,))
+        compiled = step.lower(
+            params, {"tokens": _sds(one_chip, (C, 1), i32)}, pool,
+            _sds(one_chip, (C, W), i32), _sds(one_chip, (C,), i32),
+            _sds(one_chip, (C,), i32), _sds(one_chip, (C,), jnp.bool_),
+            _sds(one_chip, (C,), i32), _sds(one_chip, (), i32)).compile()
+    else:
+        step = jax.jit(make_paged_prefill_step(model), donate_argnums=(2,))
+        compiled = step.lower(
+            params, {"tokens": _sds(one_chip, (1, s["prefill_chunk"]), i32)},
+            pool, _sds(one_chip, (W,), i32), _sds(one_chip, (), i32),
+            _sds(one_chip, (), i32)).compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes < V5E_HBM_BYTES
+    hlo = compiled.as_text()
+    L, NB, BS, width = pool["latent"].shape
+    assert width == 640 and BS == 16
+    pages = f"bf16[{L},{NB},{BS},{width}]"
+    assert any("tpu_custom_call" in line and pages in line
+               for line in hlo.splitlines())
+    # no gathered view of any row's whole context, nor of the blocks
+    assert f",{S},{width}]" not in hlo and f",{W},{BS},{width}]" not in hlo
+    # no per-head keys or values (128 heads) over the context
+    assert not re.search(rf"\[(\d+,)*{S},128,\d+\]", hlo)
+    assert not re.search(rf"\[(\d+,)*128,{S}(,\d+)*\]", hlo)
+    ops = re.findall(r"= (\(.*?\)|\S+) ([\w-]+)\(", hlo)
+    assert not [op for shape, op in ops
+                if op in ("copy", "copy-start") and pages in shape]
